@@ -1,0 +1,204 @@
+"""Chipless TPU compiles of the main-path Pallas kernels at TPC-H SF1 shapes.
+
+Interpret mode (tests/test_pallas*.py) proves what the kernels compute; it
+cannot show what the chip's compiler refuses.  Before PR 22 both hash kernels
+were refused by Mosaic (an f64 scalar out of a bool reduction, an i1 reshape,
+an i1 while-loop carry), the hash build took 230 s to compile, the fused scan
+kernel aborted the compiler on a keyless recipe and a wide segment reduce
+outgrew VMEM — all invisible to every interpreted test.  The TPU compiler is
+installed here and compiles for a chip that is described, not attached, so
+these tests guard every later PR at no chip time.  Nothing runs: results are
+the interpreted tests' business.
+
+The topology is described inside a module-scoped fixture (never at import,
+in a skipif or in parametrize: the TPU library belongs to one process, and
+every xdist worker imports every test file), and all of these tests live in
+this one file so one worker holds the library.
+"""
+
+import os
+import time
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+N_SF1 = 6_001_215  # lineitem rows at SF1
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a chipless compile is written to the persistent cache but cannot be
+    # read back without a chip: keep the cache out of these compiles
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+def _compile(fn, *shapes):
+    """AOT-compile for the described chip; the kernel must be in the
+    program as a Mosaic custom call."""
+    t0 = time.perf_counter()
+    compiled = jax.jit(fn).lower(*shapes).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return time.perf_counter() - t0
+
+
+def _col(one_chip, dtype, n=N_SF1):
+    return jax.ShapeDtypeStruct((n,), dtype, sharding=one_chip)
+
+
+@pytest.mark.parametrize("n_words,cap", [(2, 2048), (6, 4096)])
+def test_hash_build_compiles_for_v5e(one_chip, n_words, cap):
+    from trino_tpu.ops.pallas import hashagg
+
+    seconds = _compile(
+        lambda live, *w: hashagg.build_hash_table(list(w), live, cap),
+        _col(one_chip, jnp.bool_),
+        *[_col(one_chip, jnp.int32)] * n_words,
+    )
+    # the python-unrolled kernel compiled, in 230 s; the loops keep it small
+    assert seconds < 60, f"hash build compile took {seconds:.0f}s"
+
+
+@pytest.mark.parametrize("n_words,cap", [(2, 2048), (6, 4096)])
+def test_hash_probe_compiles_for_v5e(one_chip, n_words, cap):
+    from trino_tpu.ops.pallas import hashagg, hashjoin
+
+    table = jax.ShapeDtypeStruct(
+        (16, hashagg.table_size(cap)), jnp.float32, sharding=one_chip
+    )
+    _compile(
+        lambda live, tbl, *w: hashjoin.probe_hash_table(list(w), live, tbl),
+        _col(one_chip, jnp.bool_), table,
+        *[_col(one_chip, jnp.int32)] * n_words,
+    )
+
+
+def test_segment_reduce_compiles_for_v5e(one_chip):
+    from trino_tpu.ops.pallas.segreduce import SegRed, fused_segment_reduce
+
+    def reduce5(seg, f64, i64, i32, valid):
+        return fused_segment_reduce(
+            seg,
+            [SegRed("sum", f64, valid), SegRed("sum", i64, valid),
+             SegRed("count", None, valid), SegRed("min", i32, valid),
+             SegRed("max", f64, valid)],
+            4, force_pallas=True,
+        )
+
+    _compile(
+        reduce5, _col(one_chip, jnp.int32), _col(one_chip, jnp.float64),
+        _col(one_chip, jnp.int64), _col(one_chip, jnp.int32),
+        _col(one_chip, jnp.bool_),
+    )
+
+
+def test_wide_segment_reduce_fits_vmem(one_chip):
+    """q01 over a sharded scan asks for 34 reductions, 111 limb planes:
+    one pass wanted 16.1 MB of scoped VMEM (limit 16 MB) and was refused;
+    the reduction now splits into passes that fit."""
+    from trino_tpu.ops.pallas.segreduce import SegRed, fused_segment_reduce
+
+    n = N_SF1 // 4
+
+    def reduce34(seg, i64, f64, valid):
+        reds = [SegRed("sum", i64, valid)] * 22 + [
+            SegRed("sum", f64, valid), SegRed("count", None, valid),
+        ] * 6
+        return fused_segment_reduce(seg, reds, 6, force_pallas=True)
+
+    _compile(
+        reduce34, _col(one_chip, jnp.int32, n), _col(one_chip, jnp.int64, n),
+        _col(one_chip, jnp.float64, n), _col(one_chip, jnp.bool_, n),
+    )
+
+
+def test_radix_topk_compiles_for_v5e(one_chip, monkeypatch):
+    from trino_tpu.ops.pallas import segreduce
+
+    # the histogram passes ask jax.default_backend(), which is the CPU here:
+    # steer them to the kernel in the test, as the chip would
+    monkeypatch.setattr(
+        segreduce, "pallas_segreduce_supported", lambda g, backend=None: True
+    )
+    from trino_tpu.ops.pallas import topk
+
+    _compile(
+        lambda u, live: topk.radix_topk_threshold(u, live, 10),
+        _col(one_chip, jnp.uint32), _col(one_chip, jnp.bool_),
+    )
+
+
+@pytest.fixture(scope="module")
+def fused_recipes():
+    """The q01 and q06 recipes as the engine plans them (captured at
+    SF0.01 under interpret mode; dictionaries, so domains, equal SF1's)."""
+    from tests.tpch_queries import QUERIES
+    from trino_tpu.connectors.tpch import TpchConnector
+    from trino_tpu.ops.pallas import fused
+    from trino_tpu.runtime.engine import Engine
+
+    captured = {}
+    orig = fused.run
+
+    def spy(recipe, scan_cols, live, **kw):
+        captured["last"] = (recipe, list(scan_cols))
+        return orig(recipe, scan_cols, live, **kw)
+
+    eng = Engine()
+    eng.register_catalog("tpch", TpchConnector(0.01))
+    eng.session.set("pallas_interpret", "true")
+    fused.run = spy
+    try:
+        out = {}
+        for name in ("q01", "q06"):
+            eng.query(QUERIES[name])
+            out[name] = captured.pop("last")
+    finally:
+        fused.run = orig
+    return out
+
+
+@pytest.mark.parametrize("name", ["q01", "q06"])
+def test_fused_scan_compiles_for_v5e(one_chip, fused_recipes, name):
+    from trino_tpu.ops.expr import ColumnVal
+    from trino_tpu.ops.pallas import fused
+
+    recipe, cols = fused_recipes[name]
+    used = {i for i, _ in recipe.cols}
+
+    def run(live, *arrays):
+        it = iter(arrays)
+        scan = []
+        for i, cv in enumerate(cols):
+            if i not in used:
+                scan.append(None)
+                continue
+            data = next(it)
+            valid = next(it) if cv.valid is not None else None
+            scan.append(ColumnVal(data, valid, cv.dict, cv.type, None))
+        return fused.run(recipe, scan, live)
+
+    shapes = [_col(one_chip, jnp.bool_)]
+    for i, cv in enumerate(cols):
+        if i in used:
+            shapes.append(_col(one_chip, cv.data.dtype))
+            if cv.valid is not None:
+                shapes.append(_col(one_chip, jnp.bool_))
+    _compile(run, *shapes)

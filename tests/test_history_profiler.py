@@ -213,17 +213,28 @@ def test_perf_gate_wall_ratio():
     assert gate.compare(old, old) == []
 
 
-@pytest.mark.skipif(
-    not os.path.exists(os.path.join(_REPO, "BENCH_r04.json")),
-    reason="bench artifacts not present",
-)
-def test_perf_gate_on_recorded_bench_runs():
+def test_perf_gate_on_recorded_bench_runs(tmp_path):
+    """The gate's file surface (main + the driver's {parsed: ...} wrapper)
+    over two small records: a q03 wall regression and a clean pair."""
+    import json
+
     gate = _load_script("perf_gate")
-    r04 = os.path.join(_REPO, "BENCH_r04.json")
-    r05 = os.path.join(_REPO, "BENCH_r05.json")
-    assert gate.main([r04, r05]) == 2  # r05 introduced the q03 regression
-    assert gate.main([r04, r04]) == 0
-    assert gate.main([r05, r05]) == 0  # known regression doesn't re-fail
+
+    def record(name, q03_wall):
+        path = tmp_path / name
+        path.write_text(json.dumps({"n": 1, "rc": 0, "parsed": {
+            "sf": 1.0, "device": "tpu",
+            "queries": {"q01": {"wall_s": 0.116},
+                        "q03": {"wall_s": q03_wall},
+                        "q18": {"skipped": "deadline"}},
+        }}))
+        return str(path)
+
+    old = record("old.json", 1.38)
+    new = record("new.json", 2.9)  # q03 more than 1.5x slower
+    assert gate.main([old, new]) == 2
+    assert gate.main([old, old]) == 0
+    assert gate.main([new, new]) == 0
 
 
 # ----------------------------------------------------------- metrics lint

@@ -133,8 +133,11 @@ def _topn_ref(rows, keys_idx, ascending, k):
 
 @pytest.mark.parametrize("dtype", ["float64", "int64", "int32"])
 @pytest.mark.parametrize("ascending", [True, False])
-def test_radix_topn_matches_sort(rng, dtype, ascending):
-    """relops.top_n radix-select path == plain-sort path, incl. ties/NULLs."""
+@pytest.mark.parametrize("few_live", [False, True])
+def test_radix_topn_matches_sort(rng, dtype, ascending, few_live):
+    """relops.top_n radix-select path == plain-sort path, incl. ties/NULLs,
+    and with fewer live rows than K (TPC-H q18 at SF1: 63 rows, limit 100 —
+    the threshold must fall to the smallest key, not the largest)."""
     from trino_tpu.data.types import BIGINT, DOUBLE, INTEGER
     from trino_tpu.ops.expr import ColumnVal
     from trino_tpu.ops.pallas import topk
@@ -149,7 +152,7 @@ def test_radix_topn_matches_sort(rng, dtype, ascending):
         t = BIGINT if dtype == "int64" else INTEGER
     payload = np.arange(n, dtype=np.int64)
     valid = rng.rand(n) > 0.05
-    live = jnp.asarray(rng.rand(n) > 0.1)
+    live = jnp.asarray(rng.rand(n) > (0.995 if few_live else 0.1))
 
     key = ColumnVal(jnp.asarray(vals), jnp.asarray(valid), None, t)
     pay = ColumnVal(jnp.asarray(payload), None, None, BIGINT)

@@ -337,6 +337,31 @@ def test_fused_pipeline_engine_differential(kernel_engine, name):
     )
 
 
+def test_fused_pipeline_sees_through_compact():
+    """Above 64k scan rows the optimizer wraps scan filters in Compact
+    points (plan/optimizer.py); the fused pipeline must still be selected —
+    at SF0.01 there are none, which hid that it never fused at real scale."""
+    from tests.tpch_queries import QUERIES
+    from trino_tpu.connectors.tpch import TpchConnector
+    from trino_tpu.runtime.engine import Engine
+
+    eng = Engine()
+    eng.register_catalog("tpch", TpchConnector(0.02))  # 120k lineitem rows
+    sql = QUERIES["q06"]
+    assert "Compact" in eng.explain(sql)
+    eng.session.set("data_plane_kernels", "false")
+    (legacy,) = eng.query(sql)
+    eng.session.set("data_plane_kernels", "true")
+    eng.session.set("pallas_interpret", "true")
+    try:
+        (fused,) = eng.query(sql)
+        ex = eng.execute(f"EXPLAIN ANALYZE {sql}")
+    finally:
+        eng.session.set("pallas_interpret", "false")
+    assert any("pallas fused_pipeline" in str(r[0]) for r in ex)
+    assert float(fused[0]) == pytest.approx(float(legacy[0]), rel=1e-6)
+
+
 def test_fused_dispatch_metric_increments(kernel_engine):
     # dispatch counts at TRACE time, so use a q06 variant no other test has
     # traced (a jit-cache hit would legitimately not re-count)

@@ -10,17 +10,12 @@ Reference: the per-connector on-hardware variants of the engine suites
 (testing/trino-testing/.../AbstractTestQueries.java subclasses).
 """
 
-import json
-import os
-import subprocess
-import sys
-
 import pytest
 
+from tests.hw_runner import run_on_hardware
 from tests.oracle import SqliteOracle, assert_rows_equal
 from tests.tpcds_queries import ORDERED, QUERIES
 
-_HW = os.environ.get("TRINO_TPU_HW_PLATFORM", "")
 _SCALE = 0.002
 
 _TPU_QUERIES = [
@@ -29,12 +24,6 @@ _TPU_QUERIES = [
 ]
 
 _RUNNER = r"""
-import json, os, sys
-sys.path.insert(0, {repo!r})
-import jax
-from trino_tpu.utils.compilecache import enable_persistent_cache
-enable_persistent_cache({repo!r})
-assert jax.default_backend() != "cpu", f"expected hardware, got {{jax.default_backend()}}"
 from tests.tpcds_queries import QUERIES
 from trino_tpu.connectors.tpcds import TpcdsConnector
 from trino_tpu.runtime.engine import Engine
@@ -51,27 +40,7 @@ print("\nRESULT:" + json.dumps(out))
 
 @pytest.fixture(scope="module")
 def tpcds_tpu_results():
-    if not _HW or _HW == "cpu":
-        pytest.skip("no TPU platform available (explicitly CPU)")
-    env = dict(os.environ)
-    if _HW == "auto":
-        env.pop("JAX_PLATFORMS", None)
-    else:
-        env["JAX_PLATFORMS"] = _HW
-    env.pop("XLA_FLAGS", None)
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    code = _RUNNER.format(repo=repo, scale=_SCALE, names=_TPU_QUERIES)
-    proc = subprocess.run(
-        [sys.executable, "-c", code],
-        env=env, cwd=repo, capture_output=True, text=True, timeout=3600,
-    )
-    if proc.returncode != 0:
-        pytest.skip(
-            f"TPU subprocess failed (hardware unavailable?):\n{proc.stderr[-2000:]}"
-        )
-    payload = [l for l in proc.stdout.splitlines() if l.startswith("RESULT:")]
-    assert payload, f"no RESULT line:\n{proc.stdout[-2000:]}"
-    return json.loads(payload[-1][len("RESULT:"):])
+    return run_on_hardware(_RUNNER.format(scale=_SCALE, names=_TPU_QUERIES))
 
 
 @pytest.fixture(scope="module")

@@ -17,20 +17,17 @@ Reference pattern: AbstractTestQueryFramework.assertQuery
 (testing/trino-testing/.../AbstractTestQueryFramework.java:344) — same
 differential idea, with hardware in the loop.
 
-Skipped when no TPU platform is available (e.g. plain CPU CI).
+Skipped when no TPU platform is available (e.g. plain CPU CI); once the
+child process has found an accelerator, a failure there fails the tier
+(tests/hw_runner.py).
 """
-
-import json
-import os
-import subprocess
-import sys
 
 import pytest
 
+from tests.hw_runner import run_on_hardware
 from tests.oracle import assert_rows_equal
 from tests.tpch_queries import QUERIES
 
-_HW = os.environ.get("TRINO_TPU_HW_PLATFORM", "")
 _SCALE = 0.01
 
 # ALL 22 TPC-H queries run on the chip (round-4 verdict asked for the full
@@ -57,16 +54,8 @@ _EXTRA_SQL = {
 }
 
 _RUNNER = r"""
-import json, os, sys
-sys.path.insert(0, {repo!r})
-import jax
-jax.config.update("jax_compilation_cache_dir",
-                  os.path.join({repo!r}, ".jax_cache"))
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
 from trino_tpu.connectors.tpch import TpchConnector
 from trino_tpu.runtime.engine import Engine
-
-assert jax.default_backend() != "cpu", f"expected hardware, got {{jax.default_backend()}}"
 from tests.tpch_queries import QUERIES
 
 sqls = dict(QUERIES)
@@ -88,32 +77,10 @@ print("\nRESULT:" + json.dumps(out))
 
 @pytest.fixture(scope="module")
 def tpu_results():
-    if not _HW or _HW == "cpu":
-        pytest.skip("no TPU platform available (explicitly CPU)")
-    env = dict(os.environ)
-    if _HW == "auto":
-        env.pop("JAX_PLATFORMS", None)  # let jax autodetect the accelerator
-    else:
-        env["JAX_PLATFORMS"] = _HW
-    env.pop("XLA_FLAGS", None)  # drop the CPU suite's virtual-device forcing
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    code = _RUNNER.format(
-        repo=repo, scale=_SCALE, names=_TPU_QUERIES,
+    return run_on_hardware(_RUNNER.format(
+        scale=_SCALE, names=_TPU_QUERIES,
         dist_names=_TPU_DISTRIBUTED, extra=_EXTRA_SQL,
-    )
-    proc = subprocess.run(
-        [sys.executable, "-c", code],
-        env=env,
-        cwd=repo,
-        capture_output=True,
-        text=True,
-        timeout=3600,
-    )
-    if proc.returncode != 0:
-        pytest.skip(f"TPU subprocess failed (hardware unavailable?):\n{proc.stderr[-2000:]}")
-    payload = [l for l in proc.stdout.splitlines() if l.startswith("RESULT:")]
-    assert payload, f"no RESULT line in TPU subprocess output:\n{proc.stdout[-2000:]}"
-    return json.loads(payload[-1][len("RESULT:"):])
+    ))
 
 
 @pytest.mark.parametrize(

@@ -19,6 +19,11 @@ module is the one place that decision is configured and observed:
   * events_for(plan) — the captured events of the last trace of `plan`
     (plans are frozen dataclasses, so they key a bounded dict directly).
 
+ops: group_by (ops/pallas/hashagg.py), join (hashagg build + hashjoin
+probe), fused_pipeline (fused.py), segment_reduce (segreduce.py — the
+accumulator under every group-by path and the radix histograms) and top_n
+(topk.py radix select); the last two are counted when selected only.
+
 impl values: "pallas" = the Pallas kernel was selected; "sort" = the static
 gate chose the legacy sort path (disabled, unencodable keys, unsupported
 shape, or a non-TPU backend without interpret); "fallback" = the shape was
@@ -57,9 +62,10 @@ _POLICY = _DEFAULT
 _DISPATCH = _metrics.GLOBAL.counter(
     "trino_tpu_kernel_dispatch_total",
     "Data-plane kernel selections at plan-trace time, by relational op "
-    "(group_by | join | fused_pipeline) and implementation (pallas = Pallas "
-    "TPU kernel, sort = legacy sort path, fallback = kernel-eligible shape "
-    "past the policy capacity limit, sort path ran)",
+    "(group_by | join | fused_pipeline | segment_reduce | top_n) and "
+    "implementation (pallas = Pallas TPU kernel, sort = legacy sort path, "
+    "fallback = kernel-eligible shape past the policy capacity limit, sort "
+    "path ran; segment_reduce and top_n count their Pallas selections only)",
     ("op", "impl"),
 )
 

@@ -53,7 +53,6 @@ from .hashagg import (
     _CHUNK_S,
     _STEP_CHUNKS,
     _STEP_ROWS,
-    _enable_x64,
     _prep,
 )
 from . import hashagg as _hashagg
@@ -592,23 +591,33 @@ def _fused_kernel(recipe: _Recipe, n_chunks: int, interpret: bool):
             mask = i32[0] > 0
             for f in recipe.filters:
                 mask = mask & ev.pred(f)
-            code = jnp.zeros((_CHUNK_S, _CHUNK_L), jnp.int32)
+            code = None
             for ci, _, stride in recipe.keys:
-                code = code + i32[key_planes[ci]] * jnp.int32(stride)
+                term = i32[key_planes[ci]] * jnp.int32(stride)
+                code = term if code is None else code + term
             streams = [
                 ev.masked_stream(tag, e, mask) for tag, e in recipe.streams
             ]
             upd = jnp.stack(streams, axis=1)  # (8, NR, 128)
-            lane = jax.lax.broadcasted_iota(
-                jnp.int32, (_CHUNK_S, _CHUNK_L, _DTILE), 2
-            )
-            oh = (code[:, :, None] == lane).astype(jnp.float32)
-            part = jax.lax.dot_general(
-                upd, oh,
-                (((2,), (1,)), ((0,), (0,))),
-                preferred_element_type=jnp.float32,
-                precision=jax.lax.Precision.HIGHEST,
-            ).sum(axis=0)  # (NR, 512)
+            if recipe.keys:
+                lane = jax.lax.broadcasted_iota(
+                    jnp.int32, (_CHUNK_S, _CHUNK_L, _DTILE), 2
+                )
+                oh = (code[:, :, None] == lane).astype(jnp.float32)
+                part = jax.lax.dot_general(
+                    upd, oh,
+                    (((2,), (1,)), ((0,), (0,))),
+                    preferred_element_type=jnp.float32,
+                    precision=jax.lax.Precision.HIGHEST,
+                ).sum(axis=0)  # (NR, 512)
+            else:
+                # one global group: a plain reduction into lane 0.  (A
+                # one-hot of a constant code aborts the TPU compiler.)
+                tot = jnp.sum(jnp.sum(upd, axis=0), axis=1, keepdims=True)
+                lane0 = jax.lax.broadcasted_iota(
+                    jnp.int32, (nr, _DTILE), 1
+                ) == 0
+                part = jnp.where(lane0, tot, jnp.float32(0.0))
             # Neumaier: compensate chunk-to-chunk rounding of the running sum
             a = acc[...]
             t = a + part
@@ -692,7 +701,7 @@ def run(recipe: _Recipe, scan_cols, live, *, interpret: bool = False):
         f32_planes[0] = _prep(jnp.zeros((1,), jnp.float32), n_pad, 0.0)
 
     call = _fused_kernel(recipe, n_chunks, interpret)
-    with _enable_x64(False):
+    with jax.enable_x64(False):
         out = call(jnp.stack(i32_planes), jnp.stack(f32_planes))
     totals = (
         out[0].astype(jnp.float64) + out[1].astype(jnp.float64)

@@ -36,7 +36,7 @@ from .hashagg import (
     _PROBE_LIMIT,
     _STEP_CHUNKS,
     _STEP_ROWS,
-    _enable_x64,
+    _any_f32,
     _gather_channels,
     _prep,
     hash_words,
@@ -59,18 +59,18 @@ def _probe_kernel(n_words: int, T: int, n_chunks: int, interpret: bool):
         def _init():
             over[0] = jnp.int32(0)
 
-        for c in range(_STEP_CHUNKS):
-            rows = slice(c * _CHUNK_S, (c + 1) * _CHUNK_S)
+        def _sub_chunk(c, _):
+            rows = pl.ds(pl.multiple_of(c * _CHUNK_S, _CHUNK_S), _CHUNK_S)
             sl = slot_ref[rows, :]
             lv = live_ref[rows, :] > 0
             vals = [planes_ref[w, rows, :] for w in range(n_half)]
 
             off0 = jnp.zeros(sl.shape, jnp.int32)
-            resolved0 = ~lv
             gid0 = jnp.full(sl.shape, -1, jnp.int32)
 
             def _round(carry):
-                r, off, resolved, gid = carry
+                r, off, done, gid = carry  # done: 0/1 int32, see hashagg
+                resolved = done > 0
                 cur = sl + off
                 cur = jnp.where(cur >= T, cur - T, cur)
                 active = ~resolved
@@ -84,19 +84,23 @@ def _probe_kernel(n_words: int, T: int, n_chunks: int, interpret: bool):
                 # an empty slot on the probe walk proves the key is absent
                 resolved = resolved | match | (active & ~used)
                 off = off + (active & used & ~eq).astype(jnp.int32)
-                return r + 1, off, resolved, gid
+                return r + 1, off, resolved.astype(jnp.int32), gid
 
             def _unresolved(carry):
-                r, _off, resolved, _gid = carry
-                return (r < _PROBE_LIMIT) & jnp.any(~resolved)
+                r, _off, done, _gid = carry
+                return (r < _PROBE_LIMIT) & _any_f32(done == 0)
 
-            _, _, resolved, gid = jax.lax.while_loop(
-                _unresolved, _round, (jnp.int32(0), off0, resolved0, gid0)
+            _, _, done, gid = jax.lax.while_loop(
+                _unresolved, _round,
+                (jnp.int32(0), off0, (~lv).astype(jnp.int32), gid0),
             )
             over[0] = jnp.maximum(
-                over[0], jnp.any(~resolved).astype(jnp.int32)
+                over[0], _any_f32(done == 0).astype(jnp.int32)
             )
             gid_ref[rows, :] = gid
+            return 0
+
+        jax.lax.fori_loop(0, _STEP_CHUNKS, _sub_chunk, 0)
 
         @pl.when(i == n_chunks - 1)
         def _flush():
@@ -156,7 +160,7 @@ def probe_hash_table(words, live, table, *, interpret: bool = False):
         planes.append(_prep(lo, n_pad, 0.0))
         planes.append(_prep(hi, n_pad, 0.0))
     call = _probe_kernel(len(words), T, n_chunks, interpret)
-    with _enable_x64(False):
+    with jax.enable_x64(False):
         gid_b, stats = call(
             _prep(slot0, n_pad, 0),
             _prep(live.astype(jnp.int32), n_pad, 0),

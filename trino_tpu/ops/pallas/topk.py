@@ -106,10 +106,10 @@ def radix_topk_threshold(u: jnp.ndarray, live: jnp.ndarray, k: int) -> jnp.ndarr
         bin_ = jnp.argmax(sel).astype(jnp.uint32)
         # k exceeds the live rows under this prefix: take the smallest
         # non-empty bin so every such row qualifies
-        nonempty = hist > 0
-        low_bin = jnp.where(
-            jnp.any(nonempty), 255 - jnp.argmax(nonempty[::-1]), 0
-        ).astype(jnp.uint32)
+        # (argmax of a bool vector is its FIRST true lane, i.e. the smallest
+        # non-empty bin, and 0 when there is none; this used to pick the
+        # largest one, so a TopN over fewer than K live rows kept one row)
+        low_bin = jnp.argmax(hist > 0).astype(jnp.uint32)
         bin_ = jnp.where(any_sel, bin_, low_bin)
         above = jnp.where(any_sel, above_b[bin_.astype(jnp.int32)], above)
         prefix = prefix | (bin_ << shift)

@@ -52,6 +52,17 @@ _EXCHANGE_PLANNED_BYTES = _METRICS.counter(
 )
 
 
+def pmax_count(value, axis: str):
+    """Cross-device max of a row counter, agreed on by every device.
+
+    Not `lax.pmax`: counters are int64, and the TPU compiler lowers a 64-bit
+    all-reduce only for Sum ("Supported lowering only of Sum all reduce"),
+    so an int64 pmax compiles on the CPU's virtual devices and is refused on
+    a real multi-chip mesh.  An all_gather is data movement, which it does
+    split into 32-bit halves; the max is then local."""
+    return jnp.max(jax.lax.all_gather(value, axis))
+
+
 def _planned_bytes(cols: Sequence[ColumnVal], live: jnp.ndarray) -> int:
     total = int(live.shape[0])  # the live mask itself (1B bool lanes)
     for cv in cols:
@@ -114,7 +125,7 @@ def repartition(
         first_idx, jnp.minimum(part_s, D)
     )
     required = jnp.max(counts[:D]) if D > 0 else jnp.int32(0)
-    required = jax.lax.pmax(required, axis)
+    required = pmax_count(required, axis)
 
     # scatter sorted rows into [D, B] send buffers (overflow rows dropped --
     # the host retries with bigger B before trusting results)

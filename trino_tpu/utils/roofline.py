@@ -14,9 +14,10 @@ Two sources, chosen by platform:
   repeated processes on the same host skip the probe.  Cache path:
   ``$TRINO_TPU_ROOFLINE_CACHE`` or ``<tmpdir>/trino_tpu_roofline.json``.
 
-Everything is lazy — nothing touches jax or runs the probe at import —
-and every path degrades to a conservative default rather than raising:
-the roofline is telemetry, never a query dependency.
+Everything is lazy — nothing touches jax or runs the probe at import.
+The CPU probe degrades to a conservative default; a TPU whose device kind
+is not in the table raises (callers treat the roofline as telemetry and
+report none, rather than a percentage of a guessed peak).
 """
 
 from __future__ import annotations
@@ -55,11 +56,10 @@ SIGNATURE_GBPS = _metrics.GLOBAL.histogram(
 # jax's device_kind string; first match wins, most-specific first
 TPU_HBM_GBPS: tuple[tuple[str, float], ...] = (
     ("v6e", 1640.0),
-    ("v6", 1640.0),
+    ("v6 lite", 1640.0),
     ("v5p", 2765.0),
     ("v5 lite", 819.0),
     ("v5e", 819.0),
-    ("v5", 819.0),
     ("v4", 1228.0),
     ("v3", 900.0),
     ("v2", 700.0),
@@ -138,20 +138,21 @@ def device_roofline(cache_path: Optional[str] = None) -> dict:
     with _lock:
         if _cached is not None:
             return dict(_cached)
-    platform, kind = "cpu", "cpu"
-    try:
-        import jax
+    import jax
 
-        dev = jax.devices()[0]
-        platform = str(dev.platform).lower()
-        kind = str(getattr(dev, "device_kind", platform))
-    except Exception:
-        pass
+    dev = jax.devices()[0]  # no device is an error, never "cpu"
+    platform = str(dev.platform).lower()
+    kind = str(dev.device_kind)
     if platform == "tpu":
         low = kind.lower()
-        gbps = next(
-            (v for frag, v in TPU_HBM_GBPS if frag in low), 819.0
-        )
+        gbps = next((v for frag, v in TPU_HBM_GBPS if frag in low), None)
+        if gbps is None:
+            # a device that is not in the table is an error, not a default:
+            # a guessed peak would make every %-of-roofline figure a guess
+            raise LookupError(
+                f"no HBM bandwidth on record for TPU device_kind {kind!r};"
+                f" add it to TPU_HBM_GBPS with its source"
+            )
         info = {
             "platform": platform,
             "device_kind": kind,
