@@ -50,12 +50,15 @@ def one_chip():
     cc.reset_cache()
 
 
-def _compile(fn, *shapes):
+def _compile(fn, *shapes, kernel):
     """AOT-compile for the described chip; the kernel must be in the
-    program as a Mosaic custom call."""
+    program as a Mosaic custom call that carries its name (`name=` on the
+    pallas_call: a profiler trace then says `%hash_agg.1`, not `%call.130`)."""
     t0 = time.perf_counter()
     compiled = jax.jit(fn).lower(*shapes).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert f"%{kernel}" in text, kernel
     return time.perf_counter() - t0
 
 
@@ -71,6 +74,7 @@ def test_hash_build_compiles_for_v5e(one_chip, n_words, cap):
         lambda live, *w: hashagg.build_hash_table(list(w), live, cap),
         _col(one_chip, jnp.bool_),
         *[_col(one_chip, jnp.int32)] * n_words,
+        kernel="hash_agg",
     )
     # the python-unrolled kernel compiled, in 230 s; the loops keep it small
     assert seconds < 60, f"hash build compile took {seconds:.0f}s"
@@ -87,6 +91,7 @@ def test_hash_probe_compiles_for_v5e(one_chip, n_words, cap):
         lambda live, tbl, *w: hashjoin.probe_hash_table(list(w), live, tbl),
         _col(one_chip, jnp.bool_), table,
         *[_col(one_chip, jnp.int32)] * n_words,
+        kernel="hash_join_probe",
     )
 
 
@@ -105,7 +110,7 @@ def test_segment_reduce_compiles_for_v5e(one_chip):
     _compile(
         reduce5, _col(one_chip, jnp.int32), _col(one_chip, jnp.float64),
         _col(one_chip, jnp.int64), _col(one_chip, jnp.int32),
-        _col(one_chip, jnp.bool_),
+        _col(one_chip, jnp.bool_), kernel="seg_reduce",
     )
 
 
@@ -126,6 +131,7 @@ def test_wide_segment_reduce_fits_vmem(one_chip):
     _compile(
         reduce34, _col(one_chip, jnp.int32, n), _col(one_chip, jnp.int64, n),
         _col(one_chip, jnp.float64, n), _col(one_chip, jnp.bool_, n),
+        kernel="seg_reduce",
     )
 
 
@@ -142,6 +148,7 @@ def test_radix_topk_compiles_for_v5e(one_chip, monkeypatch):
     _compile(
         lambda u, live: topk.radix_topk_threshold(u, live, 10),
         _col(one_chip, jnp.uint32), _col(one_chip, jnp.bool_),
+        kernel="seg_reduce",  # the histogram passes are segment reductions
     )
 
 
@@ -201,4 +208,4 @@ def test_fused_scan_compiles_for_v5e(one_chip, fused_recipes, name):
             shapes.append(_col(one_chip, cv.data.dtype))
             if cv.valid is not None:
                 shapes.append(_col(one_chip, jnp.bool_))
-    _compile(run, *shapes)
+    _compile(run, *shapes, kernel="fused_scan")

@@ -299,6 +299,12 @@ class Page:
     def num_columns(self) -> int:
         return len(self.columns)
 
+    @property
+    def nbytes(self) -> int:
+        """Bytes of every array the page holds: what _fetch_host brings to
+        the host, less a live mask it has to make."""
+        return sum(int(a.nbytes) for a in jax.tree_util.tree_leaves(self))
+
     def live_mask(self) -> jnp.ndarray:
         if self.live is None:
             return jnp.ones((self.capacity,), dtype=jnp.bool_)

@@ -17,6 +17,7 @@ caches the compiled program per (plan, capacities).
 
 from __future__ import annotations
 
+import contextlib
 import math
 from dataclasses import dataclass
 from typing import Optional, Sequence
@@ -184,6 +185,22 @@ class LocalExecutor:
         # so the worker->coordinator stats pipeline carries it for free)
         self.fallback_events: list[dict] = []
         self.last_fallback_reason: Optional[str] = None
+        # the owner's utils.tracing.Tracer (Engine, Coordinator and Worker
+        # hand over their own): scan_load / compile / dispatch / device_wait /
+        # operator_stats open as children of whatever span the owner has
+        # open on this thread.  None opens nothing.
+        self.tracer = None
+        # bytes table_page has put on the device, and the columns it made
+        # them from, over this executor's life (scan_load reports the deltas)
+        self.h2d_bytes = 0
+        self.columns_loaded = 0
+
+    def _span(self, name: str, **attributes):
+        """-> a context manager yielding the open Span, or None without a
+        tracer."""
+        if self.tracer is None:
+            return contextlib.nullcontext()
+        return self.tracer.span(name, **attributes)
 
     # ------------------------------------------------------------- table IO
     def table_page(
@@ -291,7 +308,13 @@ class LocalExecutor:
                     else:
                         arr = np.concatenate([arr, fill]) if n_live else fill
                     self._table_live[live_key] = n_live
-                self._table_cols[key_of(c)] = Column.from_numpy(schema.type_of(c), arr)
+                col = Column.from_numpy(schema.type_of(c), arr)
+                self._table_cols[key_of(c)] = col
+                self.columns_loaded += 1
+                self.h2d_bytes += sum(
+                    a.nbytes for a in (col.data, col.valid, col.data2)
+                    if a is not None
+                )
         page_key = (catalog, table, tuple(columns), gen, self.split, filters)
         cached = self._table_pages.get(page_key)
         if cached is not None:
@@ -331,13 +354,21 @@ class LocalExecutor:
         self.execute_events = {}
         nodes = _node_ids(plan)
         inputs = {}
-        for i, n in nodes.items():
-            if isinstance(n, TableScan):
-                inputs[str(i)] = self.table_page(
-                    n.catalog, n.table, n.column_names, n.output_types, scan_id=i
+        with self._span("scan_load") as span:
+            bytes0, loaded0, columns = self.h2d_bytes, self.columns_loaded, 0
+            for i, n in nodes.items():
+                if isinstance(n, TableScan):
+                    columns += len(n.column_names)
+                    inputs[str(i)] = self.table_page(
+                        n.catalog, n.table, n.column_names, n.output_types, scan_id=i
+                    )
+                elif isinstance(n, RemoteSource):
+                    inputs[str(i)] = remote_pages[n.fragment_id]
+            if span is not None:
+                span.attributes.update(
+                    h2d_bytes=self.h2d_bytes - bytes0, columns=columns,
+                    columns_cached=columns - (self.columns_loaded - loaded0),
                 )
-            elif isinstance(n, RemoteSource):
-                inputs[str(i)] = remote_pages[n.fragment_id]
         caps = self._learned_caps.get(plan)
         if caps is None:
             from .capcache import load_caps
@@ -439,10 +470,11 @@ class LocalExecutor:
                     0.0, wall_s * 1e3 - self.last_compile_ms
                 )
                 if self.collect_operator_stats:
-                    jax.block_until_ready([c.data for c in out_page.columns])
-                    self._record_operator_stats(
-                        nodes, required, (_time.perf_counter() - t0) * 1e3
-                    )
+                    with self._span("operator_stats"):
+                        jax.block_until_ready([c.data for c in out_page.columns])
+                        self._record_operator_stats(
+                            nodes, required, (_time.perf_counter() - t0) * 1e3
+                        )
                 return out_page
             for nid, req in overflow.items():
                 caps[nid] = _pow2(max(req, caps[nid] * 2))
@@ -708,6 +740,7 @@ class LocalExecutor:
             # A capacity-overflow retry lands here again with new caps — a
             # new SIGNATURE — so a warm-run recompile regression (q03,
             # BENCH_r05) is attributable to the tier that recompiled.
+            t_miss = _time.perf_counter()
             sig = signature_of(plan, caps)
             svc = self.compile_service or SERVICE
             # snapshot caps for the traced closure: execute()'s overflow
@@ -771,6 +804,16 @@ class LocalExecutor:
             )
             wait_ms = round(out.waited_s * 1e3, 3)
             self.last_compile_ms += wait_ms
+            if self.tracer is not None:
+                # fresh: this call's build ran (SERVICE.builds rose by it);
+                # else the service handed over, or this call waited for, a
+                # program some other execution built (cause `joined`)
+                built = out.result if out.fresh and out.status == "ready" else {}
+                self.tracer.record(
+                    "compile", t_miss, signature=sig, cause=out.cause,
+                    status=out.status, compile_s=built.get("compile_s", 0.0),
+                    cache=built.get("cache"),
+                )
             if out.status == "ready":
                 res = out.result
                 self._jit_cache[cache_key] = (res["fn"], res["holder"], sig)
@@ -820,20 +863,27 @@ class LocalExecutor:
                 return out_page, {k: int(v) for k, v in required.items()}
         fn, holder, sig = self._jit_cache[cache_key]
         t0 = _time.perf_counter()
-        try:
-            out_page, packed = fn(inputs, params)
-        except TypeError:
-            # AOT programs are pinned to one input pytree structure; a
-            # structure drift the key missed (e.g. weak-type promotion)
-            # must not fail the query — retrace with a lazy jit, counted
-            # as a cache miss.  A genuine TypeError in the traced ops
-            # re-raises from the lazy dispatch.
-            _JIT_CACHE_LOOKUPS.labels("miss").inc()
-            call, holder = _make_call(plan, dict(caps), collect)
-            fn = jax.jit(call)
-            self._jit_cache[cache_key] = (fn, holder, sig)
-            out_page, packed = fn(inputs, params)
-        vals = np.asarray(packed)  # ONE device->host transfer
+        with self._span("dispatch", signature=sig):  # enqueue; returns early
+            try:
+                out_page, packed = fn(inputs, params)
+            except TypeError:
+                # AOT programs are pinned to one input pytree structure; a
+                # structure drift the key missed (e.g. weak-type promotion)
+                # must not fail the query — retrace with a lazy jit, counted
+                # as a cache miss.  A genuine TypeError in the traced ops
+                # re-raises from the lazy dispatch.
+                _JIT_CACHE_LOOKUPS.labels("miss").inc()
+                call, holder = _make_call(plan, dict(caps), collect)
+                fn = jax.jit(call)
+                self._jit_cache[cache_key] = (fn, holder, sig)
+                out_page, packed = fn(inputs, params)
+        # the host blocks on the device here; the annotation puts the same
+        # interval into a profiler trace, on the trace's clock
+        with self._span("device_wait", signature=sig) as span, \
+                jax.profiler.TraceAnnotation("device_wait"):
+            vals = np.asarray(packed)  # ONE device->host transfer
+            if span is not None:
+                span.attributes["d2h_bytes"] = vals.nbytes
         self._note_execute(sig, _time.perf_counter() - t0)
         required = dict(zip(holder["keys"], vals.tolist()))
         return out_page, required
@@ -1025,7 +1075,11 @@ def _trace_plan(
                     ],
                     stage_c.live,
                 )
-        stage = _emit(node)
+        # the node's ops carry its name into the HLO metadata and from there
+        # into a profiler trace (pre-order ids: stable across runs).  Names
+        # only: no plan, capacity key, service key or fusion reads them.
+        with jax.named_scope(f"{type(node).__name__}#{nid_here}"):
+            stage = _emit(node)
         if hashable:
             memo[node] = (stage, _scan_offsets(node), nid_here)
         if collect_stats:

@@ -180,6 +180,13 @@ class Outcome:
     reason: the fallback-reason label for every non-ready status
     fresh:  True when THIS call created the job and waited it to
             completion (the compile wall belongs to this query).
+    cause:  why this call led to a build, for the executor's `compile`
+            span — `new_plan`: the first program of the signature's plan in
+            this service; `caps_tier`: the plan again at other capacity
+            tiers (an overflow retry, a tightened tier); `new_avals`: plan
+            and tiers built before, so the inputs' shapes, dtypes or
+            dictionaries differ; `joined`: no build of this call's — the
+            service had the program or another call was building it.
     """
 
     status: str
@@ -188,6 +195,7 @@ class Outcome:
     error: Optional[BaseException] = None
     waited_s: float = 0.0
     fresh: bool = False
+    cause: str = "joined"
 
 
 @dataclass
@@ -232,6 +240,9 @@ class CompileService:
         self._done: OrderedDict[Any, Any] = OrderedDict()
         self.breaker = breaker or SignatureBreaker()
         self.builds = 0  # total build() invocations (dedup observability)
+        # plan part of a signature ("Join+41n#1f2ab3") -> the signatures
+        # ("...@c9": plan at capacity tiers) a job was started for
+        self._built: dict[str, set] = {}
 
     def _ensure_pool(self):
         from concurrent.futures import ThreadPoolExecutor
@@ -267,6 +278,7 @@ class CompileService:
                 return Outcome("ready", result=hit)
             job = self._inflight.get(key)
             fresh = job is None
+            cause = "joined"
             if fresh:
                 if not self.breaker.allow(sig):
                     _fr.record(
@@ -277,6 +289,10 @@ class CompileService:
                     return Outcome("breaker_open", reason="breaker_open")
                 job = _Job(key=key, sig=sig, created_at=t0)
                 self._inflight[key] = job
+                seen = self._built.setdefault(sig.split("@")[0], set())
+                cause = ("new_plan" if not seen
+                         else "new_avals" if sig in seen else "caps_tier")
+                seen.add(sig)
                 COMPILE_INFLIGHT.set(len(self._inflight))
                 _fr.record(
                     "compile_start", node="compilesvc",
@@ -308,10 +324,11 @@ class CompileService:
                 if job.error is not None:
                     return Outcome(
                         "error", reason="compile_error", error=job.error,
-                        waited_s=waited, fresh=fresh,
+                        waited_s=waited, fresh=fresh, cause=cause,
                     )
                 return Outcome(
-                    "ready", result=job.result, waited_s=waited, fresh=fresh
+                    "ready", result=job.result, waited_s=waited, fresh=fresh,
+                    cause=cause,
                 )
             now = time.monotonic()
             if deadline_at is not None and now >= deadline_at:
@@ -322,7 +339,8 @@ class CompileService:
                     reason="compile_timeout", waited_s=round(waited, 3),
                 )
                 return Outcome(
-                    "timeout", reason="compile_timeout", waited_s=waited
+                    "timeout", reason="compile_timeout", waited_s=waited,
+                    cause=cause,
                 )
             if budget_at is not None and now >= budget_at:
                 _fr.record(
@@ -331,7 +349,8 @@ class CompileService:
                     reason="compile_wait", waited_s=round(waited, 3),
                 )
                 return Outcome(
-                    "pending", reason="compile_wait", waited_s=waited
+                    "pending", reason="compile_wait", waited_s=waited,
+                    cause=cause,
                 )
 
     def warm(self, key: Any, sig: str, build: Callable[[], Any]) -> bool:
@@ -412,6 +431,7 @@ class CompileService:
         """Forget done programs and breaker history (tests)."""
         with self._lock:
             self._done.clear()
+            self._built.clear()
             self.builds = 0
         self.breaker = SignatureBreaker(
             threshold=self.breaker.threshold,

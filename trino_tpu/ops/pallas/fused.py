@@ -657,6 +657,7 @@ def _fused_kernel(recipe: _Recipe, n_chunks: int, interpret: bool):
             pltpu.VMEM((nr, _DTILE), jnp.float32),
         ],
         interpret=interpret,
+        name="fused_scan",
     )
 
 

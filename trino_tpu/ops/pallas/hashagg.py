@@ -346,6 +346,7 @@ def _build_kernel(n_words: int, T: int, n_chunks: int, interpret: bool):
             pltpu.SMEM((1,), jnp.int32),
         ],
         interpret=interpret,
+        name="hash_agg",
     )
 
 

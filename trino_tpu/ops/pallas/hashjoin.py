@@ -136,6 +136,7 @@ def _probe_kernel(n_words: int, T: int, n_chunks: int, interpret: bool):
         ),
         scratch_shapes=[pltpu.SMEM((1,), jnp.int32)],
         interpret=interpret,
+        name="hash_join_probe",
     )
 
 

@@ -280,6 +280,7 @@ def _make_kernel(
         out_shape=tuple(out_shape),
         scratch_shapes=scratch,
         interpret=interpret,
+        name="seg_reduce",
     )
 
 

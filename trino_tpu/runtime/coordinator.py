@@ -1276,6 +1276,9 @@ class Coordinator:
             "done": threading.Event(),
             "spooled": spooled and bool(self.session.get("client_spool_dir")),
             "prepared": prepared,
+            # admission, on the spans' clock: `queued` runs from here to the
+            # moment the query's own thread opens its `query` span
+            "submitted_pc": time.perf_counter(),
         }
         with self._lock:
             if qid in self.queries:
@@ -1394,59 +1397,71 @@ class Coordinator:
             with self.tracer.span("query", query_id=sm.query_id) as qspan:
                 record["trace_id"] = qspan.trace_id
                 record["traceparent"] = traceparent(qspan)
+                if "submitted_pc" in record:
+                    self.tracer.record(
+                        "queued", record["submitted_pc"], qspan.start_s
+                    )
                 self._run_inner(record)
                 self.tracer.annotate(state=sm.state)
         finally:
             if self._killed:
                 return  # crash simulation: the query ends mid-flight,
                 # un-terminal and un-journaled — recovery's starting state
-            wall = time.perf_counter() - t0
-            self._m_query_seconds.observe(wall)
-            self._m_queries.labels(sm.state).inc()
-            if self.journal is not None and record.get("journaled"):
-                self.journal.append(
-                    "finish", sm.query_id, state=sm.state,
-                    error=sm.error, error_code=sm.error_code,
-                )
-            if record.get("resumed"):
-                self._m_resumed.labels(
-                    "completed" if sm.state == "FINISHED" else "failed"
-                ).inc()
-            qi = record.get("query_info") or {}
-            self.events.fire(
-                QueryEvent(
-                    "completed" if sm.state == "FINISHED" else "failed",
-                    sm.query_id,
-                    sql_text,
-                    wall,
-                    rows=len(record["result"] or []),
-                    error=sm.error,
-                    cpu_ms=float(qi.get("cpu_ms") or 0.0),
-                    peak_memory_bytes=int(qi.get("peak_memory_bytes") or 0),
-                    stage_count=int(qi.get("stage_count") or 0),
-                )
+            with self.tracer.span("finalize", query_id=sm.query_id):
+                self._finalize(record, sql_text, time.perf_counter() - t0)
+
+    def _finalize(self, record: dict, sql_text: str, wall: float) -> None:
+        """What a finished query leaves behind: metrics, the journal's
+        finish record, the completed/failed event, history, the flight
+        recorder, a post-mortem bundle.  The client may already hold the
+        answer: sm is terminal before this runs."""
+        sm: QueryStateMachine = record["sm"]
+        self._m_query_seconds.observe(wall)
+        self._m_queries.labels(sm.state).inc()
+        if self.journal is not None and record.get("journaled"):
+            self.journal.append(
+                "finish", sm.query_id, state=sm.state,
+                error=sm.error, error_code=sm.error_code,
             )
-            try:  # history must never fail a finished query
-                self.history.record(self._history_record(record, wall))
-            except Exception:
-                traceback.print_exc()
-            _fr.record(
-                "query_finish", node=self.url, query_id=sm.query_id,
-                state=sm.state, wall_ms=round(wall * 1e3, 3),
-                anomalies=[a["kind"] for a in record.get("anomalies") or []]
-                or None,
+        if record.get("resumed"):
+            self._m_resumed.labels(
+                "completed" if sm.state == "FINISHED" else "failed"
+            ).inc()
+        qi = record.get("query_info") or {}
+        self.events.fire(
+            QueryEvent(
+                "completed" if sm.state == "FINISHED" else "failed",
+                sm.query_id,
+                sql_text,
+                wall,
+                rows=len(record["result"] or []),
+                error=sm.error,
+                cpu_ms=float(qi.get("cpu_ms") or 0.0),
+                peak_memory_bytes=int(qi.get("peak_memory_bytes") or 0),
+                stage_count=int(qi.get("stage_count") or 0),
             )
-            # post-mortem bundle: typed failure or a sentinel-flagged run
-            # fans out to every node that touched the query and writes one
-            # correlated JSONL bundle under the spool dir — never fails
-            # the query it documents
-            try:
-                if sm.state == "FAILED":
-                    self._write_postmortem(record, trigger="failure")
-                elif record.get("anomalies"):
-                    self._write_postmortem(record, trigger="anomaly")
-            except Exception:
-                traceback.print_exc()
+        )
+        try:  # history must never fail a finished query
+            self.history.record(self._history_record(record, wall))
+        except Exception:
+            traceback.print_exc()
+        _fr.record(
+            "query_finish", node=self.url, query_id=sm.query_id,
+            state=sm.state, wall_ms=round(wall * 1e3, 3),
+            anomalies=[a["kind"] for a in record.get("anomalies") or []]
+            or None,
+        )
+        # post-mortem bundle: typed failure or a sentinel-flagged run
+        # fans out to every node that touched the query and writes one
+        # correlated JSONL bundle under the spool dir — never fails
+        # the query it documents
+        try:
+            if sm.state == "FAILED":
+                self._write_postmortem(record, trigger="failure")
+            elif record.get("anomalies"):
+                self._write_postmortem(record, trigger="anomaly")
+        except Exception:
+            traceback.print_exc()
 
     def _history_record(self, record: dict, wall_s: float) -> dict:
         """JSON-able completed-query snapshot for the history store: the
@@ -2146,9 +2161,10 @@ class Coordinator:
             }
             return None
         try:
-            plan = optimize(
-                self.planner.plan(record["sql"]), self.catalogs, self.session
-            )
+            with self.tracer.span("planner", preplanned=False):
+                plan = optimize(
+                    self.planner.plan(record["sql"]), self.catalogs, self.session
+                )
         except Exception:
             return None  # let the execution path raise the real error
         # _run_once reuses this plan for attempt 0 (pop: retries re-plan)
@@ -2300,13 +2316,15 @@ class Coordinator:
         # the cache-begin hook already planned attempt 0 (for the plan hash
         # + version vector); retries re-plan from scratch
         plan = record.pop("_preplanned", None)
-        if plan is None:
-            plan = optimize(
-                self.planner.plan(record["sql"]), self.catalogs, self.session
-            )
-        dplan = distribute(plan, self.catalogs, nw, self.session,
-                           connector_buckets=True)
-        fragments = fragment_plan(dplan)
+        with self.tracer.span("planner", preplanned=plan is not None) as span:
+            if plan is None:
+                plan = optimize(
+                    self.planner.plan(record["sql"]), self.catalogs, self.session
+                )
+            dplan = distribute(plan, self.catalogs, nw, self.session,
+                               connector_buckets=True)
+            fragments = fragment_plan(dplan)
+            span.attributes["fragments"] = len(fragments)
         record["columns"] = list(plan.output_names)
 
         sm.transition("STARTING")
@@ -2758,6 +2776,7 @@ class Coordinator:
                 )
 
         try:
+            t_schedule = time.perf_counter()
             non_result = [f for f in fragments if f.output_kind != "result"]
             if phased:
                 # PHASED with overlap (reference: scheduler/policy/
@@ -2818,18 +2837,6 @@ class Coordinator:
             from .worker import _stream_fetch
 
             root = frag_by_id[0]
-            executor = LocalExecutor(self.catalogs, self.default_catalog)
-            # the root stage reports operator stats like any worker task
-            executor.collect_operator_stats = True
-            # ... and honors the same compile-resilience knobs: a compile
-            # storm on the workers can queue the root fragment's build
-            # behind theirs, and the root must fall back, not wall
-            executor.compile_wait_budget_ms = int(
-                self.session.get("compile_wait_budget_ms") or 0
-            )
-            executor.compile_deadline_s = float(
-                self.session.get("compile_deadline_s") or 0.0
-            )
             if record.get("cancel"):  # e.g. memory kill during the stages
                 raise RuntimeError(
                     record.get("kill_reason") or "Query was canceled"
@@ -2870,34 +2877,59 @@ class Coordinator:
                 remote_pages[child_id] = wire_to_page(
                     blobs, list(child.root.output_types)
                 )
+            # the non-result stages posted (phased: run) and the root's
+            # inputs collected; with one worker there is neither
+            self.tracer.record(
+                "schedule", t_schedule, stages=len(non_result),
+                tasks=len(all_tasks),
+            )
             sm.transition("FINISHING")
-            if record.get("analyze"):
-                page, root_an = executor.explain_analyze(root.root, remote_pages)
-                for nid, s in root_an.items():
-                    if "ms" in s:
-                        executor.last_operator_stats.setdefault(nid, {})["ms"] = (
-                            round(s["ms"], 3)
-                        )
-            else:
-                page = executor.execute(root.root, remote_pages)
-            record["result"] = page.to_pylist()
-            # stats are pulled from the workers BEFORE cleanup deletes the
-            # tasks; a stats failure must never fail a finished query
-            try:
-                self._collect_query_info(
-                    record, fragments, ntasks, task_urls, executor,
-                    stage_times, t_query0,
+            with self.tracer.span("root_fragment", fragment_id=root.id):
+                executor = LocalExecutor(self.catalogs, self.default_catalog)
+                executor.tracer = self.tracer
+                # the root stage reports operator stats like any worker task
+                executor.collect_operator_stats = True
+                # ... and honors the same compile-resilience knobs: a compile
+                # storm on the workers can queue the root fragment's build
+                # behind theirs, and the root must fall back, not wall
+                executor.compile_wait_budget_ms = int(
+                    self.session.get("compile_wait_budget_ms") or 0
                 )
-            except Exception:
-                traceback.print_exc()
-            # anomaly sentinel scores HERE — before the EXPLAIN ANALYZE
-            # renderer reads query_info (the "-- anomaly:" footer) and
-            # before the history record is cut (flagged runs must not
-            # poison their own baseline)
-            try:
-                self._score_anomalies(record)
-            except Exception:
-                traceback.print_exc()
+                executor.compile_deadline_s = float(
+                    self.session.get("compile_deadline_s") or 0.0
+                )
+                if record.get("analyze"):
+                    page, root_an = executor.explain_analyze(
+                        root.root, remote_pages
+                    )
+                    for nid, s in root_an.items():
+                        if "ms" in s:
+                            executor.last_operator_stats.setdefault(nid, {})[
+                                "ms"
+                            ] = round(s["ms"], 3)
+                else:
+                    page = executor.execute(root.root, remote_pages)
+            with self.tracer.span("to_rows", d2h_bytes=page.nbytes) as span:
+                record["result"] = page.to_pylist()
+                span.attributes["rows"] = len(record["result"])
+            with self.tracer.span("query_info"):
+                # stats are pulled from the workers BEFORE cleanup deletes
+                # the tasks; a stats failure must never fail a finished query
+                try:
+                    self._collect_query_info(
+                        record, fragments, ntasks, task_urls, executor,
+                        stage_times, t_query0,
+                    )
+                except Exception:
+                    traceback.print_exc()
+                # anomaly sentinel scores HERE — before the EXPLAIN ANALYZE
+                # renderer reads query_info (the "-- anomaly:" footer) and
+                # before the history record is cut (flagged runs must not
+                # poison their own baseline)
+                try:
+                    self._score_anomalies(record)
+                except Exception:
+                    traceback.print_exc()
             if record.get("spooled"):
                 self._spool_result(sm.query_id, record)
             # adopt memo-miss fragment outputs into the memo_* namespace
@@ -3782,14 +3814,15 @@ def _statement_surface(coord: "Coordinator"):
             self._query_seq = 0
             self._prepared = {}
             self._tx_snapshots = None
-            from ..utils.tracing import Tracer
             from .security import AllowAllAccessControl
 
             self.access_control = getattr(
                 coord, "access_control", None
             ) or AllowAllAccessControl()
             self.user = "user"
-            self.tracer = Tracer()
+            # the coordinator's own: what this surface and the fast path's
+            # executor open nests under the statement's `query` span
+            self.tracer = coord.tracer
             # write statements through this surface invalidate the
             # COORDINATOR's caches (Engine.cache_invalidate), not a local
             # engine's — same typed hooks as runtime/dml.py
@@ -3879,7 +3912,7 @@ def _make_handler(coord: Coordinator):
         def log_message(self, *args):
             pass
 
-        def _send_json(self, code: int, obj, headers=None) -> None:
+        def _send_json(self, code: int, obj, headers=None) -> int:
             body = json.dumps(obj, default=_json_default).encode()
             self.send_response(code)
             self.send_header("Content-Type", "application/json")
@@ -3888,8 +3921,10 @@ def _make_handler(coord: Coordinator):
                 self.send_header(k, v)
             self.end_headers()
             self.wfile.write(body)
+            return len(body)
 
         def do_POST(self):
+            t_http = time.perf_counter()
             n = int(self.headers.get("Content-Length", 0))
             body = self.rfile.read(n)
             parts = self.path.strip("/").split("/")
@@ -3962,10 +3997,16 @@ def _make_handler(coord: Coordinator):
                     # client submits
                     query_id=self.headers.get("X-Trino-Query-Id") or None,
                 )
-                return self._send_json(
+                self._send_json(
                     200,
                     {"id": qid, "nextUri": f"{coord.url}/v1/statement/{qid}/0"},
                 )
+                # a root of this handler thread: admission of one statement,
+                # from the request's first byte read to the answer written
+                coord.tracer.record(
+                    "http.post", t_http, query_id=qid, body_bytes=n
+                )
+                return
             if (
                 parts[:2] == ["v1", "query"] and len(parts) >= 4
                 and parts[3] == "postmortem"
@@ -4384,22 +4425,37 @@ def _make_handler(coord: Coordinator):
                 )
             if parts[:2] == ["v1", "statement"] and len(parts) >= 4:
                 qid = parts[2]
+                t_http = time.perf_counter()
                 with coord._lock:
                     record = coord.queries.get(qid)
+
+                def answer(code: int, obj, served: bool = False) -> None:
+                    # one poll, a root of this handler thread.  `served`:
+                    # this poll carried the answer; since_finished_ms: how
+                    # long the finished answer had lain when the poll came
+                    n = self._send_json(code, obj)
+                    done_pc = record["sm"].finished_pc if record else None
+                    coord.tracer.record(
+                        "http.get", t_http, query_id=qid, served=served,
+                        body_bytes=n,
+                        since_finished_ms=None if done_pc is None
+                        else (t_http - done_pc) * 1e3,
+                    )
+
                 if record is None:
-                    return self._send_json(404, {"error": "unknown query"})
+                    return answer(404, {"error": "unknown query"})
                 sm: QueryStateMachine = record["sm"]
                 if record.get("resume_refused"):
                     # resume_policy=FAIL: a poll for a pre-restart query id
                     # gets a typed 410 GONE instead of a silent 404, so a
                     # re-attaching client surfaces COORDINATOR_RESTART
                     # rather than retrying forever
-                    return self._send_json(
+                    return answer(
                         410,
                         {"error": sm.error, "errorCode": sm.error_code},
                     )
                 if not sm.done:
-                    return self._send_json(
+                    return answer(
                         200,
                         {
                             "id": qid,
@@ -4408,7 +4464,7 @@ def _make_handler(coord: Coordinator):
                         },
                     )
                 if sm.state == "FAILED":
-                    return self._send_json(
+                    return answer(
                         200,
                         {
                             "id": qid,
@@ -4420,7 +4476,7 @@ def _make_handler(coord: Coordinator):
                         },
                     )
                 if record.get("segments") is not None:
-                    return self._send_json(
+                    return answer(
                         200,
                         {
                             "id": qid,
@@ -4434,6 +4490,7 @@ def _make_handler(coord: Coordinator):
                                 for i, seg in enumerate(record["segments"])
                             ],
                         },
+                        served=True,
                     )
                 final = {
                     "id": qid,
@@ -4447,7 +4504,7 @@ def _make_handler(coord: Coordinator):
                 for k in ("addedPrepare", "deallocatedPrepare"):
                     if record.get(k):
                         final[k] = record[k]
-                return self._send_json(200, final)
+                return answer(200, final, served=True)
             if parts[:2] == ["v1", "spooled"] and len(parts) >= 4:
                 if not parts[3].isdigit():
                     return self._send_json(404, {"error": "no such segment"})

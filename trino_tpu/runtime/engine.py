@@ -106,6 +106,9 @@ class Engine:
         # reference: OpenTelemetry spans (SqlQueryExecution.java:473)
         self.tracer = Tracer()
         add_exporters_from_env(self.tracer)
+        # the executors open their spans (scan_load, compile, dispatch,
+        # device_wait) under this engine's `execute`
+        self.executor.tracer = self._local_fallback.tracer = self.tracer
         # result & fragment caches (runtime/resultcache.py): attached by the
         # coordinator's statement surface so DML executed here invalidates
         # the coordinator's cached results; None on a plain local engine
@@ -274,7 +277,10 @@ class Engine:
         t0 = _time.perf_counter()
         try:
             with self.tracer.span("query", query_id=qid):
-                rows = self.execute_page(sql).to_pylist()
+                page = self.execute_page(sql)
+                with self.tracer.span("to_rows", d2h_bytes=page.nbytes) as span:
+                    rows = page.to_pylist()
+                    span.attributes["rows"] = len(rows)
                 self.tracer.annotate(rows=len(rows))
         except Exception as e:
             self.events.fire(

@@ -360,6 +360,7 @@ class FastPath:
             from ..exec.compiler import LocalExecutor
 
             ex = LocalExecutor(eng.catalogs, eng.default_catalog)
+            ex.tracer = eng.tracer
             eng._local_fallback = ex
         return ex
 

@@ -40,6 +40,10 @@ class QueryStateMachine:
         self.error_code: Optional[str] = None
         self.created_at = time.time()
         self.finished_at: Optional[float] = None
+        # the terminal instant on the spans' clock (time.perf_counter,
+        # utils/tracing.py): `http.get` reads from it how long a finished
+        # answer lay before a client's poll took it
+        self.finished_pc: Optional[float] = None
         self.state_changed_at = self.created_at  # /ui "in state for" column
         # entry timestamp per visited state, in visit order — the raw
         # material of the phase ledger (reference: QueryStateTimer's
@@ -75,6 +79,7 @@ class QueryStateMachine:
             self.state_history.append((new_state, self.state_changed_at))
             if new_state in TERMINAL:
                 self.finished_at = time.time()
+                self.finished_pc = time.perf_counter()
             listeners = list(self._listeners)
         for fn in listeners:  # outside the lock (reference: StateMachine.java)
             fn(new_state)

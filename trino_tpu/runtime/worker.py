@@ -785,6 +785,7 @@ class Worker:
         self.fault_injector.task_fault(task.task_id)
         task.progress()
         executor = LocalExecutor(self.catalogs, self.default_catalog)
+        executor.tracer = self.tracer  # children of this thread's `task` span
         executor.split = (req["part"], req["num_parts"])
         if req.get("split_pad_rows"):
             # split-driven scan (runtime/splits.py): this task IS one

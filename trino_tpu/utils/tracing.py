@@ -15,6 +15,13 @@ and 64-bit span_id; `traceparent(span)` encodes the standard
 and a worker joins the remote trace via `tracer.join(header)` so its task
 spans share the coordinator's trace_id (scripts/trace_dump.py stitches the
 JSONL export back into one flame summary per query).
+
+Clocks: a span's start_s/end_s are `time.perf_counter()` of this process —
+the clock benchmarks/tracered.py maps the profiler's device trace onto, so
+spans (and only spans) may be laid against device ops.  The phase ledger
+(runtime/statemachine.py, QueryStateMachine.phase_seconds) and the history
+records stamp `time.time()`: another clock, good for durations and for
+telling a human when, never for alignment with a span or a device event.
 """
 
 from __future__ import annotations
@@ -161,6 +168,25 @@ class Tracer:
         cur = self.current()
         if cur is not None:
             cur.attributes.update(attributes)
+
+    def record(self, name: str, start_s: float,
+               end_s: Optional[float] = None, **attributes) -> Span:
+        """Add a FINISHED span with explicit perf_counter times (end_s None
+        == now) as a child of this thread's current span, or export it as a
+        root when none is open.  For intervals a `with` cannot bracket: one
+        that began on another thread (`queued`: admitted by the HTTP handler,
+        started by the query thread) or whose code is not one block."""
+        span = Span(name, dict(attributes), start_s,
+                    time.perf_counter() if end_s is None else end_s,
+                    span_id=_new_span_id())
+        parent = self.current()
+        if parent is None:
+            span.trace_id = _new_trace_id()
+            self._export(span)
+        else:
+            span.trace_id, span.parent_id = parent.trace_id, parent.span_id
+            parent.children.append(span)
+        return span
 
     def join(self, traceparent_header: Optional[str]) -> bool:
         """Join a remote trace: the next ROOT span opened on this thread
