@@ -76,8 +76,15 @@ def test_drain_mid_query_zero_retries(tmp_path):
         qid = coord.submit_query(JOIN_SQL)
         assert _wait(lambda: conn.entered > 0, 60), "probe stage never started"
 
-        victim = runner.workers[1]
-        runner.drain_worker(1)
+        # the worker that holds the gated task: the split scheduler breaks
+        # ties by URL, and a worker with nothing running drains before the
+        # breaker's next look
+        index = next(
+            i for i, w in enumerate(runner.workers)
+            if any(t.state == "RUNNING" for t in list(w.tasks.values()))
+        )
+        victim = runner.workers[index]
+        runner.drain_worker(index)
         # the breaker must flip the worker to DRAINING (not QUARANTINED)
         # before we let the query proceed — no dispatch race
         det = coord.failure_detector
@@ -196,8 +203,11 @@ def test_no_progress_watchdog_kills_wedged_task(tmp_path):
         assert runner.query(JOIN_SQL) == [(expect,)]
 
         coord.session.set("task_no_progress_timeout_s", "1.0")
-        runner.inject_task_failure(worker_index=0, mode="SLOW",
-                                   delay_ms=8000, count=1)
+        # on both: the split scheduler breaks ties by URL, so which worker
+        # gets this small query's tasks depends on the ports of the day
+        for index in range(len(runner.workers)):
+            runner.inject_task_failure(worker_index=index, mode="SLOW",
+                                       delay_ms=8000, count=1)
         t0 = time.monotonic()
         assert runner.query(JOIN_SQL) == [(expect,)]
         elapsed = time.monotonic() - t0

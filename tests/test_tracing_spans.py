@@ -156,9 +156,8 @@ def test_scan_load_counts_bytes_once():
     engine.execute_page(QUERIES["q06"])
     first, second = [s.find("scan_load") for s in exporter.snapshot()
                      if s.name == "execute"]
-    resident = sum(
-        a.nbytes for col in engine.executor._table_cols.values()
-        for a in (col.data, col.valid, col.data2) if a is not None)
+    resident = engine.resident.nbytes  # the columns live in the engine's store
+    assert not engine.executor._table_cols
     assert first.attributes == {"h2d_bytes": resident, "columns": 4, "columns_cached": 0}
     assert resident > 0
     assert second.attributes == {"h2d_bytes": 0, "columns": 4, "columns_cached": 4}

@@ -68,6 +68,9 @@ class FakerConnector(Connector):
         self._rows.pop(name)
         self.generation += 1
 
+    def scan_version(self, table: str):
+        return self.generation  # rows are a function of the table's definition
+
     def estimated_row_count(self, table: str) -> int:
         return self._rows[table]
 

@@ -177,6 +177,19 @@ class IcebergConnector(Connector):
         )
         return TableSchema(table, cols)
 
+    def scan_version(self, table: str):
+        """The snapshot a scan of this ref reads: a commit from outside this
+        process moves it too.  `generation` tells a table dropped and made
+        again from the one before it (snapshot ids restart at 1); `t@<snap>`
+        names an immutable snapshot; a metadata table (`t$snapshots`) is
+        computed per scan and gives no version."""
+        base, snap, meta_table = self._parse_ref(table)
+        if meta_table is not None:
+            return None
+        generation = self.generation  # read first: a commit bumps it last
+        s = self._snapshot(base, snap)
+        return (generation, s["snapshot_id"], s["timestamp_ms"])
+
     def estimated_row_count(self, table: str) -> Optional[int]:
         base, snap, meta_table = self._parse_ref(table)
         if meta_table == "snapshots":

@@ -181,6 +181,9 @@ class MemoryConnector(Connector):
             rows = 0
         return rows
 
+    def scan_version(self, table: str):
+        return self.generation  # every write bumps it, after the data is in place
+
     def estimated_row_count(self, table: str) -> Optional[int]:
         data = self._data.get(table)
         if not data:

@@ -292,6 +292,16 @@ class Connector(abc.ABC):
                     self._txn_state = state
         return state
 
+    def scan_version(self, table: str):
+        """What a scan of `table` would read now, as an opaque hashable
+        token, or None for "cannot tell" (the default: data that can change
+        behind the engine, such as a directory of files or a remote
+        database).  Columns read at one token may answer every later scan
+        that sees the same token (exec/resident.py keeps them on the
+        device), so a connector that returns one promises that the token
+        moves with every change, and only AFTER the new data is readable."""
+        return None
+
     def write_version(self, table: str):
         """Opaque CAS token for the table's current committed state.  The
         default is the connector-wide generation counter (coarse: any write

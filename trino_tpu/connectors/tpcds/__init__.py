@@ -55,6 +55,9 @@ class TpcdsConnector(Connector):
         hi = (split.part + 1) * n // split.num_parts
         return {c: data[c][lo:hi] for c in columns}
 
+    def scan_version(self, table: str):
+        return self.scale  # generated from (table, scale): it never changes
+
     def estimated_row_count(self, table: str) -> Optional[int]:
         data = _CACHE.get((table, self.scale))
         if data is not None:

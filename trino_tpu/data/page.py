@@ -215,6 +215,14 @@ class Column:
     def capacity(self) -> int:
         return self.data.shape[0]
 
+    @property
+    def nbytes(self) -> int:
+        """Bytes of the column's arrays: what an upload puts on the device."""
+        return sum(
+            int(a.nbytes) for a in (self.data, self.valid, self.data2)
+            if a is not None
+        )
+
     @staticmethod
     def from_numpy(type_: Type, values: np.ndarray, valid: Optional[np.ndarray] = None) -> "Column":
         if isinstance(values, np.ma.MaskedArray):

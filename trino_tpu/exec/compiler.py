@@ -190,6 +190,13 @@ class LocalExecutor:
         # operator_stats open as children of whatever span the owner has
         # open on this thread.  None opens nothing.
         self.tracer = None
+        # the owner's exec.resident.ResidentStore, handed over like the
+        # tracer: table columns that can be asked for again live there and
+        # outlive this executor.  None keeps every column in _table_cols
+        # (tests, scripts, and the out-of-core executors of exec/spill.py,
+        # which exist to release HBM between slices).
+        self.resident = None
+        self._released_seen = 0  # the store's `released` at the last execute
         # bytes table_page has put on the device, and the columns it made
         # them from, over this executor's life (scan_load reports the deltas)
         self.h2d_bytes = 0
@@ -215,113 +222,52 @@ class LocalExecutor:
         uploaded lazily, once each (the scan-level projection pushdown the
         reference does via ConnectorPageSource lazy blocks).  scan_id scopes
         dynamic filters to THIS scan site (exec/dynfilter.py) and is part of
-        the cache key so filtered and unfiltered sites never share columns."""
+        the cache key so filtered and unfiltered sites never share columns.
+
+        The columns live in the owner's resident store when there is one and
+        they can be asked for again: the table's own rows (no dynamic
+        filter, no pad to a morsel or a slice) at a version the connector
+        vouches for.  Everything else stays in this executor's own
+        dictionary and dies with it."""
         conn = self.catalogs.get(catalog)
-        schema = conn.table_schema(table)
         gen = getattr(conn, "generation", 0)  # writable connectors bump this
         filters = self.scan_filters.get(scan_id, ()) if scan_id is not None else ()
-        key_of = lambda c: (catalog, table, c, gen, self.split, filters)
-        live_key = (catalog, table, gen, self.split, filters)
-        missing = [c for c in columns if key_of(c) not in self._table_cols]
-        if missing:
-            part, num_parts = self.split
-            want = list(missing) + [
-                f.column for f in filters if f.column not in missing
-            ]
-            splits = [
-                s
-                for i, s in enumerate(conn.get_splits(table, num_parts))
-                if i % num_parts == part or num_parts == 1
-            ]
-            data = conn.read_split(splits[0], want)
-            for s in splits[1:]:
-                more = conn.read_split(s, want)
-                data = {
-                    c: (
-                        np.ma.concatenate([data[c], more[c]])
-                        if isinstance(data[c], np.ma.MaskedArray)
-                        or isinstance(more[c], np.ma.MaskedArray)
-                        else np.concatenate([data[c], more[c]])
-                    )
-                    for c in want
-                }
-            if filters:
-                nrows = len(next(iter(data.values()))) if data else 0
-                cache = conn.__dict__.setdefault("_keep_mask_cache", {})
-                mask_key = (table, gen, self.split, filters)
-                keep = cache.get(mask_key)
-                if keep is None or len(keep) != nrows:
-                    keep = np.ones((nrows,), dtype=bool)
-                    for f in filters:
-                        vals = data[f.column]
-                        if f.values is not None:
-                            # dictionary-set domain (string keys): membership
-                            base = (
-                                np.ma.getdata(vals)
-                                if isinstance(vals, np.ma.MaskedArray)
-                                else vals
-                            )
-                            ok = np.isin(base, np.asarray(f.values, dtype=object))
-                            if isinstance(vals, np.ma.MaskedArray):
-                                ok &= ~np.ma.getmaskarray(vals)
-                            keep &= ok
-                        elif isinstance(vals, np.ma.MaskedArray):
-                            # NULL probe keys never equi-match: prune them too
-                            ok = (vals >= f.min) & (vals <= f.max)
-                            keep &= np.asarray(ok.filled(False))
-                        else:
-                            keep &= (vals >= f.min) & (vals <= f.max)
-                    if len(cache) >= _KEEP_MASK_CACHE_MAX:
-                        cache.clear()
-                    cache[mask_key] = keep
-                self.rows_pruned += int(nrows - keep.sum())
-                data = {c: data[c][keep] for c in missing}
-            pad_to = 1  # kernels need capacity >= 1
-            if filters:
-                # pruned capacity varies run to run: pow2 padding keeps the
-                # compiled-shape count logarithmic
-                n_after = len(next(iter(data.values()))) if data else 0
-                pad_to = 1 << max(0, (n_after - 1).bit_length())
-            if self.pad_splits and num_parts > 1 and not filters:
-                total = conn.estimated_row_count(table)
-                if total:
-                    pad_to = max(1, -(-int(total) // num_parts))
-            if self.split_pad_rows:
-                # morsel mode: a fixed capacity wins over both the filtered
-                # pow2 and the ceil(total/num_parts) pads (a filtered morsel
-                # can only shrink below it, never grow past it)
-                pad_to = max(pad_to, int(self.split_pad_rows))
-            for c in missing:
-                arr = data[c]
-                n_live = len(arr)
-                if n_live < pad_to:
-                    t = schema.type_of(c)
-                    fill = np.zeros(
-                        (pad_to - n_live,), dtype=object if t.is_string else t.np_dtype
-                    )
-                    if t.is_string:
-                        fill[:] = ""
-                    if isinstance(arr, np.ma.MaskedArray):
-                        arr = np.ma.concatenate(
-                            [arr, np.ma.MaskedArray(fill, mask=True)]
-                        )
-                    else:
-                        arr = np.concatenate([arr, fill]) if n_live else fill
+        version = None
+        if self.resident is not None and not (
+            filters or self.split_pad_rows or self.pad_splits
+        ):
+            version = conn.scan_version(table)
+        if version is not None:
+            cols, n_live = self.resident.columns(
+                conn, table, self.split, version, columns,
+                lambda missing: self._load_columns(conn, table, missing, ()),
+            )
+            # one page per scan shape, good while the store hands out the
+            # same columns: another version or a re-registered catalog makes
+            # new Column objects, and the page is made again
+            page_key = (catalog, table, tuple(columns), self.split)
+            cached = self._table_pages.get(page_key)
+            if cached is not None and all(
+                a is b for a, b in zip(cached.columns, cols)
+            ):
+                return cached
+        else:
+            key_of = lambda c: (catalog, table, c, gen, self.split, filters)
+            live_key = (catalog, table, gen, self.split, filters)
+            missing = [c for c in columns if key_of(c) not in self._table_cols]
+            if missing:
+                loaded, n_live = self._load_columns(conn, table, missing, filters)
+                for c, col in loaded.items():
+                    self._table_cols[key_of(c)] = col
+                if n_live is not None:
                     self._table_live[live_key] = n_live
-                col = Column.from_numpy(schema.type_of(c), arr)
-                self._table_cols[key_of(c)] = col
-                self.columns_loaded += 1
-                self.h2d_bytes += sum(
-                    a.nbytes for a in (col.data, col.valid, col.data2)
-                    if a is not None
-                )
-        page_key = (catalog, table, tuple(columns), gen, self.split, filters)
-        cached = self._table_pages.get(page_key)
-        if cached is not None:
-            return cached
-        cols = tuple(self._table_cols[key_of(c)] for c in columns)
+            page_key = (catalog, table, tuple(columns), gen, self.split, filters)
+            cached = self._table_pages.get(page_key)
+            if cached is not None:
+                return cached
+            cols = tuple(self._table_cols[key_of(c)] for c in columns)
+            n_live = self._table_live.get(live_key)
         live = None
-        n_live = self._table_live.get(live_key)
         if n_live is not None:
             cap = cols[0].capacity if cols else 1
             live = jnp.arange(cap, dtype=jnp.int32) < n_live
@@ -332,6 +278,102 @@ class LocalExecutor:
         # site (different `filters` key -> different object) never does
         self._table_pages[page_key] = page
         return page
+
+    def _load_columns(self, conn, table: str, missing: list, filters: tuple):
+        """Read `missing` columns of this executor's split from the
+        connector, apply the scan site's dynamic filters, pad, upload.
+        -> ({name: Column}, live rows when padded else None)."""
+        schema = conn.table_schema(table)
+        gen = getattr(conn, "generation", 0)
+        part, num_parts = self.split
+        want = list(missing) + [
+            f.column for f in filters if f.column not in missing
+        ]
+        splits = [
+            s
+            for i, s in enumerate(conn.get_splits(table, num_parts))
+            if i % num_parts == part or num_parts == 1
+        ]
+        data = conn.read_split(splits[0], want)
+        for s in splits[1:]:
+            more = conn.read_split(s, want)
+            data = {
+                c: (
+                    np.ma.concatenate([data[c], more[c]])
+                    if isinstance(data[c], np.ma.MaskedArray)
+                    or isinstance(more[c], np.ma.MaskedArray)
+                    else np.concatenate([data[c], more[c]])
+                )
+                for c in want
+            }
+        if filters:
+            nrows = len(next(iter(data.values()))) if data else 0
+            cache = conn.__dict__.setdefault("_keep_mask_cache", {})
+            mask_key = (table, gen, self.split, filters)
+            keep = cache.get(mask_key)
+            if keep is None or len(keep) != nrows:
+                keep = np.ones((nrows,), dtype=bool)
+                for f in filters:
+                    vals = data[f.column]
+                    if f.values is not None:
+                        # dictionary-set domain (string keys): membership
+                        base = (
+                            np.ma.getdata(vals)
+                            if isinstance(vals, np.ma.MaskedArray)
+                            else vals
+                        )
+                        ok = np.isin(base, np.asarray(f.values, dtype=object))
+                        if isinstance(vals, np.ma.MaskedArray):
+                            ok &= ~np.ma.getmaskarray(vals)
+                        keep &= ok
+                    elif isinstance(vals, np.ma.MaskedArray):
+                        # NULL probe keys never equi-match: prune them too
+                        ok = (vals >= f.min) & (vals <= f.max)
+                        keep &= np.asarray(ok.filled(False))
+                    else:
+                        keep &= (vals >= f.min) & (vals <= f.max)
+                if len(cache) >= _KEEP_MASK_CACHE_MAX:
+                    cache.clear()
+                cache[mask_key] = keep
+            self.rows_pruned += int(nrows - keep.sum())
+            data = {c: data[c][keep] for c in missing}
+        pad_to = 1  # kernels need capacity >= 1
+        if filters:
+            # pruned capacity varies run to run: pow2 padding keeps the
+            # compiled-shape count logarithmic
+            n_after = len(next(iter(data.values()))) if data else 0
+            pad_to = 1 << max(0, (n_after - 1).bit_length())
+        if self.pad_splits and num_parts > 1 and not filters:
+            total = conn.estimated_row_count(table)
+            if total:
+                pad_to = max(1, -(-int(total) // num_parts))
+        if self.split_pad_rows:
+            # morsel mode: a fixed capacity wins over both the filtered
+            # pow2 and the ceil(total/num_parts) pads (a filtered morsel
+            # can only shrink below it, never grow past it)
+            pad_to = max(pad_to, int(self.split_pad_rows))
+        loaded, live_rows = {}, None
+        for c in missing:
+            arr = data[c]
+            n_live = len(arr)
+            if n_live < pad_to:
+                t = schema.type_of(c)
+                fill = np.zeros(
+                    (pad_to - n_live,), dtype=object if t.is_string else t.np_dtype
+                )
+                if t.is_string:
+                    fill[:] = ""
+                if isinstance(arr, np.ma.MaskedArray):
+                    arr = np.ma.concatenate(
+                        [arr, np.ma.MaskedArray(fill, mask=True)]
+                    )
+                else:
+                    arr = np.concatenate([arr, fill]) if n_live else fill
+                live_rows = n_live
+            col = loaded[c] = Column.from_numpy(schema.type_of(c), arr)
+            self.columns_loaded += 1
+            self.h2d_bytes += col.nbytes
+        return loaded, live_rows
 
     # ------------------------------------------------------------ execution
     def execute(
@@ -356,6 +398,12 @@ class LocalExecutor:
         inputs = {}
         with self._span("scan_load") as span:
             bytes0, loaded0, columns = self.h2d_bytes, self.columns_loaded, 0
+            store = self.resident
+            if store is not None and store.released != self._released_seen:
+                # the store let tables go since this (long-lived) executor
+                # last ran: its page memo must not keep them on the device
+                self._released_seen = store.released
+                self._table_pages.clear()
             for i, n in nodes.items():
                 if isinstance(n, TableScan):
                     columns += len(n.column_names)

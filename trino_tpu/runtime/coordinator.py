@@ -38,6 +38,7 @@ from typing import Optional
 from ..connectors.spi import CatalogManager
 from ..data.page import Page
 from ..exec.compiler import LocalExecutor
+from ..exec.resident import ResidentStore
 from ..plan.distribute import distribute
 from ..plan.fragmenter import Fragment, fragment_plan
 from ..plan.optimizer import optimize
@@ -277,6 +278,9 @@ class Coordinator:
         self.events = EventListenerManager()
         self.tracer = Tracer()
         add_exporters_from_env(self.tracer)
+        # table columns on the device, for the executors this coordinator
+        # makes: the root fragment's (one per query) and the fast path's
+        self.resident = ResidentStore(self.metrics)
         # per-worker circuit breaker fed by heartbeat outcomes (reference:
         # HeartbeatFailureDetector.java:76); quarantined workers receive no
         # new dispatches and are half-open probed for automatic recovery
@@ -2887,6 +2891,7 @@ class Coordinator:
             with self.tracer.span("root_fragment", fragment_id=root.id):
                 executor = LocalExecutor(self.catalogs, self.default_catalog)
                 executor.tracer = self.tracer
+                executor.resident = self.resident  # columns outlive the query
                 # the root stage reports operator stats like any worker task
                 executor.collect_operator_stats = True
                 # ... and honors the same compile-resilience knobs: a compile
@@ -3823,6 +3828,7 @@ def _statement_surface(coord: "Coordinator"):
             # the coordinator's own: what this surface and the fast path's
             # executor open nests under the statement's `query` span
             self.tracer = coord.tracer
+            self.resident = coord.resident  # the fast path's executor scans from it
             # write statements through this surface invalidate the
             # COORDINATOR's caches (Engine.cache_invalidate), not a local
             # engine's — same typed hooks as runtime/dml.py

@@ -21,6 +21,7 @@ import numpy as np
 from ..connectors.spi import CatalogManager, ColumnSchema, Connector
 from ..data.page import Page
 from ..exec.compiler import LocalExecutor
+from ..exec.resident import ResidentStore
 from ..plan.nodes import PlanNode, TableScan, format_plan
 from ..plan.planner import Planner
 from .session import SessionProperties
@@ -109,6 +110,10 @@ class Engine:
         # the executors open their spans (scan_load, compile, dispatch,
         # device_wait) under this engine's `execute`
         self.executor.tracer = self._local_fallback.tracer = self.tracer
+        # table columns on the device, for every LocalExecutor of this
+        # engine (the SPMD executor keeps its own sharded pages)
+        self.resident = ResidentStore()
+        self._local_fallback.resident = self.resident
         # result & fragment caches (runtime/resultcache.py): attached by the
         # coordinator's statement surface so DML executed here invalidates
         # the coordinator's cached results; None on a plain local engine

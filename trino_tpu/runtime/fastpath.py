@@ -361,6 +361,7 @@ class FastPath:
 
             ex = LocalExecutor(eng.catalogs, eng.default_catalog)
             ex.tracer = eng.tracer
+            ex.resident = eng.resident
             eng._local_fallback = ex
         return ex
 

@@ -65,6 +65,7 @@ from urllib.parse import quote, unquote
 from ..connectors.spi import CatalogManager
 from ..data.page import Page
 from ..exec.compiler import LocalExecutor
+from ..exec.resident import ResidentStore
 from ..plan.serde import plan_from_json
 from ..utils import flightrecorder as _fr
 from ..utils import metrics as _metrics
@@ -295,6 +296,9 @@ class Worker:
         )
         self.tracer = Tracer()
         add_exporters_from_env(self.tracer)
+        # table columns on the device, for every task's executor: a split's
+        # columns are uploaded once, not once per task
+        self.resident = ResidentStore(self.metrics)
         # lifecycle state (reference: NodeState ACTIVE/SHUTTING_DOWN served
         # by ServerInfoResource): active -> draining -> drained.  DRAINING
         # rejects new task POSTs but keeps serving status + exchange fetches.
@@ -786,6 +790,7 @@ class Worker:
         task.progress()
         executor = LocalExecutor(self.catalogs, self.default_catalog)
         executor.tracer = self.tracer  # children of this thread's `task` span
+        executor.resident = self.resident  # this split's columns outlive the task
         executor.split = (req["part"], req["num_parts"])
         if req.get("split_pad_rows"):
             # split-driven scan (runtime/splits.py): this task IS one
@@ -1070,6 +1075,9 @@ class Worker:
         # pad slice capacities to powers of two so the P executions share
         # O(log n) jit shape classes instead of compiling P times
         executor.pad_splits = True
+        # (padded slices are also this executor's alone: table_page keeps
+        # them out of the worker's resident store, which would hold on the
+        # device what revocation asks to release)
         nbuf = out_parts if out_kind == "repartition" else 1
         buffers: dict[int, list] = {p: [] for p in range(nbuf)}
         rows_out = 0
@@ -1680,6 +1688,10 @@ def _make_handler(worker: Worker):
                             if worker.memory_pool is not None
                             else None
                         ),
+                        # device bytes the resident column store holds
+                        # (exec/resident.py): HBM that task reservations
+                        # from the pool above cannot also have
+                        "resident_bytes": worker.resident.nbytes,
                         # disk-pool reservations ride the heartbeat too —
                         # the coordinator's pressure-based spool reclaim
                         # keys off these (runtime/disk.py)
