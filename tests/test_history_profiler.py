@@ -158,8 +158,14 @@ def _tiny_plan():
 def test_local_executor_records_compile_events():
     from trino_tpu.runtime.engine import Engine
 
+    from trino_tpu.exec.compilesvc import CompileService
+
     eng = Engine()
     eng.register_catalog("tpch", TpchConnector(0.01))
+    # a service of its own: the process-global one may already hold this
+    # program from another test of the same worker (a `joined` event
+    # carries a wait, not a compile)
+    eng.executor.compile_service = CompileService()
     assert eng.execute("select count(*) from region") == [(5,)]
     ev = eng.executor.compile_events
     assert ev, "cold execute must record a compile event"

@@ -224,7 +224,12 @@ class Column:
         )
 
     @staticmethod
-    def from_numpy(type_: Type, values: np.ndarray, valid: Optional[np.ndarray] = None) -> "Column":
+    def from_numpy(
+        type_: Type, values: np.ndarray, valid: Optional[np.ndarray] = None,
+        place=jnp.asarray,
+    ) -> "Column":
+        """`place` puts a finished host array on the device(s): the default
+        device, or wherever the caller's own function lays it."""
         if isinstance(values, np.ma.MaskedArray):
             mask = np.ma.getmaskarray(values)
             fill = "" if type_.is_string else 0
@@ -234,16 +239,16 @@ class Column:
                 valid = ok if valid is None else (np.asarray(valid) & ok)
         if type_.is_array:
             codes, dictionary = Dictionary.encode_arrays(values)
-            return Column(type_, jnp.asarray(codes), None if valid is None else jnp.asarray(valid), dictionary)
+            return Column(type_, place(codes), None if valid is None else place(valid), dictionary)
         if type_.is_map:
             codes, dictionary = Dictionary.encode_objects(values, _canon_map)
-            return Column(type_, jnp.asarray(codes), None if valid is None else jnp.asarray(valid), dictionary)
+            return Column(type_, place(codes), None if valid is None else place(valid), dictionary)
         if type_.is_row:
             codes, dictionary = Dictionary.encode_objects(values, _canon_row)
-            return Column(type_, jnp.asarray(codes), None if valid is None else jnp.asarray(valid), dictionary)
+            return Column(type_, place(codes), None if valid is None else place(valid), dictionary)
         if type_.is_string:
             codes, dictionary = Dictionary.encode(values)
-            return Column(type_, jnp.asarray(codes), None if valid is None else jnp.asarray(valid), dictionary)
+            return Column(type_, place(codes), None if valid is None else place(valid), dictionary)
         if (
             type_.is_decimal
             and type_.precision > 18
@@ -259,9 +264,9 @@ class Column:
             if needs_limbs(flat):
                 lo, hi = to_limbs(flat)
                 return Column(
-                    type_, jnp.asarray(lo),
-                    None if valid is None else jnp.asarray(valid),
-                    None, jnp.asarray(hi),
+                    type_, place(lo),
+                    None if valid is None else place(valid),
+                    None, place(hi),
                 )
             values = np.asarray([0 if v is None else int(v) for v in flat],
                                 dtype=np.int64)
@@ -278,8 +283,8 @@ class Column:
                 arr = arr.astype(np.int32)
         return Column(
             type_,
-            jnp.asarray(arr),
-            None if valid is None else jnp.asarray(valid),
+            place(arr),
+            None if valid is None else place(valid),
         )
 
 

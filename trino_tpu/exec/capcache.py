@@ -51,11 +51,13 @@ def _path() -> str:
     return os.path.join(root, ".jax_cache", "caps_cache.json")
 
 
-def _key(plan, inputs: dict) -> str:
+def _key(plan, inputs: dict, scope: str = "") -> str:
+    """`scope`: what else the capacities depend on (the SPMD executor's
+    are per device, so it names its device count); "" for one device."""
     from ..plan.serde import plan_to_json
 
     shapes = sorted((k, int(p.capacity)) for k, p in inputs.items())
-    text = plan_to_json(plan) + "|" + repr(shapes)
+    text = plan_to_json(plan) + "|" + repr(shapes) + scope
     return hashlib.sha1(text.encode()).hexdigest()[:24]
 
 
@@ -70,12 +72,12 @@ def _load_file() -> dict:
     return _mem
 
 
-def load_caps(plan, inputs: dict) -> Optional[dict[int, int]]:
+def load_caps(plan, inputs: dict, scope: str = "") -> Optional[dict[int, int]]:
     """Converged capacities for (plan, input shapes), or None.  A stale hit
     (code drift renumbering nodes) is harmless: wrong caps just re-enter the
     normal overflow-retry path, which re-stores the corrected tiers."""
     try:
-        key = _key(plan, inputs)
+        key = _key(plan, inputs, scope)
     except Exception:  # unserializable plan: no persistence, no failure
         return None
     with _LOCK:
@@ -86,9 +88,9 @@ def load_caps(plan, inputs: dict) -> Optional[dict[int, int]]:
     return {int(k): int(v) for k, v in entry.items()}
 
 
-def store_caps(plan, inputs: dict, caps: dict[int, int]) -> None:
+def store_caps(plan, inputs: dict, caps: dict[int, int], scope: str = "") -> None:
     try:
-        key = _key(plan, inputs)
+        key = _key(plan, inputs, scope)
     except Exception:
         return
     entry = {str(k): int(v) for k, v in caps.items()}

@@ -279,9 +279,11 @@ class LocalExecutor:
         self._table_pages[page_key] = page
         return page
 
-    def _load_columns(self, conn, table: str, missing: list, filters: tuple):
+    def _load_columns(self, conn, table: str, missing: list, filters: tuple,
+                      place=jnp.asarray):
         """Read `missing` columns of this executor's split from the
-        connector, apply the scan site's dynamic filters, pad, upload.
+        connector, apply the scan site's dynamic filters, pad, upload
+        (`place`: a host array onto the device).
         -> ({name: Column}, live rows when padded else None)."""
         schema = conn.table_schema(table)
         gen = getattr(conn, "generation", 0)
@@ -370,10 +372,40 @@ class LocalExecutor:
                 else:
                     arr = np.concatenate([arr, fill]) if n_live else fill
                 live_rows = n_live
-            col = loaded[c] = Column.from_numpy(schema.type_of(c), arr)
+            col = loaded[c] = Column.from_numpy(schema.type_of(c), arr, place=place)
             self.columns_loaded += 1
             self.h2d_bytes += col.nbytes
         return loaded, live_rows
+
+    def _load_inputs(self, nodes, remote_pages) -> dict[str, Page]:
+        """The plan's leaf pages by node id, under one `scan_load` span."""
+        inputs = {}
+        with self._span("scan_load") as span:
+            bytes0, loaded0, columns = self.h2d_bytes, self.columns_loaded, 0
+            store = self.resident
+            if store is not None and store.released != self._released_seen:
+                # the store let tables go since this (long-lived) executor
+                # last ran: its page memo must not keep them on the device
+                self._released_seen = store.released
+                self._table_pages.clear()
+            for i, n in nodes.items():
+                if isinstance(n, TableScan):
+                    columns += len(n.column_names)
+                    inputs[str(i)] = self._scan_page(i, n)
+                elif isinstance(n, RemoteSource):
+                    inputs[str(i)] = remote_pages[n.fragment_id]
+            if span is not None:
+                span.attributes.update(
+                    h2d_bytes=self.h2d_bytes - bytes0, columns=columns,
+                    columns_cached=columns - (self.columns_loaded - loaded0),
+                )
+        return inputs
+
+    def _scan_page(self, nid: int, node: TableScan) -> Page:
+        return self.table_page(
+            node.catalog, node.table, node.column_names, node.output_types,
+            scan_id=nid,
+        )
 
     # ------------------------------------------------------------ execution
     def execute(
@@ -395,33 +427,12 @@ class LocalExecutor:
         self.last_execute_ms = 0.0
         self.execute_events = {}
         nodes = _node_ids(plan)
-        inputs = {}
-        with self._span("scan_load") as span:
-            bytes0, loaded0, columns = self.h2d_bytes, self.columns_loaded, 0
-            store = self.resident
-            if store is not None and store.released != self._released_seen:
-                # the store let tables go since this (long-lived) executor
-                # last ran: its page memo must not keep them on the device
-                self._released_seen = store.released
-                self._table_pages.clear()
-            for i, n in nodes.items():
-                if isinstance(n, TableScan):
-                    columns += len(n.column_names)
-                    inputs[str(i)] = self.table_page(
-                        n.catalog, n.table, n.column_names, n.output_types, scan_id=i
-                    )
-                elif isinstance(n, RemoteSource):
-                    inputs[str(i)] = remote_pages[n.fragment_id]
-            if span is not None:
-                span.attributes.update(
-                    h2d_bytes=self.h2d_bytes - bytes0, columns=columns,
-                    columns_cached=columns - (self.columns_loaded - loaded0),
-                )
-        caps = self._learned_caps.get(plan)
+        inputs = self._load_inputs(nodes, remote_pages)
+        caps = known = self._learned_caps.get(plan)
         if caps is None:
             from .capcache import load_caps
 
-            cached = load_caps(plan, inputs)
+            cached = load_caps(plan, inputs, self._caps_scope)
             init = self._initial_caps(nodes, inputs)
             # a cached entry from an older code version may size fewer node
             # kinds than the current tracer reads — only trust it when it
@@ -439,8 +450,7 @@ class LocalExecutor:
                 # the round-1 4.5–222s/query pathology.  Cheap eager loop,
                 # then a single full jit below.
                 for _ in range(16):
-                    with param_context(params):
-                        _, required = _trace_plan(plan, inputs, caps)
+                    _, required = self._trace_eager(plan, inputs, caps, params)
                     overflow = {
                         nid: int(req)
                         for nid, req in required.items()
@@ -507,9 +517,10 @@ class LocalExecutor:
                     if tight < caps[nid]:
                         caps[nid] = tight
                 self._learned_caps[plan] = caps
-                from .capcache import store_caps
+                if caps != known:  # learned or tightened in this run
+                    from .capcache import store_caps
 
-                store_caps(plan, inputs, caps)
+                    store_caps(plan, inputs, caps, self._caps_scope)
                 # execute wall = everything this call that wasn't compile
                 # (table IO, eager sizing, kernel dispatch); the compile
                 # side was accumulated by _run as it hit jit-cache misses
@@ -795,7 +806,7 @@ class LocalExecutor:
             # retry loop mutates its dict in place, and a compile still
             # queued in the service after a fallback must trace the tiers
             # its signature was named for
-            call, holder = _make_call(plan, dict(caps), collect)
+            call, holder = self._make_call(plan, dict(caps), collect)
 
             def build(_call=call, _holder=holder):
                 # AOT lower+compile (instead of letting the first dispatch
@@ -807,7 +818,10 @@ class LocalExecutor:
                 cost = None
                 lazy = False
                 try:
-                    fn = jitted.lower(inputs, params).compile()
+                    lowered = jitted.lower(inputs, params)
+                    if "lowered" in _holder:  # the maker wants a look at it
+                        _holder["lowered"](lowered)
+                    fn = lowered.compile()
                     cost = cost_summary(fn)
                 except Exception as exc:
                     if jax.default_backend() == "tpu":
@@ -844,7 +858,8 @@ class LocalExecutor:
 
             budget_ms = int(self.compile_wait_budget_ms or 0)
             out = svc.obtain(
-                (sig, collect, treedef, avals, policy_key()), sig, build,
+                (sig, collect, treedef, avals, policy_key()) + self._program_scope,
+                sig, build,
                 wait_budget_s=(budget_ms / 1e3) if budget_ms > 0 else None,
                 deadline_s=float(self.compile_deadline_s or 0.0),
                 injector=self.fault_injector,
@@ -901,17 +916,18 @@ class LocalExecutor:
                 self.compile_events.append(event)
                 self.fallback_events.append(dict(event))
                 t0 = _time.perf_counter()
-                with param_context(params):
-                    out_page, required = _trace_plan(
-                        plan, inputs, dict(caps), collect_stats=collect
-                    )
+                out_page, required = self._trace_eager(
+                    plan, inputs, dict(caps), params, collect
+                )
                 self._note_execute(
                     sig, _time.perf_counter() - t0, fallback=True
                 )
                 return out_page, {k: int(v) for k, v in required.items()}
         fn, holder, sig = self._jit_cache[cache_key]
         t0 = _time.perf_counter()
-        with self._span("dispatch", signature=sig):  # enqueue; returns early
+        # enqueue; returns early.  holder["dispatch"]: what the program's
+        # maker has to say about every dispatch of it (exec/spmd.py)
+        with self._span("dispatch", signature=sig, **holder.get("dispatch", {})):
             try:
                 out_page, packed = fn(inputs, params)
             except TypeError:
@@ -921,7 +937,7 @@ class LocalExecutor:
                 # as a cache miss.  A genuine TypeError in the traced ops
                 # re-raises from the lazy dispatch.
                 _JIT_CACHE_LOOKUPS.labels("miss").inc()
-                call, holder = _make_call(plan, dict(caps), collect)
+                call, holder = self._make_call(plan, dict(caps), collect)
                 fn = jax.jit(call)
                 self._jit_cache[cache_key] = (fn, holder, sig)
                 out_page, packed = fn(inputs, params)
@@ -935,6 +951,19 @@ class LocalExecutor:
         self._note_execute(sig, _time.perf_counter() - t0)
         required = dict(zip(holder["keys"], vals.tolist()))
         return out_page, required
+
+    # What a subclass that runs the same plans as another kind of program
+    # (exec/spmd.py: one shard_map over a mesh) puts in their place.
+    _program_scope: tuple = ()  # joins the compile service's key
+    _caps_scope = ""  # joins the capacity cache's key
+
+    def _make_call(self, plan: PlanNode, caps: dict[int, int], collect: bool):
+        return _make_call(plan, caps, collect)
+
+    def _trace_eager(self, plan, inputs, caps, params=(), collect=False):
+        """The plan op by op, uncompiled -> (page, required)."""
+        with param_context(params):
+            return _trace_plan(plan, inputs, caps, collect_stats=collect)
 
     def _note_execute(
         self, sig: str, seconds: float, fallback: bool = False
@@ -974,16 +1003,18 @@ def _make_call(plan: PlanNode, caps: dict[int, int], collect: bool):
     def call(pages, params=(), _holder=holder):
         with param_context(params):
             out_page, req = _trace_plan(plan, pages, caps, collect_stats=collect)
-        keys = sorted(req, key=repr)
-        _holder["keys"] = keys
-        packed = (
-            jnp.stack([jnp.asarray(req[k], jnp.int64) for k in keys])
-            if keys
-            else jnp.zeros((0,), jnp.int64)
-        )
-        return out_page, packed
+        return out_page, _pack_required(req, _holder)
 
     return call, holder
+
+
+def _pack_required(req: dict, holder: dict):
+    """One int64 vector of the counters, their order left in `holder`."""
+    keys = sorted(req, key=repr)
+    holder["keys"] = keys
+    if not keys:
+        return jnp.zeros((0,), jnp.int64)
+    return jnp.stack([jnp.asarray(req[k], jnp.int64) for k in keys])
 
 
 def _est_row_bytes(node: PlanNode) -> int:

@@ -22,22 +22,28 @@ from __future__ import annotations
 from typing import Optional, Sequence
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..connectors.spi import CatalogManager
-from ..data.page import Column, Page
-from ..parallel.exchange import AXIS
+from ..data.page import Page
+from ..ops.expr import param_context
+from ..parallel.exchange import AXIS, planned_exchanges, reckon
 from ..plan.nodes import Exchange, Join, PlanNode, TableScan, TopN
 from .compiler import (
-    _EAGER_SIZING_LIMIT, LocalExecutor, _child_ids, _node_ids, _pow2, _trace_plan,
+    LocalExecutor, _child_ids, _pack_required, _pow2, _trace_plan,
 )
 
 __all__ = ["SpmdExecutor"]
 
 
 class SpmdExecutor(LocalExecutor):
+    """LocalExecutor.execute with three things put in their SPMD form: a
+    scan's page (row ranges over the mesh), the first capacities (per
+    device, exchanges sized) and the program (one shard_map).  Programs come
+    from the compile service, capacities from the capacity cache and spans
+    open as on one device."""
+
     def __init__(
         self,
         catalogs: CatalogManager,
@@ -49,6 +55,10 @@ class SpmdExecutor(LocalExecutor):
             devices = jax.devices()
         self.devices = list(devices)
         self.mesh = Mesh(np.array(self.devices), (AXIS,))
+        # a program is compiled for its devices, capacities are per device
+        self._program_scope = ("spmd", AXIS, tuple(d.id for d in self.devices))
+        self._caps_scope = f"|spmd{len(self.devices)}"
+        # (catalog, table, columns, scan version, pad) -> the sharded page
         self._sharded_pages: dict = {}
 
     @property
@@ -60,106 +70,49 @@ class SpmdExecutor(LocalExecutor):
         """Global arrays laid out [D * cap_local]: device d owns rows
         [d*cap_local, (d+1)*cap_local); trailing pad rows are dead.
 
-        Every array is placed with a NamedSharding over the mesh, so each
-        device receives only its own row range (a plain jnp.asarray would
-        land the whole table on the first device and reshard it at every
-        dispatch).  Pages are cached per (table, columns, generation): a
-        repeated query uploads nothing."""
+        Every host column goes straight to a NamedSharding over the mesh, so
+        each device receives its own row range and no device ever holds the
+        table whole.  A page is kept while the connector vouches for the
+        version it was read at (Connector.scan_version; a connector that
+        cannot tell gets one read per executor): a repeated query uploads
+        nothing."""
         conn = self.catalogs.get(node.catalog)
         key = (node.catalog, node.table, tuple(node.column_names),
-               getattr(conn, "generation", 0), self.split_pad_rows)
-        cached = self._sharded_pages.get(key)
-        if cached is not None:
-            return cached
+               conn.scan_version(node.table), self.split_pad_rows)
+        kept = self._sharded_pages.get(key)
+        if kept is not None:
+            return kept
+        for k in [k for k in self._sharded_pages if k[:3] == key[:3]]:
+            del self._sharded_pages[k]  # read at a version that is gone
         D = self.num_devices
-        full = self.table_page(node.catalog, node.table, node.column_names, node.output_types)
-        n = full.capacity
-        cap_local = max(1, -(-n // D))
-        if self.split_pad_rows:
-            # pow2-bucket the per-device shard like the split-driven
-            # distributed path: two data scales share shard shape classes
-            pad = int(self.split_pad_rows)
-            cap_local = -(-cap_local // pad) * pad
-        total = D * cap_local
         sharding = NamedSharding(self.mesh, P(AXIS))
+        host_rows = []  # of every array placed: the scan's rows before padding
 
         def place(arr):
             host = np.asarray(arr)
-            padded = np.zeros((total,), dtype=host.dtype)
-            padded[:n] = host
+            host_rows.append(len(host))
+            cap_local = max(1, -(-len(host) // D))
+            if self.split_pad_rows:
+                # pow2-bucket the per-device shard like the split-driven
+                # distributed path: two data scales share shard shape classes
+                pad = int(self.split_pad_rows)
+                cap_local = -(-cap_local // pad) * pad
+            padded = np.zeros((D * cap_local,), dtype=host.dtype)
+            padded[: len(host)] = host
             return jax.device_put(padded, sharding)
 
-        cols = [
-            Column(
-                col.type,
-                place(col.data),
-                None if col.valid is None else place(col.valid),
-                col.dictionary,
-                None if col.data2 is None else place(col.data2),
-            )
-            for col in full.columns
-        ]
-        page = Page(tuple(cols), place(full.live_mask()))
+        cols, n_live = self._load_columns(
+            conn, node.table, list(node.column_names), (), place)
+        cols = tuple(cols[c] for c in node.column_names)
+        n = host_rows[0]
+        page = Page(cols, place(np.arange(n) < (n if n_live is None else n_live)))
         self._sharded_pages[key] = page
-        # table_page() staged the whole table on the first device: release
-        # it, or that device keeps a full copy next to its quarter
-        self._table_cols.clear()
-        self._table_pages.clear()
         return page
 
-    # -------------------------------------------------------------- execution
-    def execute(self, plan: PlanNode) -> Page:
-        nodes = _node_ids(plan)
-        scans = {i: n for i, n in nodes.items() if isinstance(n, TableScan)}
-        inputs = {str(i): self.sharded_table_page(n) for i, n in scans.items()}
-        caps = self._learned_caps.get(plan)
-        if caps is None:
-            caps = self._initial_caps_spmd(nodes, inputs)
-            total_rows = sum(p.capacity for p in inputs.values())
-            if total_rows <= _EAGER_SIZING_LIMIT:
-                # converge capacities with EAGER shard_map execution (per-op
-                # dispatch, no whole-program compile per attempt) — same
-                # rationale as LocalExecutor: each retry otherwise recompiles
-                # the whole SPMD program, which on a virtual 8-device CPU
-                # mesh costs minutes
-                for _ in range(16):
-                    _, required = self._run_spmd(plan, inputs, caps, eager=True)
-                    overflow = {
-                        nid: int(req)
-                        for nid, req in required.items()
-                        if nid in caps and int(req) > caps[nid]
-                    }
-                    if not overflow:
-                        break
-                    for nid, req in overflow.items():
-                        caps[nid] = _pow2(max(req, caps[nid] * 2))
-        # capacity bucketing (ROADMAP 2a), same as LocalExecutor.execute:
-        # quantize every fed capacity onto a pow2 tier so near-identical
-        # shapes share one SPMD program; also un-aliases the learned dict
-        # from the retry loop's in-place growth below
-        caps = {nid: _pow2(max(int(c), 1)) for nid, c in caps.items()}
-        for _ in range(14):
-            out_page, required = self._run_spmd(plan, inputs, caps)
-            for key, val in required.items():
-                if isinstance(key, int) and key < 0 and int(val) > 1:
-                    raise RuntimeError(
-                        "Scalar sub-query has returned multiple rows"
-                    )
-            overflow = {
-                nid: int(req)
-                for nid, req in required.items()
-                if nid in caps and int(req) > caps[nid]
-            }
-            if not overflow:
-                self._learned_caps[plan] = caps
-                if self.collect_operator_stats:
-                    jax.block_until_ready([c.data for c in out_page.columns])
-                    self._record_operator_stats(nodes, required)
-                return out_page
-            for nid, req in overflow.items():
-                caps[nid] = _pow2(max(req, caps[nid] * 2))
-        raise RuntimeError(f"capacity retry loop did not converge: {caps}")
+    def _scan_page(self, nid: int, node: TableScan) -> Page:
+        return self.sharded_table_page(node)
 
+    # -------------------------------------------------------------- execution
     def explain_analyze(self, plan: PlanNode, remote_pages=None):
         """SPMD EXPLAIN ANALYZE: the whole plan is ONE fused program, so
         per-operator wall time is not separable — but exact per-operator row
@@ -177,7 +130,7 @@ class SpmdExecutor(LocalExecutor):
         }
         return page, stats
 
-    def _initial_caps_spmd(self, nodes, inputs) -> dict[int, int]:
+    def _initial_caps(self, nodes, inputs) -> dict[int, int]:
         """Like LocalExecutor._initial_caps but sizes are per-device and
         Exchange nodes get bucket capacities."""
         D = self.num_devices
@@ -223,14 +176,9 @@ class SpmdExecutor(LocalExecutor):
                 return caps[nid]
             if isinstance(n, TopN):
                 return min(n.count, child_sizes[0])
-            from ..plan.nodes import Compact as _Compact
-
-            if isinstance(n, _Compact):
-                # SPMD leaves compaction points as pass-throughs (per-shard
-                # capacities already divide by D; the adaptive shrink is a
-                # LocalExecutor feature)
-                caps[nid] = _pow2(max(child_sizes[0], 1))
-                return child_sizes[0]
+            # a Compact gets no capacity: unset, _trace_plan passes it
+            # through and execute() has nothing to shrink (per-shard
+            # capacities already divide by D)
             from ..plan.nodes import Unnest, Values
 
             if isinstance(n, Values):
@@ -243,56 +191,44 @@ class SpmdExecutor(LocalExecutor):
         size_of(0, nodes[0])
         return caps
 
-    def _run_spmd(
-        self,
-        plan: PlanNode,
-        inputs: dict[str, Page],
-        caps: dict[int, int],
-        eager: bool = False,
-    ):
+    def _make_call(self, plan: PlanNode, caps: dict[int, int], collect: bool):
+        """The traced entry point: `_trace_plan` on every device's shard
+        under one shard_map.  holder["dispatch"] is what every dispatch of
+        the program says of it: its devices and — reckoned by
+        parallel/exchange.py while the plan is traced, and again over what
+        survived into the lowered program — its collectives by kind and the
+        bytes a device hands to its exchanges."""
         from jax import shard_map
 
         D = self.num_devices
-        mesh = self.mesh
-        collect = self.collect_operator_stats
+        holder: dict = {"keys": None, "dispatch": {"devices": D}}
 
-        def step(pages):
-            return _trace_plan(plan, pages, caps, D, AXIS, collect_stats=collect)
+        def step(pages, params):
+            with param_context(params):
+                return _trace_plan(plan, pages, caps, D, AXIS, collect_stats=collect)
 
-        def smap(fn):
-            return shard_map(
-                fn, mesh=mesh, in_specs=(P(AXIS),), out_specs=P(),
-                check_vma=False,
-            )
+        smapped = shard_map(
+            step, mesh=self.mesh, in_specs=(P(AXIS), P()), out_specs=P(),
+            check_vma=False,
+        )
 
-        if eager:
-            out_page, required = smap(step)(inputs)
-            return out_page, jax.device_get(required)
+        def call(pages, params=(), _holder=holder):
+            with planned_exchanges() as planned:
+                out_page, req = smapped(pages, tuple(params))
+            _holder["planned"] = planned
+            _holder["dispatch"].update(reckon(planned))
+            return out_page, _pack_required(req, _holder)
 
-        from ..ops.kernels import policy_key
+        def lowered(program, _holder=holder):
+            _holder["dispatch"].update(
+                reckon(_holder["planned"], program.as_text(debug_info=True)))
 
-        cache_key = ("spmd", plan, collect, tuple(sorted(caps.items())),
-                     tuple(sorted((k, p.capacity) for k, p in inputs.items())),
-                     policy_key())
-        if cache_key not in self._jit_cache:
-            smapped = smap(step)
-            # pack overflow counters into one vector (see LocalExecutor._run:
-            # per-scalar device_get calls each synchronise with the device)
-            holder: dict = {"keys": None}
+        holder["lowered"] = lowered  # LocalExecutor._run's build calls it
+        return call, holder
 
-            def call(pages, _holder=holder):
-                out_page, req = smapped(pages)
-                keys = sorted(req, key=repr)
-                _holder["keys"] = keys
-                packed = (
-                    jnp.stack([jnp.asarray(req[k], jnp.int64) for k in keys])
-                    if keys
-                    else jnp.zeros((0,), jnp.int64)
-                )
-                return out_page, packed
-
-            self._jit_cache[cache_key] = (jax.jit(call), holder)
-        fn, holder = self._jit_cache[cache_key]
-        out_page, packed = fn(inputs)
-        vals = np.asarray(packed)
-        return out_page, dict(zip(holder["keys"], vals.tolist()))
+    def _trace_eager(self, plan, inputs, caps, params=(), collect=False):
+        """Eager shard_map: per-op dispatch, no whole-program compile per
+        attempt (on a virtual 8-device CPU mesh each costs minutes)."""
+        call, holder = self._make_call(plan, caps, collect)
+        out_page, packed = call(inputs, params)
+        return out_page, dict(zip(holder["keys"], np.asarray(packed).tolist()))

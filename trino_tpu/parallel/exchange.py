@@ -28,7 +28,10 @@ the producing worker — and checked wherever bytes are consumed.
 
 from __future__ import annotations
 
-from typing import Optional, Sequence
+import contextlib
+import re
+import threading
+from typing import Sequence
 
 import jax
 import jax.numpy as jnp
@@ -36,7 +39,7 @@ import jax.numpy as jnp
 from ..ops.expr import ColumnVal
 from ..ops.relops import _combined_hash  # shared key hashing (join/exchange)
 
-__all__ = ["repartition", "gather_all", "AXIS"]
+__all__ = ["repartition", "gather_all", "planned_exchanges", "reckon", "AXIS"]
 
 AXIS = "workers"
 
@@ -51,6 +54,53 @@ _EXCHANGE_PLANNED_BYTES = _METRICS.counter(
     ("kind",),
 )
 
+# The same reckoning per PROGRAM: whoever traces one opens
+# planned_exchanges() around the trace (exec/spmd.py) and keeps the tally
+# with the program, so every dispatch of it can say what it moves.
+_TALLY = threading.local()
+_TAG = "xchg"  # a collective's scope is `xchg<its index in the tally>`
+
+
+@contextlib.contextmanager
+def planned_exchanges():
+    """-> the tally of what is traced on this thread inside the block: one
+    (collective, per-device bytes entering it) per collective, in trace
+    order.  `reckon` sums it up."""
+    before = getattr(_TALLY, "open", None)
+    tally = _TALLY.open = []
+    try:
+        yield tally
+    finally:
+        _TALLY.open = before
+
+
+def reckon(tally: list, lowered_text: str | None = None) -> dict:
+    """{"exchanges": {collective: how many the program holds},
+    "exchange_bytes": per-device payload entering them}.  `all_to_all` and
+    `all_gather` carry rows; `pmax_count` is the one-scalar all_gather by
+    which the devices agree on an overflow counter (latency, no bytes).
+    With the lowered program's text (debug info on) only the collectives
+    that survived into it count: a gathered column nothing reads afterwards
+    is traced and then dropped, and moves nothing."""
+    alive = range(len(tally)) if lowered_text is None else sorted(
+        {int(i) for i in re.findall(rf"\b{_TAG}(\d+)/", lowered_text)})
+    out: dict = {"exchanges": {}, "exchange_bytes": 0}
+    for i in alive:
+        collective, nbytes = tally[i]
+        out["exchanges"][collective] = out["exchanges"].get(collective, 0) + 1
+        out["exchange_bytes"] += nbytes
+    return out
+
+
+def _planned(collective: str, nbytes: int = 0):
+    """Enters one collective into the open tally -> the scope to trace it
+    under, by which `reckon` finds it again in the lowered program."""
+    tally = getattr(_TALLY, "open", None)
+    if tally is None:
+        return contextlib.nullcontext()
+    tally.append((collective, nbytes))
+    return jax.named_scope(f"{_TAG}{len(tally) - 1}")
+
 
 def pmax_count(value, axis: str):
     """Cross-device max of a row counter, agreed on by every device.
@@ -60,24 +110,21 @@ def pmax_count(value, axis: str):
     so an int64 pmax compiles on the CPU's virtual devices and is refused on
     a real multi-chip mesh.  An all_gather is data movement, which it does
     split into 32-bit halves; the max is then local."""
-    return jnp.max(jax.lax.all_gather(value, axis))
+    with _planned("pmax_count"):
+        return jnp.max(jax.lax.all_gather(value, axis))
 
 
-def _planned_bytes(cols: Sequence[ColumnVal], live: jnp.ndarray) -> int:
-    total = int(live.shape[0])  # the live mask itself (1B bool lanes)
-    for cv in cols:
-        lanes = int(cv.data.shape[0])
-        total += lanes * cv.data.dtype.itemsize
-        if cv.valid is not None:
-            total += lanes
-        if cv.data2 is not None:
-            total += lanes * cv.data2.dtype.itemsize
-    return total
+def _plan(kind: str, x: jnp.ndarray, collective: str):
+    """One array enters an exchange of `kind` through one `collective`: its
+    bytes are the lanes on this device, before any send-buffer padding.
+    -> the scope to trace the collective under."""
+    nbytes = int(x.size) * x.dtype.itemsize
+    _EXCHANGE_PLANNED_BYTES.labels(kind).inc(nbytes)
+    return _planned(collective, nbytes)
 
 
 def gather_all(cols: Sequence[ColumnVal], live: jnp.ndarray, axis: str = AXIS):
     """Replicate the local shard to every device (broadcast/gather)."""
-    _EXCHANGE_PLANNED_BYTES.labels("gather").inc(_planned_bytes(cols, live))
     out_cols = []
     for cv in cols:
         data = _flatten_gather(cv.data, axis)
@@ -88,7 +135,8 @@ def gather_all(cols: Sequence[ColumnVal], live: jnp.ndarray, axis: str = AXIS):
 
 
 def _flatten_gather(x: jnp.ndarray, axis: str) -> jnp.ndarray:
-    g = jax.lax.all_gather(x, axis)  # [D, n, ...]
+    with _plan("gather", x, "all_gather"):
+        g = jax.lax.all_gather(x, axis)  # [D, n, ...]
     return g.reshape((-1,) + g.shape[2:])
 
 
@@ -109,7 +157,6 @@ def repartition(
     n = live.shape[0]
     D = num_devices
     B = bucket_capacity
-    _EXCHANGE_PLANNED_BYTES.labels("repartition").inc(_planned_bytes(cols, live))
 
     h = _combined_hash(keys, live, n, sentinel=0)
     part = jnp.where(live, h % D, 0).astype(jnp.int32)
@@ -136,21 +183,19 @@ def repartition(
         flat = flat.at[slot].set(x_sorted, mode="drop")
         return flat[: D * B].reshape((D, B) + x_sorted.shape[1:])
 
-    sent_live = to_buckets(
-        jnp.take(live, perm) & (rank < B) & (part_s < D)
-    )
-    recv_live = jax.lax.all_to_all(sent_live, axis, split_axis=0, concat_axis=0)
-    out_live = recv_live.reshape(-1)
-
-    def route(x: jnp.ndarray) -> jnp.ndarray:
-        sent = to_buckets(jnp.take(x, perm))
-        recv = jax.lax.all_to_all(sent, axis, split_axis=0, concat_axis=0)
+    def route(x: jnp.ndarray, x_sorted: jnp.ndarray) -> jnp.ndarray:
+        sent = to_buckets(x_sorted)
+        with _plan("repartition", x, "all_to_all"):
+            recv = jax.lax.all_to_all(sent, axis, split_axis=0, concat_axis=0)
         return recv.reshape((-1,) + recv.shape[2:])
+
+    out_live = route(live, jnp.take(live, perm) & (rank < B) & (part_s < D))
 
     out_cols = []
     for cv in cols:
-        data = route(cv.data)
-        valid = None if cv.valid is None else route(cv.valid)
-        data2 = None if cv.data2 is None else route(cv.data2)
+        data, valid, data2 = (
+            None if x is None else route(x, jnp.take(x, perm))
+            for x in (cv.data, cv.valid, cv.data2)
+        )
         out_cols.append(ColumnVal(data, valid, cv.dict, cv.type, data2))
     return out_cols, out_live, required
