@@ -18,6 +18,7 @@ import re
 import sys
 import threading
 import time
+import urllib.request
 
 import jax
 import pytest
@@ -77,8 +78,11 @@ def served():
                     s.name == "http.get" and s.attributes["served"] for s in roots):
                 break
             time.sleep(0.01)
+        with urllib.request.urlopen(f"{coord.url}/metrics", timeout=10) as r:
+            metrics = r.read().decode()
         yield {"rows": rows, "qid": qid, "roots": roots,
-               "all": exporter.snapshot()}
+               "all": exporter.snapshot(), "metrics": metrics,
+               "client": client, "exporter": exporter}
     finally:
         runner.stop()
 
@@ -135,8 +139,45 @@ def test_served_http_spans_say_what_the_metrics_read(served):
     assert len(last) == 1 and last[0].attributes["since_finished_ms"] >= 0.0
     assert last[0].attributes["body_bytes"] > 0
     query = next(s for s in served["roots"] if s.name == "query")
-    # the answer lay finished from inside `query` to the poll that took it
-    assert query.start_s < last[0].start_s - last[0].attributes["since_finished_ms"] / 1e3 <= query.end_s
+    # the answer lay finished from inside `query` to the instant the handler
+    # stopped waiting for it (`held_ms` after the poll came in)
+    attrs = last[0].attributes
+    finished_s = last[0].start_s + attrs["held_ms"] / 1e3 - attrs["since_finished_ms"] / 1e3
+    assert query.start_s < finished_s <= query.end_s
+
+
+def test_served_query_is_taken_by_one_held_poll(served):
+    """The client polls at once and the coordinator holds the poll: one GET a
+    request, woken by the terminal transition, so the finished answer lies
+    for the handler's switch-in and no longer."""
+    gets = [s for s in served["roots"] if s.name == "http.get"]
+    assert len(gets) == 1 and gets[0].attributes["served"]
+    attrs = gets[0].attributes
+    assert attrs["held_ms"] > 0.0 and attrs["since_finished_ms"] >= 0.0
+    # the span starts at the handler's entry and so contains the hold
+    assert (gets[0].end_s - gets[0].start_s) * 1e3 >= attrs["held_ms"]
+    polls = {
+        m.group(1): float(m.group(2)) for m in re.finditer(
+            r'trino_tpu_statement_polls_total\{result="(\w+)"\} (\S+)',
+            served["metrics"])
+    }
+    assert polls == {"held": 1.0}
+    # a few more, so that one late wake-up on a shared machine proves nothing
+    client = served["client"]
+    lay = [attrs["since_finished_ms"]]
+    for _ in range(5):
+        client.execute(QUERIES["q06"])
+        qid = client.last_query_id
+        deadline = time.time() + 5.0
+        while time.time() < deadline:  # the handler records after it sent
+            polls = [s for s in served["exporter"].snapshot()
+                     if s.name == "http.get" and s.attributes["query_id"] == qid]
+            if polls:
+                break
+            time.sleep(0.01)
+        assert len(polls) == 1 and polls[0].attributes["served"]
+        lay.append(polls[0].attributes["since_finished_ms"])
+    assert min(lay) >= 0.0 and min(lay) < 5.0, lay
 
 
 def test_http_spans_never_land_in_another_threads_tree(served):

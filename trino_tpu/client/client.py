@@ -13,6 +13,12 @@ from urllib.parse import quote, urlsplit
 
 __all__ = ["StatementClient", "QueryFailed"]
 
+# the least time between two polls of an unfinished query.  The coordinator
+# holds an unfinished poll itself (its long poll, up to 1 s) and so spends
+# the floor for us; a server that answers "still running" at once (the
+# fleet router bridging an adoption, an older coordinator) is not spun on
+_POLL_FLOOR_S = 0.05
+
 
 class QueryFailed(Exception):
     # typed failure reason from the protocol (errorCode), when the server
@@ -23,7 +29,6 @@ class QueryFailed(Exception):
 class StatementClient:
     def __init__(
         self, server_url: Union[str, Sequence[str]],
-        poll_interval: float = 0.05,
         spooled: bool = False, shed_retries: int = 0,
         reattach: bool = True, reattach_max_elapsed_s: float = 30.0,
         total_deadline_s: float = 0.0,
@@ -65,7 +70,6 @@ class StatementClient:
             endpoints = list(server_url) or [""]
         self.endpoints = [u.rstrip("/") for u in endpoints]
         self.server_url = self.endpoints[0]
-        self.poll_interval = poll_interval
         self.spooled = spooled
         self.shed_retries = shed_retries
         self.reattach = reattach
@@ -183,6 +187,7 @@ class StatementClient:
         self.last_query_id = state.get("id")
         deadline = time.time() + timeout
         backoff = None  # live only across a re-attach streak
+        not_before = 0.0  # time.monotonic(): when the next poll may go out
         while True:
             if "segments" in state:
                 self._apply_prepared_deltas(state)
@@ -201,7 +206,13 @@ class StatementClient:
                 raise QueryFailed(f"no nextUri and no data: {state}")
             if time.time() > deadline:
                 raise TimeoutError(f"query did not finish in {timeout}s")
-            time.sleep(self.poll_interval)
+            # poll at once: a finished answer is taken when it is there.
+            # Only the poll after an unfinished one that came back early
+            # is paced
+            early = not_before - time.monotonic()
+            if early > 0:
+                time.sleep(early)
+            not_before = time.monotonic() + _POLL_FLOOR_S
             try:
                 with urllib.request.urlopen(next_uri, timeout=30) as r:
                     state = json.loads(r.read())

@@ -74,6 +74,11 @@ from .wire import wire_to_page
 
 __all__ = ["Coordinator"]
 
+# how long a GET of an unfinished statement is held before it is answered
+# "still running" with the same nextUri (reference: the statement resources'
+# maxWait, 1 s by default)
+STATEMENT_MAX_WAIT_S = 1.0
+
 # typed markers a consuming worker raises for an unreadable producer
 # (runtime/worker.py) — the captured group is the producer task id to
 # reproduce.  SPOOL_LOST = the producer's COMMITTED spool partition went
@@ -184,6 +189,14 @@ class Coordinator:
         )
         self._m_running = self.metrics.gauge(
             "trino_tpu_queries_running", "Tracked queries not yet terminal"
+        )
+        # ready: the query was terminal when the poll came; held: the poll
+        # waited and the terminal transition woke it; timeout: it waited
+        # STATEMENT_MAX_WAIT_S (or until stop()) and went back unfinished
+        self._m_polls = self.metrics.counter(
+            "trino_tpu_statement_polls_total",
+            "GETs of /v1/statement/{id} by how they were answered",
+            ("result",),
         )
         self._m_dispatched = self.metrics.counter(
             "trino_tpu_tasks_dispatched_total", "Task POSTs sent to workers"
@@ -458,9 +471,18 @@ class Coordinator:
         self._m_running.set(running)
         return self.metrics.render(extra=_metrics.GLOBAL)
 
+    def _release_held_polls(self) -> None:
+        """Wake every statement GET the handlers hold, so that no handler
+        thread outlives the server socket."""
+        with self._lock:
+            records = list(self.queries.values())
+        for record in records:
+            record["sm"].release_waiters()
+
     def stop(self) -> None:
         self._hb_stop.set()
         self.sampler.stop()
+        self._release_held_polls()
         self.httpd.shutdown()
         # release the port: a replacement coordinator must be able to bind
         # the same address (clients re-attach to an unchanged nextUri)
@@ -482,6 +504,7 @@ class Coordinator:
         self._killed = True
         self._hb_stop.set()
         self.sampler.stop()
+        self._release_held_polls()  # they drop their connections (do_GET)
         try:
             self.httpd.shutdown()
             self.httpd.server_close()
@@ -4435,32 +4458,51 @@ def _make_handler(coord: Coordinator):
                 with coord._lock:
                     record = coord.queries.get(qid)
 
+                t_answer = t_http  # later: when a held poll stopped waiting
+
                 def answer(code: int, obj, served: bool = False) -> None:
-                    # one poll, a root of this handler thread.  `served`:
-                    # this poll carried the answer; since_finished_ms: how
-                    # long the finished answer had lain when the poll came
+                    # one poll, a root of this handler thread, the hold
+                    # included.  `served`: this poll carried the answer;
+                    # held_ms: how long the handler waited for the query;
+                    # since_finished_ms: how long the finished answer had
+                    # lain when the handler began to answer
                     n = self._send_json(code, obj)
                     done_pc = record["sm"].finished_pc if record else None
                     coord.tracer.record(
                         "http.get", t_http, query_id=qid, served=served,
-                        body_bytes=n,
+                        body_bytes=n, held_ms=(t_answer - t_http) * 1e3,
                         since_finished_ms=None if done_pc is None
-                        else (t_http - done_pc) * 1e3,
+                        else (t_answer - done_pc) * 1e3,
                     )
 
                 if record is None:
                     return answer(404, {"error": "unknown query"})
                 sm: QueryStateMachine = record["sm"]
-                if record.get("resume_refused"):
+                done = sm.done
+                if done:
+                    poll = "ready"
+                else:
+                    # the long poll: hold the request until the state
+                    # machine turns terminal (not until record["done"],
+                    # which waits for _finalize's bookkeeping too)
+                    done = sm.wait_done(STATEMENT_MAX_WAIT_S)
+                    if coord._killed:
+                        return  # a dead coordinator: the connection drops
+                    t_answer = time.perf_counter()
+                    poll = "held" if done else "timeout"
+                coord._m_polls.labels(poll).inc()
+                if done and record.get("resume_refused"):
                     # resume_policy=FAIL: a poll for a pre-restart query id
                     # gets a typed 410 GONE instead of a silent 404, so a
                     # re-attaching client surfaces COORDINATOR_RESTART
-                    # rather than retrying forever
+                    # rather than retrying forever.  After the hold: the
+                    # refusal sets the flag, then fails the state machine,
+                    # and a poll between the two waits for the typed error
                     return answer(
                         410,
                         {"error": sm.error, "errorCode": sm.error_code},
                     )
-                if not sm.done:
+                if not done:
                     return answer(
                         200,
                         {

@@ -44,6 +44,9 @@ class QueryStateMachine:
         # utils/tracing.py): `http.get` reads from it how long a finished
         # answer lay before a client's poll took it
         self.finished_pc: Optional[float] = None
+        # set by the terminal transition: a held statement GET (the long
+        # poll, coordinator.py do_GET) sleeps on it instead of on a timer
+        self._terminal = threading.Event()
         self.state_changed_at = self.created_at  # /ui "in state for" column
         # entry timestamp per visited state, in visit order — the raw
         # material of the phase ledger (reference: QueryStateTimer's
@@ -80,10 +83,24 @@ class QueryStateMachine:
             if new_state in TERMINAL:
                 self.finished_at = time.time()
                 self.finished_pc = time.perf_counter()
+                self._terminal.set()
             listeners = list(self._listeners)
         for fn in listeners:  # outside the lock (reference: StateMachine.java)
             fn(new_state)
         return True
+
+    def wait_done(self, timeout: float) -> bool:
+        """Block until the state is terminal, `timeout` seconds passed or
+        `release_waiters` was called; True when terminal (reference:
+        QueryStateMachine.getStateChange, which the statement resources
+        wait on for at most maxWait)."""
+        self._terminal.wait(timeout)
+        return self.done
+
+    def release_waiters(self) -> None:
+        """Wake every `wait_done` without a transition: the server that
+        holds the waiters is going away."""
+        self._terminal.set()
 
     def phase_seconds(self) -> dict[str, float]:
         """Wall seconds spent in each visited non-terminal state; an
