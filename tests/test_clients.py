@@ -489,7 +489,18 @@ def test_the_clients_floor_sleeps_only_against_a_server_that_does_not_hold(
         time=time.time, monotonic=time.monotonic,
         sleep=lambda s: (sleeps.append(s), time.sleep(s))))
     running_answers = 3
-    gets = []
+    gets, sent = [], []
+    # when each poll leaves the client: the floor is the client's promise
+    # about its sends (when a handler thread gets to run is the machine's)
+    urlopen = client_module.urllib.request.urlopen
+
+    def timed_urlopen(req, *args, **kwargs):
+        # a poll of the stub (its nextUri, a str; the POST is a Request)
+        if isinstance(req, str) and req.startswith(url):
+            sent.append(time.monotonic())
+        return urlopen(req, *args, **kwargs)
+
+    monkeypatch.setattr(client_module.urllib.request, "urlopen", timed_urlopen)
 
     class AnswersAtOnce(BaseHTTPRequestHandler):
         def log_message(self, *args):
@@ -508,7 +519,7 @@ def test_the_clients_floor_sleeps_only_against_a_server_that_does_not_hold(
                         "nextUri": f"{url}/v1/statement/q_stub/0"})
 
         def do_GET(self):
-            gets.append(time.monotonic())
+            gets.append(self.path)
             if len(gets) <= running_answers:
                 return self._send({
                     "id": "q_stub", "stats": {"state": "RUNNING"},
@@ -529,7 +540,8 @@ def test_the_clients_floor_sleeps_only_against_a_server_that_does_not_hold(
     assert len(gets) == running_answers + 1
     assert len(sleeps) == running_answers
     assert all(0.0 < s <= client_module._POLL_FLOOR_S for s in sleeps)
-    assert min(b - a for a, b in zip(gets, gets[1:])) >= 0.9 * client_module._POLL_FLOOR_S
+    assert len(sent) == len(gets)
+    assert min(b - a for a, b in zip(sent, sent[1:])) >= 0.9 * client_module._POLL_FLOOR_S
 
     # the coordinator holds the poll itself: one GET, no sleep
     runner, conn = long_poll

@@ -32,6 +32,8 @@ from trino_tpu.runtime.history import QueryHistoryStore
 from trino_tpu.utils.profiler import PROFILER, _PCACHE_EVENTS
 
 GROUP_SQL = "select k, sum(v) as s from t group by k order by k"
+# an injected compile delay that a cold eager fallback run stays far under
+SLOW_COMPILE_MS = 20000
 
 
 def _make_engine(seed=0, n=4000):
@@ -60,21 +62,24 @@ def _make_engine(seed=0, n=4000):
 
 def test_budget_exhausted_falls_back_then_swaps_in_compiled():
     """ISSUE acceptance core: under an injected slow compile and a small
-    wait budget, a cold-signature query returns correct rows well under
-    the compile wall via fallback; once the background compile lands, the
-    next execution runs the compiled program with zero new fallbacks."""
+    wait budget, a cold-signature query returns correct rows by fallback
+    while the compile is still in flight; once the background compile
+    lands, the next execution runs the compiled program with zero new
+    fallbacks."""
     eng, expected = _make_engine(seed=0)
     eng.session.set("compile_wait_budget_ms", "200")
     inj = FaultInjector()
-    inj.arm(task_id="*", mode="COMPILE_SLOW", delay_ms=2500, count=1)
+    # far above the fallback's eager run, which is the plan's first (its
+    # per-op programs cold: ~4 s alone, more beside five busy workers)
+    inj.arm(task_id="*", mode="COMPILE_SLOW", delay_ms=SLOW_COMPILE_MS, count=1)
     eng.executor.fault_injector = inj
     fb0 = FALLBACKS.value("compile_wait")
 
-    t0 = time.perf_counter()
     rows = eng.query(GROUP_SQL)
-    wall = time.perf_counter() - t0
+    # the query is back and the injected compile has not finished: the
+    # fallback dodged the compile wall, whatever the machine's speed
+    assert eng.executor.compile_service.stats()["inflight"] == 1
     assert rows == expected
-    assert wall < 2.0, f"fallback did not dodge the 2.5s compile wall: {wall}"
     assert ("COMPILE_SLOW", "local") in inj.fired
     assert eng.executor.last_fallback_reason == "compile_wait"
     ev = eng.executor.fallback_events[-1]
@@ -86,8 +91,9 @@ def test_budget_exhausted_falls_back_then_swaps_in_compiled():
     assert snap["fallback_executes"] >= 1
     assert snap["fallbacks"].get("compile_wait", 0) >= 1
 
-    # the compile finished in the background: swap in, zero new fallbacks
-    eng.executor.compile_service.drain(timeout_s=30)
+    # the compile finishes in the background: swap in, zero new fallbacks
+    eng.executor.compile_service.drain(timeout_s=60)
+    assert eng.executor.compile_service.stats()["inflight"] == 0
     n_fallbacks = len(eng.executor.fallback_events)
     assert eng.query(GROUP_SQL) == expected
     assert len(eng.executor.fallback_events) == n_fallbacks
@@ -151,8 +157,10 @@ def test_compile_deadline_is_typed_and_never_hangs():
     t_before = COMPILE_TIMEOUTS.value()
     timeouts_before = (PROFILER.snapshot(sig) or {}).get("timeouts", 0)
 
+    build_s = 4.0
+
     def build():
-        time.sleep(2.0)
+        time.sleep(build_s)
         return "program"
 
     key = (sig, 1)
@@ -160,7 +168,7 @@ def test_compile_deadline_is_typed_and_never_hangs():
     out = svc.obtain(key, sig, build, wait_budget_s=None, deadline_s=0.3)
     wall = time.perf_counter() - t0
     assert out.status == "timeout" and out.reason == "compile_timeout"
-    assert wall < 1.5, f"deadline did not bound the wait: {wall}"
+    assert wall < build_s / 2, f"deadline did not bound the wait: {wall}"
     assert COMPILE_TIMEOUTS.value() == t_before + 1
     assert PROFILER.snapshot(sig)["timeouts"] == timeouts_before + 1
     # a late completion still lands for future swap-in
@@ -173,15 +181,17 @@ def test_executor_deadline_records_typed_compile_timeout():
     # budget 0 == wait for the compile, bounded only by the deadline
     eng.session.set("compile_deadline_s", "0.3")
     inj = FaultInjector()
-    inj.arm(task_id="*", mode="COMPILE_SLOW", delay_ms=2000, count=1)
+    inj.arm(task_id="*", mode="COMPILE_SLOW", delay_ms=SLOW_COMPILE_MS, count=1)
     eng.executor.fault_injector = inj
     t0 = time.perf_counter()
     assert eng.query(GROUP_SQL) == expected
-    assert time.perf_counter() - t0 < 1.8, "query hung past compile_deadline_s"
+    # the deadline (0.3 s) and a cold eager run, not the compile's 20 s
+    assert time.perf_counter() - t0 < SLOW_COMPILE_MS / 2e3, (
+        "query hung past compile_deadline_s")
     ev = eng.executor.fallback_events[-1]
     assert ev["reason"] == "compile_timeout"
     assert ev["error"] == "COMPILE_TIMEOUT"
-    eng.executor.compile_service.drain(timeout_s=30)
+    eng.executor.compile_service.drain(timeout_s=60)
 
 
 # -------------------------------------------------------- circuit breaker

@@ -3,10 +3,6 @@ capacities from the capacity cache, the one-chip executor's spans, and what
 its exchanges move on every dispatch — on a 4-device virtual mesh, at the
 rehearsal scale of the benchmark cell that measures it (`spmd_q12_q01`),
 through that cell's own way in and against its own plain reference.
-
-Capacities are learned the way SF1 learns them: by the compiled program's
-overflow retries (the eager sizing below `_EAGER_SIZING_LIMIT` is a
-small-input shortcut, and dispatches a cold op-by-op shard_map for minutes).
 """
 
 import os
@@ -71,14 +67,13 @@ class _Cell:
 
 @pytest.fixture(scope="module")
 def cell(tmp_path_factory):
-    from trino_tpu.exec import capcache, compiler
+    from trino_tpu.exec import capcache
 
     assert len(jax.devices()) >= 4, "conftest must provide the virtual devices"
     mp = pytest.MonkeyPatch()
     mp.setenv("TRINO_TPU_CAPS_CACHE",
               str(tmp_path_factory.mktemp("caps") / "caps_cache.json"))
     mp.setattr(capcache, "_mem", None)  # a capacity file of this module's own
-    mp.setattr(compiler, "_EAGER_SIZING_LIMIT", 0)
     try:
         yield _Cell()
     finally:
@@ -106,13 +101,19 @@ def test_first_execution_builds_through_the_service(cell, name):
     assert first["names"][:2] == ["execute", "scan_load"]
 
 
-@pytest.mark.parametrize("name", STATEMENTS)
-def test_answer_equals_the_cells_plain_reference(cell, name):
+def _against_reference(cell, name: str, rows) -> dict:
+    """compare.py's verdict on `rows` against the cell's plain reference."""
     t = cell.templates[name]
     want = loader.load_module("reference", t["reference"]).reference(
         cell.data, *traffic.validation(t).args)
-    c = compare.compare(cell.first[name]["rows"], want, t["ordered"])
-    assert want and c["exact_mismatches"] == 0, c
+    assert want
+    return compare.compare(rows, want, t["ordered"])
+
+
+@pytest.mark.parametrize("name", STATEMENTS)
+def test_answer_equals_the_cells_plain_reference(cell, name):
+    c = _against_reference(cell, name, cell.first[name]["rows"])
+    assert c["exact_mismatches"] == 0, c
     assert c["decimal_rel_err"] <= cell.config["limits"]["decimal_rel_err"], c
     # the AVGs: float64 here (the chip's limit is the configuration's)
     assert c["double_rel_err"] < 1e-12, c
@@ -183,6 +184,78 @@ def test_new_executor_finds_capacities_and_program(cell, name):
     causes = [s.attributes["cause"] for s in _flat(exporter.snapshot())
               if s.name == "compile"]
     assert causes == ["joined"]
+
+
+def _own_executor(cell, monkeypatch, tmp_path):
+    """A new executor on the cell's mesh that knows nothing: a service and
+    a capacity file of its own, its `compile` spans and a count of its
+    calls of `_trace_eager`."""
+    from trino_tpu.exec import capcache
+    from trino_tpu.exec.spmd import SpmdExecutor
+    from trino_tpu.utils.tracing import InMemorySpanExporter, Tracer
+
+    monkeypatch.setenv("TRINO_TPU_CAPS_CACHE", str(tmp_path / "caps_cache.json"))
+    monkeypatch.setattr(capcache, "_mem", None)
+    eager = []
+    real = SpmdExecutor._trace_eager
+
+    def counted(self, plan, *args, **kwargs):
+        eager.append(plan)
+        return real(self, plan, *args, **kwargs)
+
+    monkeypatch.setattr(SpmdExecutor, "_trace_eager", counted)
+    ex = SpmdExecutor(cell.engine.catalogs, "tpch", cell.engine.executor.devices)
+    ex.compile_service = CompileService()
+    ex.tracer, exporter = Tracer(), InMemorySpanExporter()
+    ex.tracer.add_exporter(exporter)
+
+    def compiles():
+        return [s.attributes for s in _flat(exporter.snapshot()) if s.name == "compile"]
+
+    return ex, eager, compiles
+
+
+def test_cold_statement_is_one_build_and_no_eager_pass(cell, monkeypatch, tmp_path, oracle):
+    """Nothing learned, nothing cached, inputs as small as they come: the
+    stats-sized capacities go straight into ONE compiled program."""
+    from tests.oracle import assert_rows_equal
+    from tests.tpch_queries import ORDERED, QUERIES
+
+    ex, eager, compiles = _own_executor(cell, monkeypatch, tmp_path)
+    plan = cell.engine.plan(QUERIES["q03"])
+    with ex.tracer.span("execute"):
+        rows = ex.execute(plan).to_pylist()
+    assert_rows_equal(rows, oracle.query(QUERIES["q03"]), ordered=ORDERED["q03"])
+    assert eager == []
+    assert ex.compile_service.builds == 1
+    assert [c["cause"] for c in compiles()] == ["new_plan"]
+    assert not ex.fallback_events
+
+
+def test_undersized_capacities_converge_through_the_compiled_loop(
+        cell, monkeypatch, tmp_path):
+    """First capacities far too small: every overflow costs one compiled
+    tier (never an eager pass), and the answer is the reference's."""
+    from trino_tpu.exec.spmd import SpmdExecutor
+
+    ex, eager, compiles = _own_executor(cell, monkeypatch, tmp_path)
+    sized = SpmdExecutor._initial_caps
+    monkeypatch.setattr(
+        SpmdExecutor, "_initial_caps",
+        lambda self, nodes, inputs: {n: 2 for n in sized(self, nodes, inputs)})
+    plan = cell.engine.plan(cell.sql("q12"))
+    with ex.tracer.span("execute"):
+        rows = ex.execute(plan).to_pylist()
+    assert _against_reference(cell, "q12", rows)["exact_mismatches"] == 0
+    assert eager == []
+    built = compiles()
+    assert len(built) == ex.compile_service.builds >= 2
+    assert [c["cause"] for c in built] == ["new_plan"] + ["caps_tier"] * (len(built) - 1)
+    assert len({c["signature"] for c in built}) == len(built)  # a tier each
+    # every tier but the last overflowed somewhere; the last holds, and is
+    # what the loop learned
+    grown = ex._learned_caps[plan]
+    assert all(c >= 2 for c in grown.values()) and max(grown.values()) > 2
 
 
 def test_capacities_are_keyed_by_device_count(cell):

@@ -275,10 +275,16 @@ def test_timeseries_endpoints_both_roles_federated(cluster):
     coord = cluster.coordinator
     _run(cluster)
     want = {coord.url} | {w.url for w in cluster.workers}
-    assert _wait(
-        lambda: want <= set(_get_json(f"{coord.url}/v1/timeseries")["nodes"]),
-        timeout=15.0, interval=0.2,
-    ), "coordinator view never federated all node lanes"
+
+    def federated():
+        # every node's lanes, `cpu_s` among them: a delta, there from a
+        # node's second tick on, and above zero once a tick saw work
+        nodes = _get_json(f"{coord.url}/v1/timeseries")["nodes"]
+        return all({"cpu_s", "rss_bytes"} <= set(nodes.get(n, ())) for n in want) \
+            and sum(v for _, v in nodes[coord.url]["cpu_s"]) > 0
+
+    assert _wait(federated, timeout=15.0, interval=0.2), (
+        "coordinator view never federated all node lanes")
 
     payload = _get_json(f"{coord.url}/v1/timeseries")
     assert payload["node"] == coord.url

@@ -124,7 +124,16 @@ def engine(tpch_tiny):
     return eng
 
 
-@pytest.mark.parametrize("name", sorted(WINDOW_QUERIES))
+# SUM over a SUM of DECIMAL(12,2) is DECIMAL(38,2): two int64 limbs, which
+# the window operator does not carry.  Strict: the gate says so the day it does.
+_DECIMAL128 = pytest.mark.xfail(
+    strict=True, raises=NotImplementedError,
+    reason="ROADMAP A6: decimal128 columns do not go through a window yet")
+
+
+@pytest.mark.parametrize("name", [
+    pytest.param(n, marks=_DECIMAL128) if n == "window_over_agg" else n
+    for n in sorted(WINDOW_QUERIES)])
 def test_window(name, engine, oracle):
     sql = WINDOW_QUERIES[name]
     got = engine.query(sql)
@@ -132,20 +141,21 @@ def test_window(name, engine, oracle):
     assert_rows_equal(got, expected, ordered=False)
 
 
-def test_window_distributed(tpch_tiny, oracle):
+@pytest.fixture(scope="module")
+def dist_engine(tpch_tiny):
     import jax
 
     from trino_tpu.connectors.tpch import TpchConnector
     from trino_tpu.runtime.engine import Engine
 
-    # 4 virtual devices: the sharding surface (repartition-by-partition-keys,
-    # per-shard windows) compiles in half the time of the 8-device mesh and
-    # exercises the same collectives; the 8-device path is covered by
-    # test_tpch_distributed and the driver's dryrun_multichip gate.
     eng = Engine(distributed=True, devices=jax.devices()[:4])
     eng.register_catalog("tpch", TpchConnector(0.01))
-    sql = WINDOW_QUERIES["whole_partition"]
-    assert_rows_equal(eng.query(sql), oracle.query(sql), ordered=False)
-    # global (unpartitioned) windows gather to one shard — distinct codepath
-    sql = WINDOW_QUERIES["global_window"]
-    assert_rows_equal(eng.query(sql), oracle.query(sql), ordered=False)
+    return eng
+
+
+# whole_partition: repartition by the partition keys, a window per shard;
+# global_window: an unpartitioned window gathers to one shard
+@pytest.mark.parametrize("name", ["whole_partition", "global_window"])
+def test_window_distributed(name, dist_engine, oracle):
+    sql = WINDOW_QUERIES[name]
+    assert_rows_equal(dist_engine.query(sql), oracle.query(sql), ordered=False)

@@ -84,10 +84,10 @@ def _node_ids(plan: PlanNode) -> dict[int, PlanNode]:
     return out
 
 
-# Below this many total input rows, capacity sizing runs eagerly (op-by-op
-# dispatch, no compile); above it, eager dispatch overhead would beat the
-# compile savings and the jitted retry loop handles growth.
-_EAGER_SIZING_LIMIT = 4_000_000
+# Read by nothing: benchmarks/tests/test_correct_spmd.py still patches the
+# name (monkeypatch.setattr raises on a missing attribute).  The next
+# `benchmark` PR drops that patch, and then this line.
+_EAGER_SIZING_LIMIT = 0
 
 # Per-connector dynamic-filter keep-mask cache size (ADVICE r3): in-process
 # multi-task runs (DistributedQueryRunner workers, TASK retries) each build a
@@ -433,33 +433,15 @@ class LocalExecutor:
             from .capcache import load_caps
 
             cached = load_caps(plan, inputs, self._caps_scope)
-            init = self._initial_caps(nodes, inputs)
-            # a cached entry from an older code version may size fewer node
-            # kinds than the current tracer reads — only trust it when it
-            # covers every currently-sized node (else KeyError mid-trace)
-            if cached is not None and set(cached) >= set(init):
+            # nothing learned: the stats-sized capacities, which the compiled
+            # program's overflow-retry loop below corrects, unless the cache
+            # has them.  A cached entry from an older code version may size
+            # fewer node kinds than the current tracer reads — only trust it
+            # when it covers every currently-sized node (else KeyError
+            # mid-trace)
+            caps = self._initial_caps(nodes, inputs)
+            if cached is not None and set(cached) >= set(caps):
                 caps = cached
-        if caps is None:
-            caps = init
-            total_rows = sum(p.capacity for p in inputs.values())
-            if total_rows <= _EAGER_SIZING_LIMIT:
-                # Converge capacities EAGERLY (op-by-op dispatch, per-op jit
-                # cache — NOT jax.disable_jit(), whose interpreted lax.sort
-                # is pathologically slow): deep plans (TPC-DS CTE trees)
-                # otherwise pay a whole-plan recompile per overflowing node —
-                # the round-1 4.5–222s/query pathology.  Cheap eager loop,
-                # then a single full jit below.
-                for _ in range(16):
-                    _, required = self._trace_eager(plan, inputs, caps, params)
-                    overflow = {
-                        nid: int(req)
-                        for nid, req in required.items()
-                        if nid in caps and int(req) > caps[nid]
-                    }
-                    if not overflow:
-                        break
-                    for nid, req in overflow.items():
-                        caps[nid] = _pow2(max(req, caps[nid] * 2))
         # capacity bucketing (ROADMAP 2a): every cap — planner-fed, stats-
         # fed, cached from an older code version, or learned — lands on a
         # pow2 tier, so near-identical shapes collapse onto ONE jit
@@ -522,7 +504,7 @@ class LocalExecutor:
 
                     store_caps(plan, inputs, caps, self._caps_scope)
                 # execute wall = everything this call that wasn't compile
-                # (table IO, eager sizing, kernel dispatch); the compile
+                # (table IO, kernel dispatch, an eager fallback); the compile
                 # side was accumulated by _run as it hit jit-cache misses
                 wall_s = _time.perf_counter() - t0
                 self.last_execute_ms = max(
@@ -797,8 +779,9 @@ class LocalExecutor:
         ).inc()
         if cache_key not in self._jit_cache:
             # A capacity-overflow retry lands here again with new caps — a
-            # new SIGNATURE — so a warm-run recompile regression (q03,
-            # BENCH_r05) is attributable to the tier that recompiled.
+            # new SIGNATURE: the signature carries the tiers, so a compile
+            # on a statement that was warm names the node whose capacity
+            # grew (profiler ledger, `compile` span), not just the plan.
             t_miss = _time.perf_counter()
             sig = signature_of(plan, caps)
             svc = self.compile_service or SERVICE
@@ -961,7 +944,8 @@ class LocalExecutor:
         return _make_call(plan, caps, collect)
 
     def _trace_eager(self, plan, inputs, caps, params=(), collect=False):
-        """The plan op by op, uncompiled -> (page, required)."""
+        """The plan op by op, uncompiled -> (page, required): what `_run`
+        falls back to when the compiled program is not to be had in time."""
         with param_context(params):
             return _trace_plan(plan, inputs, caps, collect_stats=collect)
 

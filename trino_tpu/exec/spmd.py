@@ -227,8 +227,10 @@ class SpmdExecutor(LocalExecutor):
         return call, holder
 
     def _trace_eager(self, plan, inputs, caps, params=(), collect=False):
-        """Eager shard_map: per-op dispatch, no whole-program compile per
-        attempt (on a virtual 8-device CPU mesh each costs minutes)."""
+        """`_run`'s fallback on the mesh: the same shard_map un-jitted,
+        dispatched primitive by primitive over every device — many times
+        the seconds the whole program takes to compile on a virtual CPU
+        mesh, so only for a program that is not to be had in time."""
         call, holder = self._make_call(plan, caps, collect)
         out_page, packed = call(inputs, params)
         return out_page, dict(zip(holder["keys"], np.asarray(packed).tolist()))

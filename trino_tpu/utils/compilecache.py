@@ -53,7 +53,8 @@ def enable_persistent_cache(repo_root: str | None = None) -> None:
     if not _listening:
         _listening = True
         jax.monitoring.register_event_listener(_on_event)
-    # 0.1s: the eager sizing pass dispatches hundreds of small per-op
+    # 0.1s: a plan run op by op (the compile-budget fallback, plans with
+    # host-collected aggregates) dispatches hundreds of small per-op
     # programs; on a 1-core host even "small" compiles are ~0.5s, and
     # leaving them uncached keeps repeat latency high
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.1)

@@ -4,8 +4,9 @@ The engine's unit of compilation is a whole plan fragment traced into one
 jax.jit program (exec/compiler.py), so "where did the time go" decomposes
 per *jit signature*: (plan shape, stats mode, capacity tiers, input
 shapes).  A capacity-overflow retry is a NEW signature — which is exactly
-what makes the q03-style warm regression legible: BENCH_r05's 260s warm_s
-is some named signature compiling again, not an opaque total.
+what makes a compile on a warm statement legible: minutes of "warm" wall
+are some named signature, tiers and all, compiling again, not an opaque
+total.
 
 This module is the process-global ledger behind that attribution:
 
