@@ -36,7 +36,8 @@ def _flat(spans) -> list:
 
 
 class _Cell:
-    """The cell's way in over four virtual devices, every statement run once."""
+    """The cell's way in over four virtual devices, every statement run
+    twice: at its first sizes, then at the tiers that run tightened."""
 
     def __init__(self):
         from trino_tpu.connectors.tpch import tpch_data
@@ -48,9 +49,10 @@ class _Cell:
         self.request = self.entry.client(0)
         self.data = {t: tpch_data(t, SCALE)
                      for tm in self.templates.values() for t in tm["columns"]}
-        self.first = {}
+        self.first, self.tightened = {}, {}
         for name in STATEMENTS:
             self.first[name] = self.run(name)
+            self.tightened[name] = self.run(name)
 
     def sql(self, name: str) -> str:
         return loader.sql_text(self.templates[name])
@@ -120,7 +122,19 @@ def test_answer_equals_the_cells_plain_reference(cell, name):
 
 
 @pytest.mark.parametrize("name", STATEMENTS)
-def test_second_execution_builds_nothing_and_says_what_it_moves(cell, name):
+def test_second_execution_builds_the_tightened_program(cell, name):
+    """The first run sized every Aggregate at its child and the bucket at
+    half of it; what it observed is a few groups, so the second run builds
+    the plan once more at tight tiers, and says so."""
+    second = cell.tightened[name]
+    assert second["rows"] == cell.first[name]["rows"]
+    assert second["builds"] == 1
+    causes = [s.attributes["cause"] for s in second["spans"] if s.name == "compile"]
+    assert causes == ["caps_tightened"]
+
+
+@pytest.mark.parametrize("name", STATEMENTS)
+def test_third_execution_builds_nothing_and_says_what_it_moves(cell, name):
     again = cell.run(name)
     assert again["rows"] == cell.first[name]["rows"]
     assert again["builds"] == 0
@@ -264,9 +278,10 @@ def test_capacities_are_keyed_by_device_count(cell):
     plan, inputs = _plan_and_inputs(cell, "q12")
     ex = cell.engine.executor
     assert ex._caps_scope == "|spmd4"
-    assert capcache.load_caps(plan, inputs, ex._caps_scope) == ex._learned_caps[plan]
-    assert capcache.load_caps(plan, inputs) is None  # one device: another key
-    assert capcache.load_caps(plan, inputs, "|spmd8") is None
+    # found, and settled: this process stored them, they only grow from here
+    assert capcache.load_caps(plan, inputs, ex._caps_scope) == (ex._learned_caps[plan], True)
+    assert capcache.load_caps(plan, inputs) == (None, False)  # one device: another key
+    assert capcache.load_caps(plan, inputs, "|spmd8") == (None, False)
 
 
 def test_scan_pages_lie_in_equal_shards_and_follow_scan_version(cell, monkeypatch):

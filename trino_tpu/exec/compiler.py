@@ -9,10 +9,9 @@ per-page virtual dispatch becomes a single compiled kernel per fragment.
 
 Capacity protocol (the static-shape answer to dynamic selectivity/fan-out,
 replacing the reference's growable hash tables and blocking memory futures):
-stateful nodes (join expansion, group-by) get a static capacity from
-`CapacityPlan`; the traced program returns the true required size for every
-such node; the host retries at the next power-of-two tier on overflow and
-caches the compiled program per (plan, capacities).
+size, run, grow on overflow, tighten once, persist — `LocalExecutor.execute`
+does it, exec/capcache.py's docstring describes it, and the compiled program
+is cached per (plan, capacities).
 """
 
 from __future__ import annotations
@@ -34,6 +33,7 @@ from ..ops.relops import (
     AggSpec, SortSpec, broadcast_single_row, compact_rows, equi_join,
     group_aggregate, limit_mask, sort_rows, top_n, unnest_expand,
 )
+from .capcache import TIGHTENED, load_caps, store_caps
 from ..plan.nodes import (
     Aggregate, Compact, Concat, Distinct, EnforceSingleRow, Exchange, Filter,
     Join, Limit, MatchRecognize, PlanNode, Project, RemoteSource, Sort,
@@ -146,6 +146,9 @@ class LocalExecutor:
         # executions skip the growth retries (the reference's runtime-adaptive
         # statistics feedback, AdaptivePlanner, in miniature)
         self._learned_caps: dict[PlanNode, dict[int, int]] = {}
+        # plans whose learned tiers were tightened and have not run since:
+        # their next build says so (`compile` span, cause `caps_tightened`)
+        self._tightened: set[PlanNode] = set()
         # operator-stats collection (reference: OperatorStats via
         # OperatorContext): when set, execute() reports every node's live
         # output-row count from inside the compiled program and leaves the
@@ -429,10 +432,12 @@ class LocalExecutor:
         nodes = _node_ids(plan)
         inputs = self._load_inputs(nodes, remote_pages)
         caps = known = self._learned_caps.get(plan)
+        # the protocol's one step down belongs to the run that first
+        # converges the plan: in this executor, and (capcache) this process
+        tighten = False
         if caps is None:
-            from .capcache import load_caps
-
-            cached = load_caps(plan, inputs, self._caps_scope)
+            cached, settled = load_caps(plan, inputs, self._caps_scope)
+            tighten = not settled
             # nothing learned: the stats-sized capacities, which the compiled
             # program's overflow-retry loop below corrects, unless the cache
             # has them.  A cached entry from an older code version may size
@@ -465,6 +470,10 @@ class LocalExecutor:
         # cannot trace: their outputs intern structured values on the host.
         # Run them eagerly — op-by-op dispatch with concrete arrays.
         eager_only = _has_host_aggs(plan)
+        # why a build of this call is for other tiers than the plan's last
+        tier_cause = "caps_tightened" if plan in self._tightened else "caps_tier"
+        self._tightened.discard(plan)
+        grown: set[int] = set()
         for _ in range(12):  # capacity-retry loop (jitted path)
             if eager_only:
                 with param_context(params):
@@ -473,7 +482,9 @@ class LocalExecutor:
                     )
                 required = {k: int(v) for k, v in required.items()}
             else:
-                out_page, required = self._run(plan, inputs, caps, params)
+                out_page, required = self._run(
+                    plan, inputs, caps, params, tier_cause
+                )
             for key, val in required.items():
                 if isinstance(key, int) and key < 0 and int(val) > 1:
                     raise RuntimeError(
@@ -485,23 +496,10 @@ class LocalExecutor:
                 if nid in caps and int(req) > caps[nid]
             }
             if not overflow:
-                # adaptive compaction (reference: AdaptivePlanner fed by
-                # runtime stats): Compact points whose observed surviving
-                # count collapses far below their tier get a TIGHT tier for
-                # every later run (and, via the caps cache, later processes)
-                for nid, n in nodes.items():
-                    if not isinstance(n, Compact) or nid not in caps:
-                        continue
-                    req = required.get(nid)
-                    if req is None:
-                        continue
-                    tight = _pow2(int(req) * 2 + 1024)
-                    if tight < caps[nid]:
-                        caps[nid] = tight
+                if tighten and self._tighten(nodes, caps, required, grown):
+                    self._tightened.add(plan)
                 self._learned_caps[plan] = caps
                 if caps != known:  # learned or tightened in this run
-                    from .capcache import store_caps
-
                     store_caps(plan, inputs, caps, self._caps_scope)
                 # execute wall = everything this call that wasn't compile
                 # (table IO, kernel dispatch, an eager fallback); the compile
@@ -519,7 +517,37 @@ class LocalExecutor:
                 return out_page
             for nid, req in overflow.items():
                 caps[nid] = _pow2(max(req, caps[nid] * 2))
+            grown.update(overflow)
+            tier_cause = "caps_tier"
         raise RuntimeError(f"capacity retry loop did not converge: {caps}")
+
+    @staticmethod
+    def _tighten(nodes, caps, required, grown) -> bool:
+        """The protocol's step down (reference: AdaptivePlanner fed by
+        runtime stats): kernel work scales with a node's tier, not with its
+        live rows, so a node whose observed need lies far under its tier
+        gets `_pow2(2 * need + 1024)` for every later run (and, through the
+        capacity cache, every later executor).  Every sized kind reports a
+        need that does not depend on the tier it ran at — surviving rows
+        (Compact), groups (Aggregate, Distinct), the expansion's total
+        (Join, Unnest), the fullest bucket over the devices (Exchange) — so
+        one rule and one headroom serve them all.  Two exceptions: a TopN
+        keeps its floor (its need is the radix threshold's ties, which a
+        float key can multiply; `_initial_caps` says what a wrong guess
+        costs), and a node the retry loop grew in this call stays grown (the
+        hash aggregate reports an inflated count when it gives up below its
+        tier).  -> whether any tier changed."""
+        changed = False
+        for nid, cap in caps.items():
+            need = required.get(nid)
+            if need is None or nid in grown or isinstance(nodes[nid], TopN):
+                continue
+            tight = _pow2(2 * int(need) + 1024)
+            if tight < cap:
+                caps[nid] = tight
+                TIGHTENED.labels(type(nodes[nid]).__name__).inc()
+                changed = True
+        return changed
 
     def execute_to_rows(self, plan: PlanNode) -> list[tuple]:
         return self.execute(plan).to_pylist()
@@ -640,7 +668,7 @@ class LocalExecutor:
             if isinstance(n, Compact):
                 # start as a pass-through (cap = input frame): whether this
                 # point actually compacts is learned from the first run's
-                # TRUE surviving count (the shrink in execute())
+                # TRUE surviving count (`_tighten`)
                 caps[nid] = _pow2(max(child_sizes[0], 1))
                 return caps[nid]
             if isinstance(n, TopN):
@@ -764,6 +792,7 @@ class LocalExecutor:
         inputs: dict[str, Page],
         caps: dict[int, int],
         params: tuple = (),
+        tier_cause: str = "caps_tier",
     ):
         import time as _time
 
@@ -842,7 +871,7 @@ class LocalExecutor:
             budget_ms = int(self.compile_wait_budget_ms or 0)
             out = svc.obtain(
                 (sig, collect, treedef, avals, policy_key()) + self._program_scope,
-                sig, build,
+                sig, build, tier_cause=tier_cause,
                 wait_budget_s=(budget_ms / 1e3) if budget_ms > 0 else None,
                 deadline_s=float(self.compile_deadline_s or 0.0),
                 injector=self.fault_injector,

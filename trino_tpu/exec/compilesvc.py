@@ -182,8 +182,10 @@ class Outcome:
             completion (the compile wall belongs to this query).
     cause:  why this call led to a build, for the executor's `compile`
             span — `new_plan`: the first program of the signature's plan in
-            this service; `caps_tier`: the plan again at other capacity
-            tiers (an overflow retry, a tightened tier); `new_avals`: plan
+            this service; the plan again at other capacity tiers is what
+            the caller says it is — `caps_tightened`: the tiers its first
+            converged run tightened, else `caps_tier`: an overflow retry
+            grew them, or another executor learned them; `new_avals`: plan
             and tiers built before, so the inputs' shapes, dtypes or
             dictionaries differ; `joined`: no build of this call's — the
             service had the program or another call was building it.
@@ -264,6 +266,7 @@ class CompileService:
         deadline_s: float = 0.0,
         injector=None,
         fault_task_id: str = "local",
+        tier_cause: str = "caps_tier",
     ) -> Outcome:
         """Get the compiled program for `key`, compiling via `build` on
         the pool if needed.  wait_budget_s None == wait until done (or
@@ -291,7 +294,7 @@ class CompileService:
                 self._inflight[key] = job
                 seen = self._built.setdefault(sig.split("@")[0], set())
                 cause = ("new_plan" if not seen
-                         else "new_avals" if sig in seen else "caps_tier")
+                         else "new_avals" if sig in seen else tier_cause)
                 seen.add(sig)
                 COMPILE_INFLIGHT.set(len(self._inflight))
                 _fr.record(
