@@ -324,12 +324,16 @@ def test_query_longer_than_max_wait_gets_the_same_next_uri_and_the_client_goes_o
         "nextUri": posted["nextUri"],
     }
     assert get.state["stats"]["state"] not in ("FINISHED", "FAILED")
-    assert _polls_since(coord, before) == {"ready": 0, "held": 0, "timeout": 1}
+    # that poll was counted as one that timed out (under xdist a straggler
+    # of another test may add to any kind: no exact counts by kind here)
+    assert _polls_since(coord, before)["timeout"] >= 1
     conn.gate.set()
     last = _Get(posted["nextUri"])
     assert last.done.wait(60) and last.state["data"] == LONG_POLL_ROWS
 
-    # the client: its polls time out while the gate is shut, then one is held
+    # the client: its polls time out while the gate is shut and it goes on
+    # polling; the answer then comes with a poll that was held, or, where
+    # the query finished between two polls, with one that found it ready
     conn.reset(open_gate=False)
     before = _polls(coord)
     out = {}
@@ -345,9 +349,9 @@ def test_query_longer_than_max_wait_gets_the_same_next_uri_and_the_client_goes_o
     assert t.is_alive()
     conn.gate.set()
     t.join(60)
-    assert out["result"][1] == LONG_POLL_ROWS
+    assert not t.is_alive() and out["result"][1] == LONG_POLL_ROWS
     after = _polls_since(coord, before)
-    assert after["held"] == 1 and after["ready"] == 0 and after["timeout"] >= 2
+    assert after["timeout"] >= 2 and after["held"] + after["ready"] >= 1
 
 
 @pytest.mark.parametrize(

@@ -379,3 +379,61 @@ def test_fused_dispatch_metric_increments(kernel_engine):
     finally:
         eng.session.set("pallas_interpret", "false")
     assert after > before
+
+
+# ------------------------------------------------- the fused scan's running sums
+
+
+def test_fused_group_total_past_2_24_and_2_31_is_exact():
+    """One group's integer total passes 2^24 and then 2^31 by its values.
+    Each row is under 2^14, so a 1024-row sub-chunk's f32 partial is an exact
+    integer; what carries the table is the accumulator across sub-chunks,
+    and acc + err has to stay the exact integer past f32's 2^24."""
+    import decimal
+
+    from trino_tpu.connectors.memory import MemoryConnector
+    from trino_tpu.connectors.spi import ColumnSchema
+    from trino_tpu.data.types import VARCHAR, DecimalType
+    from trino_tpu.runtime.engine import Engine
+
+    rng = np.random.default_rng(24)
+    n = 300_000
+    k = np.where(rng.random(n) < 0.9, "big", "small").astype(object)
+    v = rng.integers(1, 16_000, n).astype(np.int64)  # hundredths
+    conn = MemoryConnector()
+    conn.create_table("t", [ColumnSchema("k", VARCHAR), ColumnSchema("v", DecimalType(12, 2))])
+    conn.insert("t", {"k": k, "v": v})
+    eng = Engine(default_catalog="mem")
+    eng.register_catalog("mem", conn)
+    eng.session.set("pallas_interpret", "true")
+    before = kernels._DISPATCH.value("fused_pipeline", "pallas")
+    try:
+        rows = eng.query("select k, sum(v), count(*) from t group by k order by k")
+    finally:
+        eng.session.set("pallas_interpret", "false")
+    assert kernels._DISPATCH.value("fused_pipeline", "pallas") == before + 1
+    want = [(g, decimal.Decimal(int(v[k == g].sum())).scaleb(-2), int((k == g).sum()))
+            for g in ("big", "small")]
+    assert int(v[k == "big"].sum()) > 2**31 and 2**24 < int(v[k == "small"].sum()) < 2**31
+    assert [tuple(r) for r in rows] == want
+
+
+def test_fused_accumulator_counts_exactly_past_2_24_rows():
+    """The accumulator step alone, fed one count per sub-chunk (0..1024 live
+    rows each) for 70,000 sub-chunks, as a 60M-row table feeds it: f32 stops
+    holding odd integers at 2^24, acc + err does not."""
+    import jax
+
+    from trino_tpu.ops.pallas.fused import _accumulate
+
+    counts = np.random.default_rng(60).integers(0, 1025, 70_000)
+    assert counts.sum() > 2 * 2**24
+
+    def step(carry, part):
+        return _accumulate(*carry, part), None
+
+    zero = jnp.zeros((), jnp.float32)
+    (acc, err), _ = jax.lax.scan(step, (zero, zero), jnp.asarray(counts, jnp.float32))
+    assert acc.dtype == err.dtype == jnp.float32
+    assert float(acc) != float(counts.sum())  # the f32 sum alone has rounded
+    assert int(np.float64(acc) + np.float64(err)) == int(counts.sum())

@@ -109,7 +109,9 @@ def test_second_served_q06_uploads_nothing(served):
     assert first["columns"] == second["columns"] == 4
     # (an earlier test of this module may have made lineitem resident)
     assert first["h2d_bytes"] > 0 or first["columns_cached"] == 4
-    assert second == {"h2d_bytes": 0, "columns": 4, "columns_cached": 4}
+    assert first["source"] in ("generated", "file", "resident")
+    assert second == {"h2d_bytes": 0, "columns": 4, "columns_cached": 4,
+                      "source": "resident", "host_prepare_ms": 0.0}
     assert rows1 == rows2 and store.nbytes == resident > 0
     # ... and the executor that uploaded them is gone: the next query's has
     # an empty dictionary of its own and still finds them

@@ -199,9 +199,14 @@ def test_scan_load_counts_bytes_once():
                      if s.name == "execute"]
     resident = engine.resident.nbytes  # the columns live in the engine's store
     assert not engine.executor._table_cols
+    # where the columns came from, and the host's part of loading them
+    # (read + code + narrow, the uploads left out)
+    assert first.attributes.pop("source") in ("generated", "file")
+    assert 0 < first.attributes.pop("host_prepare_ms") < 1e3 * (first.end_s - first.start_s)
     assert first.attributes == {"h2d_bytes": resident, "columns": 4, "columns_cached": 0}
     assert resident > 0
-    assert second.attributes == {"h2d_bytes": 0, "columns": 4, "columns_cached": 4}
+    assert second.attributes == {"h2d_bytes": 0, "columns": 4, "columns_cached": 4,
+                                 "source": "resident", "host_prepare_ms": 0.0}
 
 
 # ----------------------------------------------------- (c) compile's cause

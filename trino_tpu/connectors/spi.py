@@ -12,6 +12,7 @@ from __future__ import annotations
 import abc
 import threading
 import time
+from collections.abc import Mapping
 from dataclasses import dataclass
 from typing import Optional, Sequence
 
@@ -134,10 +135,40 @@ class ColumnStats:
     null_fraction: float = 0.0
 
 
+class LazyStats(Mapping):
+    """key -> ColumnStats, each worked out by `make(key)` when it is first
+    asked for and kept.  The planner sizes relations before it prunes their
+    columns, and a connector may have to read a column to say anything of it
+    — the TPC-H connector to generate it: 16 columns of a 60M-row lineitem
+    for a statement that reads four.  Asking whether a key is there, or for
+    the keys, makes nothing."""
+
+    def __init__(self, keys, make):
+        self._keys = tuple(keys)
+        self._make = make
+        self._made: dict = {}
+
+    def __getitem__(self, key):
+        if key not in self._made:
+            if key not in self._keys:
+                raise KeyError(key)
+            self._made[key] = self._make(key)
+        return self._made[key]
+
+    def __contains__(self, key) -> bool:
+        return key in self._keys
+
+    def __iter__(self):
+        return iter(self._keys)
+
+    def __len__(self) -> int:
+        return len(self._keys)
+
+
 @dataclass(frozen=True)
 class TableStats:
     row_count: float
-    columns: dict  # name -> ColumnStats
+    columns: Mapping  # name -> ColumnStats (a dict, or LazyStats)
 
 
 # Above this row count NDV comes from a fixed-size random sample (the
@@ -261,6 +292,12 @@ class Connector(abc.ABC):
         self, split: Split, columns: Sequence[str]
     ) -> dict[str, np.ndarray]:
         """Materialize the requested columns of a split as host arrays."""
+
+    def read_split_from(self, split: Split, columns: Sequence[str]) -> tuple[dict, str]:
+        """-> (read_split's columns, where they came from): what `scan_load`
+        reports as its `source`.  "connector" unless the connector can say
+        more (the TPC-H connector: "file" or "generated")."""
+        return self.read_split(split, columns), "connector"
 
     def estimated_row_count(self, table: str) -> Optional[int]:
         """Optional stats for the cost-based optimizer."""

@@ -10,6 +10,12 @@ vs sqlite over the *same* generated rows), so spec-exact dbgen bit-equality is
 not required; distribution shape is, because the 22 queries' selectivities
 depend on it.
 
+`generate_columns` is the way in that scales: a string column is made as a
+vocabulary and codes (`Coded`), never as one Python object per row, and the
+strings that cost a pass of their own are made only when asked for
+(connectors/tpch/columns.py keeps what it returns as files).
+`generate_table` is the same data with every string column decoded.
+
 Money/rate/quantity columns are DECIMAL(12,2), the spec types (the reference
 offers both mappings via plugin/trino-tpch TpchMetadata DecimalTypeMapping;
 here decimals are the default because scaled-int64 lanes are the only way a
@@ -29,7 +35,10 @@ from ...data.types import BIGINT, DATE, DOUBLE, DecimalType, INTEGER, VARCHAR, T
 # int64 lanes make comparisons and sums exact on TPU (no native f64).
 MONEY = DecimalType(12, 2)
 
-__all__ = ["TPCH_SCHEMAS", "generate_table", "table_row_count", "SCALE_TINY"]
+__all__ = [
+    "TPCH_SCHEMAS", "Coded", "generate_columns", "generate_table",
+    "table_row_count", "SCALE_TINY",
+]
 
 SCALE_TINY = 0.01
 
@@ -183,13 +192,69 @@ def _rng(table: str, scale: float, part: int = 0) -> np.random.Generator:
     return np.random.Generator(np.random.PCG64([_SEED, table_tag, int(scale * 1e6), part]))
 
 
-def _comments(rng: np.random.Generator, n: int, nwords: int = 4) -> np.ndarray:
-    words = np.asarray(_WORDS, dtype=object)
-    picks = rng.integers(0, len(words), size=(n, nwords))
-    out = words[picks[:, 0]]
-    for i in range(1, nwords):
-        out = out + " " + words[picks[:, i]]
-    return out
+class Coded:
+    """A string column as its makers have it: `values[codes]`, never 60M
+    Python objects.  `values` is whatever vocabulary the column was drawn
+    from, in any order, used or not; `normalised` gives the form a scan
+    wants (data/page.py Dictionary.encode's: the sorted distinct values
+    that occur, and int32 codes into them)."""
+
+    __slots__ = ("values", "codes")
+
+    def __init__(self, values, codes: np.ndarray):
+        self.values = list(values)
+        self.codes = codes
+
+    def __len__(self) -> int:
+        return len(self.codes)
+
+    def decode(self) -> np.ndarray:
+        return np.asarray(self.values, dtype=object)[self.codes]
+
+    def replace(self, mask: np.ndarray, value: str) -> "Coded":
+        """The column with `value` in the rows of `mask`."""
+        codes = np.array(self.codes)
+        codes[mask] = len(self.values)
+        return Coded(self.values + [value], codes)
+
+    def normalised(self) -> tuple[np.ndarray, np.ndarray]:
+        used = np.flatnonzero(np.bincount(self.codes, minlength=len(self.values)))
+        distinct, place = np.unique(
+            np.asarray([self.values[i] for i in used], dtype=object), return_inverse=True)
+        remap = np.zeros(len(self.values), dtype=np.int32)
+        remap[used] = place
+        return distinct, remap[self.codes]
+
+
+def _formatted(fmt: str, numbers: np.ndarray) -> Coded:
+    """`fmt.format(k)` per row, formatted once per distinct k."""
+    distinct, codes = np.unique(numbers, return_inverse=True)
+    return Coded([fmt.format(k) for k in distinct.tolist()], codes)
+
+
+def _joined(*parts: Coded) -> Coded:
+    """The parts joined by single spaces, row by row; a string is built only
+    for a combination that occurs."""
+    combined = np.zeros(len(parts[0]), dtype=np.int64)
+    for part in parts:
+        combined = combined * len(part.values) + part.codes
+    distinct, codes = np.unique(combined, return_inverse=True)
+    values = []
+    for c in distinct.tolist():
+        words = []
+        for part in reversed(parts):
+            c, digit = divmod(c, len(part.values))
+            words.append(part.values[digit])
+        values.append(" ".join(reversed(words)))
+    return Coded(values, codes)
+
+
+def _comment_picks(rng: np.random.Generator, n: int, nwords: int = 4) -> np.ndarray:
+    return rng.integers(0, len(_WORDS), size=(n, nwords))
+
+
+def _comments(picks: np.ndarray) -> Coded:
+    return _joined(*[Coded(_WORDS, picks[:, i]) for i in range(picks.shape[1])])
 
 
 def _money(rng: np.random.Generator, n: int, lo: float, hi: float) -> np.ndarray:
@@ -209,8 +274,17 @@ def _supp_for_part(partkey: np.ndarray, i: np.ndarray, num_supp: int, scale: flo
     return (partkey + i * (s // 4 + (partkey - 1) // s)) % s + 1
 
 
-def generate_table(table: str, scale: float) -> dict[str, np.ndarray]:
-    """Generate a full table as {column_name: numpy array} (object dtype for strings).
+_ALL = lambda column: True  # noqa: E731
+
+
+def generate_columns(table: str, scale: float, columns=None) -> dict:
+    """{column: numpy array, or Coded for a string column} holding at least
+    `columns` (None: all of them).  One table is a few seeded streams, each
+    drawn in a fixed order, so every numeric column and every small-vocabulary
+    string column falls out of the pass that makes any one of them and is
+    returned whether asked for or not; the strings that cost a pass of their
+    own (names, addresses, phones, comments) are built only when asked.
+    Value for value what `generate_table` returns.
 
     Money/quantity columns generate as f64 (exact multiples of 0.01 at these
     magnitudes) and are scaled to DECIMAL(12,2) int64 lanes here, matching
@@ -225,7 +299,7 @@ def generate_table(table: str, scale: float) -> dict[str, np.ndarray]:
         "orders": _gen_orders,
         "lineitem": _gen_lineitem,
     }[table]
-    data = fn(scale)
+    data = fn(scale, _ALL if columns is None else set(columns).__contains__)
     schema = dict(TPCH_SCHEMAS[table])
     for c, arr in data.items():
         t = schema[c]
@@ -234,86 +308,105 @@ def generate_table(table: str, scale: float) -> dict[str, np.ndarray]:
     return data
 
 
-def _gen_region(scale: float) -> dict[str, np.ndarray]:
+def generate_table(table: str, scale: float) -> dict[str, np.ndarray]:
+    """Generate a full table as {column_name: numpy array} (object dtype for strings)."""
+    data = generate_columns(table, scale)
+    return {
+        c: data[c].decode() if isinstance(data[c], Coded) else data[c]
+        for c, _t in TPCH_SCHEMAS[table]
+    }
+
+
+def _gen_region(scale: float, want=_ALL) -> dict:
     rng = _rng("region", scale)
     return {
         "r_regionkey": np.arange(5, dtype=np.int64),
-        "r_name": np.asarray(_REGIONS, dtype=object),
-        "r_comment": _comments(rng, 5),
+        "r_name": Coded(_REGIONS, np.arange(5)),
+        "r_comment": _comments(_comment_picks(rng, 5)),
     }
 
 
-def _gen_nation(scale: float) -> dict[str, np.ndarray]:
+def _gen_nation(scale: float, want=_ALL) -> dict:
     rng = _rng("nation", scale)
     return {
         "n_nationkey": np.arange(25, dtype=np.int64),
-        "n_name": np.asarray([n for n, _ in _NATIONS], dtype=object),
+        "n_name": Coded([n for n, _ in _NATIONS], np.arange(25)),
         "n_regionkey": np.asarray([r for _, r in _NATIONS], dtype=np.int64),
-        "n_comment": _comments(rng, 25),
+        "n_comment": _comments(_comment_picks(rng, 25)),
     }
 
 
-def _gen_supplier(scale: float) -> dict[str, np.ndarray]:
+def _gen_supplier(scale: float, want=_ALL) -> dict:
     n = table_row_count("supplier", scale)
     rng = _rng("supplier", scale)
     key = np.arange(1, n + 1, dtype=np.int64)
     nation = rng.integers(0, 25, size=n).astype(np.int64)
-    comments = _comments(rng, n)
+    comment_picks = _comment_picks(rng, n)
     # Q16: some suppliers have 'Customer ... Complaints' comments (spec: 5 per SF*10000/2... keep ~0.05%)
     bad = rng.random(n) < 0.0005
-    comments = comments.copy()
-    comments[bad] = "take Customer heed Complaints carefully"
-    phone = _phones(rng, nation)
-    return {
+    phone = _phone_draws(rng, nation)
+    address_picks = _comment_picks(rng, n, 2)
+    out = {
         "s_suppkey": key,
-        "s_name": np.asarray([f"Supplier#{k:09d}" for k in key], dtype=object),
-        "s_address": _comments(rng, n, 2),
         "s_nationkey": nation,
-        "s_phone": phone,
         "s_acctbal": _money(rng, n, -999.99, 9999.99),
-        "s_comment": comments,
     }
+    if want("s_name"):
+        out["s_name"] = _formatted("Supplier#{:09d}", key)
+    if want("s_address"):
+        out["s_address"] = _comments(address_picks)
+    if want("s_phone"):
+        out["s_phone"] = _phones(*phone)
+    if want("s_comment"):
+        out["s_comment"] = _comments(comment_picks).replace(
+            bad, "take Customer heed Complaints carefully")
+    return out
 
 
-def _phones(rng: np.random.Generator, nation: np.ndarray) -> np.ndarray:
+def _phone_draws(rng: np.random.Generator, nation: np.ndarray) -> tuple:
     n = len(nation)
     cc = (nation + 10).astype(np.int64)
     a = rng.integers(100, 1000, size=n)
     b = rng.integers(100, 1000, size=n)
     c = rng.integers(1000, 10000, size=n)
-    return np.asarray([f"{cc[i]}-{a[i]}-{b[i]}-{c[i]}" for i in range(n)], dtype=object)
+    return cc, a, b, c
 
 
-def _gen_part(scale: float) -> dict[str, np.ndarray]:
+def _phones(cc, a, b, c) -> np.ndarray:
+    return np.asarray(
+        [f"{cc[i]}-{a[i]}-{b[i]}-{c[i]}" for i in range(len(cc))], dtype=object)
+
+
+def _gen_part(scale: float, want=_ALL) -> dict:
     n = table_row_count("part", scale)
     rng = _rng("part", scale)
     key = np.arange(1, n + 1, dtype=np.int64)
-    colors = np.asarray(_COLORS, dtype=object)
-    picks = rng.integers(0, len(colors), size=(n, 5))
-    name = colors[picks[:, 0]]
-    for i in range(1, 5):
-        name = name + " " + colors[picks[:, i]]
+    name_picks = rng.integers(0, len(_COLORS), size=(n, 5))
     mfgr_i = rng.integers(1, 6, size=n)
     brand_i = mfgr_i * 10 + rng.integers(1, 6, size=n)
-    t1 = np.asarray(_TYPES1, dtype=object)[rng.integers(0, len(_TYPES1), size=n)]
-    t2 = np.asarray(_TYPES2, dtype=object)[rng.integers(0, len(_TYPES2), size=n)]
-    t3 = np.asarray(_TYPES3, dtype=object)[rng.integers(0, len(_TYPES3), size=n)]
-    c1 = np.asarray(_CONTAINERS1, dtype=object)[rng.integers(0, len(_CONTAINERS1), size=n)]
-    c2 = np.asarray(_CONTAINERS2, dtype=object)[rng.integers(0, len(_CONTAINERS2), size=n)]
-    return {
+    types = [Coded(v, rng.integers(0, len(v), size=n)) for v in (_TYPES1, _TYPES2, _TYPES3)]
+    containers = [Coded(v, rng.integers(0, len(v), size=n)) for v in (_CONTAINERS1, _CONTAINERS2)]
+    size = rng.integers(1, 51, size=n).astype(np.int32)
+    comment_picks = _comment_picks(rng, n, 2)
+    out = {
         "p_partkey": key,
-        "p_name": name,
-        "p_mfgr": np.asarray([f"Manufacturer#{i}" for i in mfgr_i], dtype=object),
-        "p_brand": np.asarray([f"Brand#{i}" for i in brand_i], dtype=object),
-        "p_type": t1 + " " + t2 + " " + t3,
-        "p_size": rng.integers(1, 51, size=n).astype(np.int32),
-        "p_container": c1 + " " + c2,
+        "p_mfgr": _formatted("Manufacturer#{}", mfgr_i),
+        "p_brand": _formatted("Brand#{}", brand_i),
+        "p_size": size,
         "p_retailprice": _retail_price(key),
-        "p_comment": _comments(rng, n, 2),
     }
+    if want("p_name"):
+        out["p_name"] = _joined(*[Coded(_COLORS, name_picks[:, i]) for i in range(5)])
+    if want("p_type"):
+        out["p_type"] = _joined(*types)
+    if want("p_container"):
+        out["p_container"] = _joined(*containers)
+    if want("p_comment"):
+        out["p_comment"] = _comments(comment_picks)
+    return out
 
 
-def _gen_partsupp(scale: float) -> dict[str, np.ndarray]:
+def _gen_partsupp(scale: float, want=_ALL) -> dict:
     nparts = table_row_count("part", scale)
     nsupp = table_row_count("supplier", scale)
     rng = _rng("partsupp", scale)
@@ -321,33 +414,46 @@ def _gen_partsupp(scale: float) -> dict[str, np.ndarray]:
     i = np.tile(np.arange(4, dtype=np.int64), nparts)
     suppkey = _supp_for_part(partkey, i, nsupp, scale)
     n = len(partkey)
-    return {
+    out = {
         "ps_partkey": partkey,
         "ps_suppkey": suppkey,
         "ps_availqty": rng.integers(1, 10_000, size=n).astype(np.int32),
         "ps_supplycost": _money(rng, n, 1.00, 1000.00),
-        "ps_comment": _comments(rng, n, 3),
     }
+    if want("ps_comment"):
+        out["ps_comment"] = _comments(_comment_picks(rng, n, 3))
+    return out
 
 
-def _gen_customer(scale: float) -> dict[str, np.ndarray]:
+def _gen_customer(scale: float, want=_ALL) -> dict:
     n = table_row_count("customer", scale)
     rng = _rng("customer", scale)
     key = np.arange(1, n + 1, dtype=np.int64)
     nation = rng.integers(0, 25, size=n).astype(np.int64)
-    return {
+    address_picks = _comment_picks(rng, n, 2)
+    phone = _phone_draws(rng, nation)
+    out = {
         "c_custkey": key,
-        "c_name": np.asarray([f"Customer#{k:09d}" for k in key], dtype=object),
-        "c_address": _comments(rng, n, 2),
         "c_nationkey": nation,
-        "c_phone": _phones(rng, nation),
         "c_acctbal": _money(rng, n, -999.99, 9999.99),
-        "c_mktsegment": np.asarray(_SEGMENTS, dtype=object)[rng.integers(0, 5, size=n)],
-        "c_comment": _comments(rng, n, 4),
+        "c_mktsegment": Coded(_SEGMENTS, rng.integers(0, 5, size=n)),
     }
+    if want("c_name"):
+        out["c_name"] = _formatted("Customer#{:09d}", key)
+    if want("c_address"):
+        out["c_address"] = _comments(address_picks)
+    if want("c_phone"):
+        out["c_phone"] = _phones(*phone)
+    if want("c_comment"):
+        out["c_comment"] = _comments(_comment_picks(rng, n, 4))
+    return out
 
 
+# What orders and lineitem share is kept between their passes while it is
+# small (the tests' scales); at SF10 it is 5 GB, made again by the pass that
+# needs it and let go.
 _ORDER_LINES_CACHE: dict[float, dict] = {}
+_ORDER_LINES_CACHE_MAX_ORDERS = 2_000_000
 
 
 def _order_lines(scale: float):
@@ -357,7 +463,8 @@ def _order_lines(scale: float):
     if scale in _ORDER_LINES_CACHE:
         return _ORDER_LINES_CACHE[scale]
     g = _order_lines_uncached(scale)
-    _ORDER_LINES_CACHE[scale] = g
+    if g["norders"] <= _ORDER_LINES_CACHE_MAX_ORDERS:
+        _ORDER_LINES_CACHE[scale] = g
     return g
 
 
@@ -394,12 +501,12 @@ def _order_lines_uncached(scale: float):
     shipdate = (l_orderdate + lrng.integers(1, 122, size=total_lines)).astype(np.int32)
     commitdate = (l_orderdate + lrng.integers(30, 91, size=total_lines)).astype(np.int32)
     receiptdate = (shipdate + lrng.integers(1, 31, size=total_lines)).astype(np.int32)
-    returnflag = np.where(
-        receiptdate <= _CURRENTDATE,
-        np.where(lrng.random(total_lines) < 0.5, "R", "A"),
-        "N",
-    ).astype(object)
-    linestatus = np.where(shipdate > _CURRENTDATE, "O", "F").astype(object)
+    returned = lrng.random(total_lines) < 0.5
+    returnflag = Coded(
+        ["A", "N", "R"],
+        np.where(receiptdate <= _CURRENTDATE, np.where(returned, 2, 0), 1).astype(np.int8),
+    )
+    linestatus = Coded(["F", "O"], (shipdate > _CURRENTDATE).astype(np.int8))
 
     return {
         "norders": norders,
@@ -423,7 +530,7 @@ def _order_lines_uncached(scale: float):
     }
 
 
-def _gen_orders(scale: float) -> dict[str, np.ndarray]:
+def _gen_orders(scale: float, want=_ALL) -> dict:
     g = _order_lines(scale)
     norders = g["norders"]
     # fresh stream (part=1): the cached _order_lines dict must stay free of
@@ -431,34 +538,35 @@ def _gen_orders(scale: float) -> dict[str, np.ndarray]:
     rng = _rng("orders", scale, part=1)
     line_total = np.round(g["extprice"] * (1 + g["tax"]) * (1 - g["discount"]), 2)
     totalprice = np.round(np.bincount(g["oidx"], weights=line_total, minlength=norders), 2)
-    open_lines = np.bincount(g["oidx"], weights=(g["linestatus"] == "O").astype(float), minlength=norders)
-    status = np.where(open_lines == 0, "F", np.where(open_lines == g["nlines"], "O", "P")).astype(object)
-    comments = _comments(rng, norders, 4)
+    open_lines = np.bincount(g["oidx"], weights=(g["linestatus"].codes == 1).astype(float), minlength=norders)
+    status = Coded(
+        ["F", "O", "P"],
+        np.where(open_lines == 0, 0, np.where(open_lines == g["nlines"], 1, 2)).astype(np.int8),
+    )
+    comment_picks = _comment_picks(rng, norders, 4)
     # Q13 filters o_comment NOT LIKE '%special%requests%'
     has_special = rng.random(norders) < 0.01
-    comments = comments.copy()
-    comments[has_special] = "blithely special packages requests sleep"
-    clerk = np.asarray(
-        [f"Clerk#{k:09d}" for k in rng.integers(1, max(2, int(1000 * scale)) + 1, size=norders)], dtype=object
-    )
-    return {
+    clerk = rng.integers(1, max(2, int(1000 * scale)) + 1, size=norders)
+    out = {
         "o_orderkey": g["orderkey"],
         "o_custkey": g["custkey"],
         "o_orderstatus": status,
         "o_totalprice": totalprice,
         "o_orderdate": g["orderdate"],
-        "o_orderpriority": np.asarray(_PRIORITIES, dtype=object)[rng.integers(0, 5, size=norders)],
-        "o_clerk": clerk,
+        "o_orderpriority": Coded(_PRIORITIES, rng.integers(0, 5, size=norders)),
         "o_shippriority": np.zeros(norders, dtype=np.int32),
-        "o_comment": comments,
     }
+    if want("o_clerk"):
+        out["o_clerk"] = _formatted("Clerk#{:09d}", clerk)
+    if want("o_comment"):
+        out["o_comment"] = _comments(comment_picks).replace(
+            has_special, "blithely special packages requests sleep")
+    return out
 
 
-def _gen_lineitem(scale: float) -> dict[str, np.ndarray]:
+def _gen_lineitem(scale: float, want=_ALL) -> dict:
     g = _order_lines(scale)
-    lrng = _rng("lineitem", scale, part=1)
-    total_lines = len(g["partkey"])
-    return {
+    out = {
         "l_orderkey": g["orderkey"][g["oidx"]],
         "l_partkey": g["partkey"],
         "l_suppkey": g["suppkey"],
@@ -472,7 +580,13 @@ def _gen_lineitem(scale: float) -> dict[str, np.ndarray]:
         "l_shipdate": g["shipdate"],
         "l_commitdate": g["commitdate"],
         "l_receiptdate": g["receiptdate"],
-        "l_shipinstruct": np.asarray(_INSTRUCTS, dtype=object)[lrng.integers(0, 4, size=total_lines)],
-        "l_shipmode": np.asarray(_MODES, dtype=object)[lrng.integers(0, 7, size=total_lines)],
-        "l_comment": _comments(lrng, total_lines, 2),
     }
+    # the second stream, drawn in this order: instructions, modes, comments
+    if any(want(c) for c in ("l_shipinstruct", "l_shipmode", "l_comment")):
+        lrng = _rng("lineitem", scale, part=1)
+        total_lines = len(g["partkey"])
+        out["l_shipinstruct"] = Coded(_INSTRUCTS, lrng.integers(0, 4, size=total_lines))
+        out["l_shipmode"] = Coded(_MODES, lrng.integers(0, 7, size=total_lines))
+        if want("l_comment"):
+            out["l_comment"] = _comments(_comment_picks(lrng, total_lines, 2))
+    return out

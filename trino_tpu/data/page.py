@@ -28,7 +28,7 @@ import numpy as np
 
 from .types import BOOLEAN, DATE, Type, days_to_date
 
-__all__ = ["Dictionary", "Column", "Page"]
+__all__ = ["Dictionary", "CodedStrings", "Column", "Page"]
 
 # content-keyed Dictionary intern table (Dictionary.intern): tuple(values)
 # -> the one shared instance.  Bounded LRU; very large dictionaries bypass
@@ -188,6 +188,30 @@ class Dictionary:
         return f"Dictionary({len(self.values)} values)"
 
 
+class CodedStrings:
+    """A host-side string column that arrives dictionary coded: `codes`
+    (int32) into `dictionary` (distinct values, sorted as Dictionary.encode
+    sorts them; some may not occur in this slice).  What a connector hands
+    to a scan in place of an object array when it keeps its strings coded
+    (connectors/tpch/columns.py): Column.from_numpy uploads the codes as
+    they are."""
+
+    __slots__ = ("codes", "dictionary")
+
+    def __init__(self, codes: np.ndarray, dictionary: np.ndarray):
+        self.codes = codes
+        self.dictionary = dictionary
+
+    def __len__(self) -> int:
+        return len(self.codes)
+
+    def __getitem__(self, rows) -> "CodedStrings":
+        return CodedStrings(self.codes[rows], self.dictionary)
+
+    def decode(self) -> np.ndarray:
+        return self.dictionary[self.codes]
+
+
 @jax.tree_util.register_pytree_node_class
 @dataclass
 class Column:
@@ -247,7 +271,11 @@ class Column:
             codes, dictionary = Dictionary.encode_objects(values, _canon_row)
             return Column(type_, place(codes), None if valid is None else place(valid), dictionary)
         if type_.is_string:
-            codes, dictionary = Dictionary.encode(values)
+            if isinstance(values, CodedStrings):
+                codes = np.asarray(values.codes, dtype=np.int32)
+                dictionary = Dictionary.intern(values.dictionary)
+            else:
+                codes, dictionary = Dictionary.encode(values)
             return Column(type_, place(codes), None if valid is None else place(valid), dictionary)
         if (
             type_.is_decimal

@@ -18,6 +18,7 @@ from __future__ import annotations
 
 import contextlib
 import math
+import time
 from dataclasses import dataclass
 from typing import Optional, Sequence
 
@@ -26,7 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..connectors.spi import CatalogManager
-from ..data.page import Column, Page
+from ..data.page import CodedStrings, Column, Page
 from ..data.types import Type
 from ..ops.expr import ColumnVal, column_val, eval_expr, eval_predicate, param_context
 from ..ops.relops import (
@@ -97,6 +98,12 @@ _EAGER_SIZING_LIMIT = 0
 # lifetime scopes the cache; an id()-keyed global could alias a recycled
 # address after GC) and entries key on (table, gen, split, filters).
 _KEEP_MASK_CACHE_MAX = 64
+
+
+def _plain(column):
+    """A connector's dictionary-coded strings as the object array the row
+    filters, the concatenation of splits and the padding work on."""
+    return column.decode() if isinstance(column, CodedStrings) else column
 
 
 def _pow2(n: int) -> int:
@@ -204,6 +211,10 @@ class LocalExecutor:
         # them from, over this executor's life (scan_load reports the deltas)
         self.h2d_bytes = 0
         self.columns_loaded = 0
+        # of the current `scan_load`: seconds in _load_columns outside the
+        # uploads, and what the connector said of where its columns were
+        self.host_prepare_s = 0.0
+        self.load_sources: set = set()
 
     def _span(self, name: str, **attributes):
         """-> a context manager yielding the open Span, or None without a
@@ -299,9 +310,15 @@ class LocalExecutor:
             for i, s in enumerate(conn.get_splits(table, num_parts))
             if i % num_parts == part or num_parts == 1
         ]
-        data = conn.read_split(splits[0], want)
+        t_read = time.perf_counter()
+        data, source = conn.read_split_from(splits[0], want)
+        self.load_sources.add(source)
+        if len(splits) > 1 or filters:
+            data = {c: _plain(v) for c, v in data.items()}
         for s in splits[1:]:
-            more = conn.read_split(s, want)
+            more, source = conn.read_split_from(s, want)
+            self.load_sources.add(source)
+            more = {c: _plain(v) for c, v in more.items()}
             data = {
                 c: (
                     np.ma.concatenate([data[c], more[c]])
@@ -358,10 +375,20 @@ class LocalExecutor:
             # can only shrink below it, never grow past it)
             pad_to = max(pad_to, int(self.split_pad_rows))
         loaded, live_rows = {}, None
+        placing = [0.0]
+
+        def timed_place(host):
+            t0 = time.perf_counter()
+            try:
+                return place(host)
+            finally:
+                placing[0] += time.perf_counter() - t0
+
         for c in missing:
             arr = data[c]
             n_live = len(arr)
             if n_live < pad_to:
+                arr = _plain(arr)
                 t = schema.type_of(c)
                 fill = np.zeros(
                     (pad_to - n_live,), dtype=object if t.is_string else t.np_dtype
@@ -375,9 +402,11 @@ class LocalExecutor:
                 else:
                     arr = np.concatenate([arr, fill]) if n_live else fill
                 live_rows = n_live
-            col = loaded[c] = Column.from_numpy(schema.type_of(c), arr, place=place)
+            col = loaded[c] = Column.from_numpy(schema.type_of(c), arr, place=timed_place)
             self.columns_loaded += 1
             self.h2d_bytes += col.nbytes
+        # read + code + narrow + pad: everything here but the uploads
+        self.host_prepare_s += time.perf_counter() - t_read - placing[0]
         return loaded, live_rows
 
     def _load_inputs(self, nodes, remote_pages) -> dict[str, Page]:
@@ -385,6 +414,8 @@ class LocalExecutor:
         inputs = {}
         with self._span("scan_load") as span:
             bytes0, loaded0, columns = self.h2d_bytes, self.columns_loaded, 0
+            prepare0 = self.host_prepare_s
+            self.load_sources.clear()
             store = self.resident
             if store is not None and store.released != self._released_seen:
                 # the store let tables go since this (long-lived) executor
@@ -401,6 +432,12 @@ class LocalExecutor:
                 span.attributes.update(
                     h2d_bytes=self.h2d_bytes - bytes0, columns=columns,
                     columns_cached=columns - (self.columns_loaded - loaded0),
+                    # the furthest any column came from: made just now, read
+                    # from the connector's files, or found on the device
+                    source=next(
+                        (s for s in ("generated", "file", "connector")
+                         if s in self.load_sources), "resident"),
+                    host_prepare_ms=(self.host_prepare_s - prepare0) * 1e3,
                 )
         return inputs
 
@@ -1125,8 +1162,22 @@ def _trace_plan(
             value = pmax_count(value, axis)
         required[nid] = value
 
+    # id(mask) -> (mask, rows) for the masks made here of ones (a page with
+    # no live mask of its own): their count is their length.  Summing one is
+    # a reduction of a constant, which the TPU compiler folds on the host
+    # one element at a time — 53 s of compiling for 6M rows, 500 s for 60M.
+    all_live: dict[int, tuple] = {}
+
+    def page_live(page):
+        live = page.live_mask()
+        if page.live is None:
+            all_live[id(live)] = (live, page.capacity)
+        return live
+
     def count_rows(nid_here: int, live) -> None:
-        cnt = jnp.sum(live.astype(jnp.int64))
+        known = all_live.get(id(live))
+        cnt = (jnp.int64(known[1]) if known is not None
+               else jnp.sum(live.astype(jnp.int64)))
         if axis is not None and num_devices > 1:
             cnt = jax.lax.psum(cnt, axis)
         required[_STATS_ROWS_BASE + nid_here] = cnt
@@ -1250,7 +1301,7 @@ def _trace_plan(
         if recipe is None:
             return None
         counter[0] = scan_nid + 1  # consume the whole chain's id range
-        live = page.live_mask()
+        live = page_live(page)
         _kernels.record_dispatch(
             "fused_pipeline", "pallas",
             f"{len(filters)} filters {len(recipe.streams)} streams "
@@ -1282,7 +1333,7 @@ def _trace_plan(
             cols = [column_val(c) for c in page.columns]
             for cv, t in zip(cols, node.output_types):
                 cv.type = t
-            return _Stage(cols, page.live_mask())
+            return _Stage(cols, page_live(page))
 
         if isinstance(node, EnforceSingleRow):
             s = emit(node.child)

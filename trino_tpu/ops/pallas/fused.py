@@ -567,6 +567,20 @@ def _kleene(op, d1, v1, d2, v2):
 # -------------------------------------------------------------- the kernel
 
 
+def _accumulate(acc, err, part):
+    """One step of the running sum over a table's sub-chunks (Neumaier):
+    -> (acc + part rounded to f32, err + what that rounding lost).  The loss
+    of one f32 addition is itself an f32, so acc + err stays the exact sum
+    for as long as err's own additions are exact — for integer streams
+    (counts, whole hundredths) while |err| < 2^24, which a 60M-row table's
+    ~58,600 steps of at most half an ulp of acc each keep far away."""
+    t = acc + part
+    lost = jnp.where(
+        jnp.abs(acc) >= jnp.abs(part), (acc - t) + part, (part - t) + acc
+    )
+    return t, err + lost
+
+
 @functools.lru_cache(maxsize=64)
 def _fused_kernel(recipe: _Recipe, n_chunks: int, interpret: bool):
     from jax.experimental import pallas as pl
@@ -618,13 +632,7 @@ def _fused_kernel(recipe: _Recipe, n_chunks: int, interpret: bool):
                     jnp.int32, (nr, _DTILE), 1
                 ) == 0
                 part = jnp.where(lane0, tot, jnp.float32(0.0))
-            # Neumaier: compensate chunk-to-chunk rounding of the running sum
-            a = acc[...]
-            t = a + part
-            err[...] = err[...] + jnp.where(
-                jnp.abs(a) >= jnp.abs(part), (a - t) + part, (part - t) + a
-            )
-            acc[...] = t
+            acc[...], err[...] = _accumulate(acc[...], err[...], part)
 
         @pl.when(i == n_chunks - 1)
         def _flush():
@@ -683,27 +691,31 @@ def run(recipe: _Recipe, scan_cols, live, *, interpret: bool = False):
 
     i32_planes: list = [None] * recipe.n_i32
     f32_planes: list = [None] * max(recipe.n_f32, 1)
-    i32_planes[0] = _prep(live.astype(jnp.int32), n_pad, 0)
-    for ci, plan in recipe.cols:
-        cv = scan_cols[ci]
-        if plan[0] == "dd":
-            _, hp, lp, vp, _ = plan
-            hi, lo = _dd_planes(cv.data)
-            f32_planes[hp] = _prep(hi, n_pad, 0.0)
-            f32_planes[lp] = _prep(lo, n_pad, 0.0)
-        elif plan[0] == "dict":
-            i32_planes[plan[1]] = _prep(cv.data.astype(jnp.int32), n_pad, 0)
-        else:
-            _, p, vp, _ = plan
-            i32_planes[p] = _prep(cv.data.astype(jnp.int32), n_pad, 0)
-        if plan[0] != "dict" and plan[-2] >= 0:
-            i32_planes[plan[-2]] = _prep(cv.valid.astype(jnp.int32), n_pad, 0)
-    if recipe.n_f32 == 0:
-        f32_planes[0] = _prep(jnp.zeros((1,), jnp.float32), n_pad, 0.0)
+    # the casts, splits, pads and stacks that lay the kernel's planes out:
+    # device work of their own, told apart in a trace by this scope
+    with jax.named_scope("fused_scan_prep"):
+        i32_planes[0] = _prep(live.astype(jnp.int32), n_pad, 0)
+        for ci, plan in recipe.cols:
+            cv = scan_cols[ci]
+            if plan[0] == "dd":
+                _, hp, lp, vp, _ = plan
+                hi, lo = _dd_planes(cv.data)
+                f32_planes[hp] = _prep(hi, n_pad, 0.0)
+                f32_planes[lp] = _prep(lo, n_pad, 0.0)
+            elif plan[0] == "dict":
+                i32_planes[plan[1]] = _prep(cv.data.astype(jnp.int32), n_pad, 0)
+            else:
+                _, p, vp, _ = plan
+                i32_planes[p] = _prep(cv.data.astype(jnp.int32), n_pad, 0)
+            if plan[0] != "dict" and plan[-2] >= 0:
+                i32_planes[plan[-2]] = _prep(cv.valid.astype(jnp.int32), n_pad, 0)
+        if recipe.n_f32 == 0:
+            f32_planes[0] = _prep(jnp.zeros((1,), jnp.float32), n_pad, 0.0)
+        i32, f32 = jnp.stack(i32_planes), jnp.stack(f32_planes)
 
     call = _fused_kernel(recipe, n_chunks, interpret)
     with jax.enable_x64(False):
-        out = call(jnp.stack(i32_planes), jnp.stack(f32_planes))
+        out = call(i32, f32)
     totals = (
         out[0].astype(jnp.float64) + out[1].astype(jnp.float64)
     )[:, : recipe.domain]

@@ -21,7 +21,7 @@ from __future__ import annotations
 from dataclasses import dataclass
 from typing import Optional
 
-from ..connectors.spi import CatalogManager, ColumnStats
+from ..connectors.spi import CatalogManager, ColumnStats, LazyStats
 from .ir import Call, Const, FieldRef, InListIr, IrExpr, LikeIr
 from .nodes import (
     Compact,
@@ -74,11 +74,11 @@ def estimate(node: PlanNode, catalogs: CatalogManager) -> PlanStats:
         except Exception:
             ts = None
         if ts is not None:
-            cols = {
-                i: ts.columns[name]
-                for i, name in enumerate(node.column_names)
-                if name in ts.columns
-            }
+            names = node.column_names
+            cols = LazyStats(
+                [i for i, name in enumerate(names) if name in ts.columns],
+                lambda i: ts.columns[names[i]],
+            )
             return PlanStats(ts.row_count, cols)
         n = conn.estimated_row_count(node.table)
         return PlanStats(float(n) if n is not None else _DEFAULT_ROWS, {})
@@ -107,11 +107,13 @@ def estimate(node: PlanNode, catalogs: CatalogManager) -> PlanStats:
             reps = max(1.0, child.rows / ndv)
             return max(1.0, ndv * (1.0 - (1.0 - min(sel, 1.0)) ** reps))
 
-        cols = {}
-        for i, c in child.columns.items():
+        def filtered(i: int) -> ColumnStats:
+            c = child.columns[i]
             nd = targeted[i] if i in targeted else survive(c.ndv)
-            cols[i] = ColumnStats(nd, c.min, c.max, c.null_fraction)
-        return PlanStats(max(1.0, child.rows * sel), cols)
+            return ColumnStats(nd, c.min, c.max, c.null_fraction)
+
+        return PlanStats(
+            max(1.0, child.rows * sel), LazyStats(child.columns, filtered))
 
     if isinstance(node, Project):
         child = estimate(node.child, catalogs)
@@ -172,10 +174,11 @@ def estimate(node: PlanNode, catalogs: CatalogManager) -> PlanStats:
             rows = max(left.rows, right.rows)
         if node.kind == "left":
             rows = max(rows, left.rows)
-        cols = dict(left.columns)
         off = len(node.left.output_types)
-        for i, c in right.columns.items():
-            cols[off + i] = c
+        cols = LazyStats(
+            list(left.columns) + [off + i for i in right.columns],
+            lambda i: left.columns[i] if i in left.columns else right.columns[i - off],
+        )
         return PlanStats(rows, cols)
 
     if isinstance(node, (TopN, Limit)):
