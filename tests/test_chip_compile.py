@@ -182,12 +182,26 @@ def fused_recipes():
     return out
 
 
-@pytest.mark.parametrize("name", ["q01", "q06"])
+@pytest.mark.parametrize("name", ["q01", "q06", "domain-129"])
 def test_fused_scan_compiles_for_v5e(one_chip, fused_recipes, name):
+    """q06 (keyless) and q01 (6 groups) take the select-and-add scatter;
+    `domain-129` is q01's recipe with 65 return flags (130 groups: one more
+    lane tile than 128), so the one-hot matmul form, at two lane tiles, is
+    still compiled somewhere."""
+    import dataclasses
+
     from trino_tpu.ops.expr import ColumnVal
     from trino_tpu.ops.pallas import fused
 
-    recipe, cols = fused_recipes[name]
+    recipe, cols = fused_recipes["q01" if name == "domain-129" else name]
+    if name == "domain-129":
+        (c0, _, s0), (c1, d1, s1) = recipe.keys
+        recipe = dataclasses.replace(
+            recipe, keys=((c0, 65, s0), (c1, d1, s1)), domain=65 * d1
+        )
+        assert fused.scatter_form(recipe) == ("mxu", 256)
+    else:
+        assert fused.scatter_form(recipe) == ("vpu", 128)
     used = {i for i, _ in recipe.cols}
 
     def run(live, *arrays):

@@ -19,7 +19,7 @@ import numpy as np
 import pytest
 import jax.numpy as jnp
 
-from trino_tpu.data.types import BIGINT, DOUBLE, INTEGER, DecimalType
+from trino_tpu.data.types import BIGINT, BOOLEAN, DOUBLE, INTEGER, DecimalType
 from trino_tpu.ops import kernels, relops
 from trino_tpu.ops.expr import ColumnVal
 from trino_tpu.ops.relops import AggSpec
@@ -319,10 +319,12 @@ def test_fused_pipeline_engine_differential(kernel_engine, name):
         eng.session.set("pallas_interpret", "false")
     lines = [r[0] for r in ex if str(r[0]).startswith("-- kernel:")]
     assert any("pallas fused_pipeline" in l for l in lines), lines
-    # the fused kernel's f32 partial sums land within ~1e-7 relative BY
-    # DESIGN (ops/pallas/fused.py accuracy note) — that applies to its
-    # decimal outputs too, so compare them under the float tolerance
-    # instead of the oracle's exact-Decimal equality
+    # 6 groups x 15 streams (q01) and 1 x 3 (q06): a select and an add each
+    assert any("scatter vpu tile 128" in l for l in lines), lines
+    # the fused kernel's f32 partial sums round BY DESIGN (ops/pallas/
+    # fused.py accuracy note: ~1e-8 relative at this size, interpreted) —
+    # that applies to its decimal outputs too, so compare them under the
+    # float tolerance instead of the oracle's exact-Decimal equality
     def _approx(rows):
         return [
             tuple(
@@ -374,11 +376,109 @@ def test_fused_dispatch_metric_increments(kernel_engine):
     eng.session.set("pallas_interpret", "true")
     try:
         before = kernels._DISPATCH.value("fused_pipeline", "pallas")
+        forms = kernels.FUSED_SCATTER.value("vpu"), kernels.FUSED_SCATTER.value("mxu")
         eng.query(sql)
         after = kernels._DISPATCH.value("fused_pipeline", "pallas")
     finally:
         eng.session.set("pallas_interpret", "false")
     assert after > before
+    # one keyless program traced: the select-and-add form, never the one-hot
+    assert kernels.FUSED_SCATTER.value("vpu") == forms[0] + (after - before)
+    assert kernels.FUSED_SCATTER.value("mxu") == forms[1]
+
+
+# ------------------------------------------------- the fused scan's scatter
+
+
+def _fused_case(domains, n=17_500, seed=34):
+    """Synthetic scan columns + the recipe of
+    `select k.., sum(v), sum(v * w), avg(d), count(*) where q < 40 group by k..`
+    -> (recipe, scan_cols, live, numpy columns).  Values are small whole
+    numbers (and quarters), so every f32 partial sum is exact whatever the
+    scatter's form and a fault shows as a wrong group, not as rounding."""
+    from trino_tpu.data.page import Dictionary
+    from trino_tpu.data.types import VARCHAR
+    from trino_tpu.ops.pallas import fused
+    from trino_tpu.plan.ir import Call, Const, FieldRef
+
+    rng = np.random.default_rng(seed)
+    dec, dec4 = DecimalType(12, 2), DecimalType(18, 4)
+    v = rng.integers(-500, 1_600, n).astype(np.int32)
+    w = rng.integers(0, 11, n).astype(np.int32)
+    d = rng.integers(-4_000, 4_000, n) / 4.0
+    q = rng.integers(0, 50, n).astype(np.int32)
+    keys = [rng.integers(0, k, n).astype(np.int32) for k in domains]
+    live = rng.random(n) < 0.9
+    cols = [_cv(v, typ=dec), _cv(w, typ=dec), _cv(d, typ=DOUBLE), _cv(q, typ=INTEGER)]
+    cols += [
+        _cv(k, dict_=Dictionary(np.array([f"k{i}" for i in range(dom)], object)), typ=VARCHAR)
+        for k, dom in zip(keys, domains)
+    ]
+    f = [FieldRef(i, c.type) for i, c in enumerate(cols)]
+    recipe, why = fused.plan_pipeline(
+        cols,
+        [Call("lt", (f[3], Const(40, INTEGER)), BOOLEAN)],
+        f[4:],
+        ["sum", "sum", "avg", "count_star"],
+        [f[0], Call("mul", (f[0], f[1]), dec4), f[2], None],
+        [DecimalType(38, 2), DecimalType(38, 4), DOUBLE, BIGINT],
+    )
+    assert recipe is not None, why
+    return recipe, cols, jnp.asarray(live), (v, w, d, q, keys, live)
+
+
+@pytest.mark.parametrize(
+    "domains,tile",
+    [((), 128), ((2,), 128), ((3, 2), 128), ((7,), 128), ((128,), 128),
+     ((129,), 256), ((32, 16), 512)],
+    ids=["keyless", "2", "3x2", "7", "128", "129", "32x16"],
+)
+def test_fused_scatter_by_domain(monkeypatch, domains, tile):
+    """The scatter's width and form follow the key domain (1 = keyless, a
+    handful of groups, one lane tile exactly, one group more, the widest the
+    planner accepts): against a numpy float64 group-by over a ragged last
+    step and dead lanes, counts exactly and sums to 1e-9; and the
+    accumulator the kernel hands back is as wide as the rule says."""
+    from trino_tpu.ops.pallas import fused
+
+    recipe, cols, live, (v, w, d, q, keys, live_np) = _fused_case(domains)
+    domain = int(np.prod(domains)) if domains else 1
+    assert recipe.domain == domain
+    shapes = []
+    recombine = fused._totals
+    monkeypatch.setattr(
+        fused, "_totals",
+        lambda r, out: shapes.append(out.shape) or recombine(r, out),
+    )
+    totals = fused.run(recipe, cols, live, interpret=True)
+    key_codes, aggs, out_live, n_groups = fused.assemble(recipe, totals)
+    (shape,) = shapes
+    assert shape[-1] == tile == fused.scatter_form(recipe)[1]
+
+    keep = live_np & (q < 40)
+    code = np.zeros(len(v), np.int64)
+    for k, dom in zip(keys, domains):
+        code = code * dom + k
+    want = {
+        name: np.bincount(code[keep], weights=x[keep].astype(np.float64), minlength=domain)
+        for name, x in (("n", np.ones_like(v)), ("v", v), ("vw", v * w), ("d", d))
+    }
+    (sv, _, _, _), (svw, _, _, _), (avg, avg_ok), (cnt, _) = aggs
+    np.testing.assert_array_equal(np.asarray(cnt), want["n"].astype(np.int64))
+    if domains:
+        np.testing.assert_array_equal(np.asarray(out_live), want["n"] > 0)
+        assert int(n_groups) == int((want["n"] > 0).sum())
+        got_code = np.zeros(domain, np.int64)
+        for kc, dom in zip(key_codes, domains):
+            got_code = got_code * dom + np.asarray(kc)
+        np.testing.assert_array_equal(got_code, np.arange(domain))
+    np.testing.assert_allclose(np.asarray(sv), want["v"], rtol=1e-9, atol=0)
+    np.testing.assert_allclose(np.asarray(svw), want["vw"], rtol=1e-9, atol=0)
+    some = want["n"] > 0
+    np.testing.assert_array_equal(np.asarray(avg_ok), some)
+    np.testing.assert_allclose(
+        np.asarray(avg)[some], want["d"][some] / want["n"][some], rtol=1e-9, atol=1e-12
+    )
 
 
 # ------------------------------------------------- the fused scan's running sums

@@ -1302,11 +1302,13 @@ def _trace_plan(
             return None
         counter[0] = scan_nid + 1  # consume the whole chain's id range
         live = page_live(page)
+        form, tile = _fused.scatter_form(recipe)
         _kernels.record_dispatch(
             "fused_pipeline", "pallas",
             f"{len(filters)} filters {len(recipe.streams)} streams "
-            f"domain {recipe.domain}",
+            f"domain {recipe.domain} scatter {form} tile {tile}",
         )
+        _kernels.FUSED_SCATTER.labels(form=form).inc()
         totals = _fused.run(recipe, scan_cols, live, interpret=policy.interpret)
         key_codes, agg_cols, out_live, n_groups = _fused.assemble(recipe, totals)
         report(nid, n_groups)

@@ -69,6 +69,15 @@ _DISPATCH = _metrics.GLOBAL.counter(
     ("op", "impl"),
 )
 
+FUSED_SCATTER = _metrics.GLOBAL.counter(
+    "trino_tpu_fused_scatter_total",
+    "Fused scan programs traced, by the form the kernel scatters its "
+    "streams into their groups with (ops/pallas/fused.py: vpu = one select "
+    "and add per group and stream, no one-hot; mxu = a one-hot matmul as "
+    "wide as the key domain's lane tiles)",
+    ("form",),
+)
+
 
 def get_policy() -> KernelPolicy:
     return _POLICY

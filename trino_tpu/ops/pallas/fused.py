@@ -16,23 +16,46 @@ once and does everything else in VMEM:
     error-free transforms (Knuth two-sum, Dekker two-product with a 4097
     split), so products like extendedprice*(1-discount) stay exact per row;
   * grouping keys are dictionary codes combined into one mixed-radix code
-    of domain D <= 512 — one lane tile — and each per-1024-row partial is a
-    one-hot MXU matmul: stacked streams (8, NR, 128) x one-hot (8, 128, 512)
-    contracted over lanes, summed over sublanes, into an (NR, 512)
-    accumulator held in VMEM across the whole grid with Neumaier
-    compensation (acc + err recovered in f64 on the host).
+    of domain D <= 512, and each (group, stream) pair is a sum in
+    compensated f32 (an acc and an err that holds what acc's roundings
+    lost, recombined in f64 outside the kernel).  How the masked streams of
+    a 1,024-row sub-chunk reach their groups follows the shape of the
+    recipe (`scatter_form`):
+      - D x streams <= 448 (q01: 6 x 15; q06 and every keyless scan: D = 1):
+        one (8, 128) f32 tile per pair, `where(code == g, stream, 0)` added
+        into it lane by lane across a grid step's eight sub-chunks, then
+        one compensated step per tile — D compares and D x streams
+        select-adds a sub-chunk, no one-hot, no MXU.  The 1,024 lanes of a
+        tile are summed in f64 afterwards, so an f32 partial is 8 rows;
+      - beyond that, a one-hot MXU matmul as wide as D needs,
+        128 * ceil(D / 128) lanes: stacked streams (8, NR, 128) x one-hot
+        (8, 128, lanes) at HIGHEST contracted over lanes and summed over
+        sublanes into an (NR, lanes) accumulator, one compensated step a
+        sub-chunk.
+    On a v5e at 60,000,466 rows (PERF.md, PR 34) a 1,024-row sub-chunk of
+    q01 takes 102 ns in the first form (6.0 ms a scan: 59% of HBM for its
+    twelve planes) where the 512-lane one-hot of PRs 22-33 took 1,248 ns
+    and the one-hot at 128 lanes takes ~600 ns (35 ms a scan, whether 3 or
+    15 streams ride on it: building the one-hot and loading it into the MXU
+    is the cost).  The first form grows by ~0.7 ns a pair: 240 pairs 12.9
+    ms a scan, 448 pairs 22.4 ms — the widest measured, still a third
+    under the narrowest one-hot — so the rule keeps it to 448 pairs.  q06
+    takes 53 ns (3.1 ms, 75% of HBM).
 
 Every aggregate lowers to a handful of f32 *streams* (per-row values summed
 per group): count -> the row mask; sum -> the hi and lo parts (summed as
 separate streams, recombined in f64); avg -> sum's streams plus a count.
 Streams are deduplicated, so q01's six sums+avgs over four expressions cost
-eleven streams, not eighteen.
+fifteen streams, not eighteen.
 
 Accuracy: per-row expression math is exact; only the f32 summation inside a
-1024-row partial rounds (compensated across partials).  For the TPC-H
-aggregates this lands within ~1e-7 relative of the exact result, far inside
-the engine's comparison tolerance; exactness-critical cases (BIGINT sum's
-mod-2^64 semantics) are rejected at plan time and take the sort path.
+partial rounds (compensated across partials), and that averages out as
+1/sqrt(partials).  On the chip at SF10 the decimal sums of q01 and q06 read
+2.2e-11 relative in the first form (8-row partials; 5.3e-10 with the
+1,024-row partials of the 512-lane one-hot) and q01's AVGs 5.0e-9 in
+either (4.7e-9): their last digits are lost outside the partial sums.
+Exactness-critical cases (BIGINT sum's mod-2^64 semantics) are rejected at
+plan time and take the sort path.
 
 Like the hash kernels, everything here runs under pallas interpret mode on
 CPU so tier-1 exercises the same code path as the TPU build.
@@ -57,9 +80,14 @@ from .hashagg import (
 )
 from . import hashagg as _hashagg
 
-# one lane tile: the mixed-radix key-code domain must fit a single 512-wide
-# accumulator tile so the scatter is one matmul, no table walk
-_DTILE = 512
+# the widest mixed-radix key-code domain the planner accepts: four lane
+# tiles of one-hot, no table walk
+_MAX_DOMAIN = 512
+# (group, stream) pairs up to which the scatter is a select and an add per
+# pair on the VPU; beyond, a one-hot matmul (scatter_form).  The widest the
+# chip was timed at and found faster (module docstring); the kernel is
+# unrolled, ~10,000 vector ops a grid step there
+_VPU_PAIRS = 448
 _MAX_STREAMS = 64
 # double-float pairs are exact only while the integer payload fits hi+lo
 _DD_EXACT_BITS = 47
@@ -275,8 +303,8 @@ def plan_pipeline(scan_cols, filters, key_exprs, agg_fns, agg_args, agg_types):
             d = len(p.scan_cols[ke.index].dict)
             keys.append((ke.index, d))
             domain *= max(d, 1)
-        if domain > _DTILE:
-            raise _Unsupported(f"key domain {domain} > {_DTILE}")
+        if domain > _MAX_DOMAIN:
+            raise _Unsupported(f"key domain {domain} > {_MAX_DOMAIN}")
         rows_s = p.stream("rows", None)
         aggs = []
         for fn, arg, otype in zip(agg_fns, agg_args, agg_types):
@@ -568,17 +596,26 @@ def _kleene(op, d1, v1, d2, v2):
 
 
 def _accumulate(acc, err, part):
-    """One step of the running sum over a table's sub-chunks (Neumaier):
+    """One step of the running sum over a table's partials (Neumaier's
+    compensated sum, the rounding taken branch-free by Knuth's two-sum):
     -> (acc + part rounded to f32, err + what that rounding lost).  The loss
     of one f32 addition is itself an f32, so acc + err stays the exact sum
     for as long as err's own additions are exact — for integer streams
     (counts, whole hundredths) while |err| < 2^24, which a 60M-row table's
-    ~58,600 steps of at most half an ulp of acc each keep far away."""
-    t = acc + part
-    lost = jnp.where(
-        jnp.abs(acc) >= jnp.abs(part), (acc - t) + part, (part - t) + acc
-    )
+    at most ~58,600 steps of half an ulp of acc or less each keep far away."""
+    t, lost = _two_sum(acc, part)
     return t, err + lost
+
+
+def scatter_form(recipe: _Recipe) -> tuple[str, int]:
+    """How the kernel puts a sub-chunk's masked streams into their groups,
+    and the lane width of the accumulator that takes them: ("vpu", 128) —
+    one (8, 128) tile per (group, stream), a select and an add each — while
+    there are at most _VPU_PAIRS such tiles, else ("mxu", lanes) — a one-hot
+    matmul over as many lane tiles as the key domain needs."""
+    if recipe.domain * len(recipe.streams) <= _VPU_PAIRS:
+        return "vpu", _CHUNK_L
+    return "mxu", _CHUNK_L * -(-recipe.domain // _CHUNK_L)
 
 
 @functools.lru_cache(maxsize=64)
@@ -587,60 +624,75 @@ def _fused_kernel(recipe: _Recipe, n_chunks: int, interpret: bool):
     from jax.experimental.pallas import tpu as pltpu
 
     nr = len(recipe.streams)
+    domain = recipe.domain
     key_planes = {i: dict(recipe.cols)[i][1] for i, _, _ in recipe.keys}
+    form, dtile = scatter_form(recipe)
+    acc_shape = (
+        (domain * nr, _CHUNK_S, _CHUNK_L) if form == "vpu" else (nr, dtile)
+    )
 
-    def kernel(i32_ref, f32_ref, out_ref, acc, err):
-        i = pl.program_id(0)
+    def sub_chunk(i32_ref, f32_ref, c):
+        """-> (key code | None, masked streams) of a step's c-th sub-chunk."""
+        rows = slice(c * _CHUNK_S, (c + 1) * _CHUNK_S)
+        i32 = [i32_ref[p, rows, :] for p in range(recipe.n_i32)]
+        f32 = [f32_ref[p, rows, :] for p in range(max(recipe.n_f32, 1))]
+        ev = _Eval(recipe, i32, f32, (_CHUNK_S, _CHUNK_L))
+        mask = i32[0] > 0
+        for f in recipe.filters:
+            mask = mask & ev.pred(f)
+        code = None
+        for ci, _, stride in recipe.keys:
+            term = i32[key_planes[ci]] * jnp.int32(stride)
+            code = term if code is None else code + term
+        return code, [ev.masked_stream(tag, e, mask) for tag, e in recipe.streams]
 
-        @pl.when(i == 0)
-        def _init():
-            acc[...] = jnp.zeros((nr, _DTILE), jnp.float32)
-            err[...] = jnp.zeros((nr, _DTILE), jnp.float32)
-
+    def vpu_step(i32_ref, f32_ref, acc, err):
+        # tile g * nr + s sums stream s over the rows of group g, lane by
+        # lane: a step adds 8 rows to each of a tile's 1,024 lanes, and the
+        # lanes are summed in f64 outside the kernel (_totals).  One group
+        # (no keys, or a dictionary of one value) needs no compare.
+        part = [None] * (domain * nr)
         for c in range(_STEP_CHUNKS):
-            rows = slice(c * _CHUNK_S, (c + 1) * _CHUNK_S)
-            i32 = [i32_ref[p, rows, :] for p in range(recipe.n_i32)]
-            f32 = [f32_ref[p, rows, :] for p in range(max(recipe.n_f32, 1))]
-            ev = _Eval(recipe, i32, f32, (_CHUNK_S, _CHUNK_L))
-            mask = i32[0] > 0
-            for f in recipe.filters:
-                mask = mask & ev.pred(f)
-            code = None
-            for ci, _, stride in recipe.keys:
-                term = i32[key_planes[ci]] * jnp.int32(stride)
-                code = term if code is None else code + term
-            streams = [
-                ev.masked_stream(tag, e, mask) for tag, e in recipe.streams
-            ]
+            code, streams = sub_chunk(i32_ref, f32_ref, c)
+            for g in range(domain):
+                hit = None if domain == 1 else code == jnp.int32(g)
+                for s, x in enumerate(streams):
+                    v = x if hit is None else jnp.where(hit, x, jnp.float32(0.0))
+                    k = g * nr + s
+                    part[k] = v if part[k] is None else part[k] + v
+        for k, p in enumerate(part):
+            acc[k], err[k] = _accumulate(acc[k], err[k], p)
+
+    def mxu_step(i32_ref, f32_ref, acc, err):
+        lane = jax.lax.broadcasted_iota(
+            jnp.int32, (_CHUNK_S, _CHUNK_L, dtile), 2
+        )
+        for c in range(_STEP_CHUNKS):
+            code, streams = sub_chunk(i32_ref, f32_ref, c)
             upd = jnp.stack(streams, axis=1)  # (8, NR, 128)
-            if recipe.keys:
-                lane = jax.lax.broadcasted_iota(
-                    jnp.int32, (_CHUNK_S, _CHUNK_L, _DTILE), 2
-                )
-                oh = (code[:, :, None] == lane).astype(jnp.float32)
-                part = jax.lax.dot_general(
-                    upd, oh,
-                    (((2,), (1,)), ((0,), (0,))),
-                    preferred_element_type=jnp.float32,
-                    precision=jax.lax.Precision.HIGHEST,
-                ).sum(axis=0)  # (NR, 512)
-            else:
-                # one global group: a plain reduction into lane 0.  (A
-                # one-hot of a constant code aborts the TPU compiler.)
-                tot = jnp.sum(jnp.sum(upd, axis=0), axis=1, keepdims=True)
-                lane0 = jax.lax.broadcasted_iota(
-                    jnp.int32, (nr, _DTILE), 1
-                ) == 0
-                part = jnp.where(lane0, tot, jnp.float32(0.0))
+            oh = (code[:, :, None] == lane).astype(jnp.float32)
+            part = jax.lax.dot_general(
+                upd, oh,
+                (((2,), (1,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32,
+                precision=jax.lax.Precision.HIGHEST,
+            ).sum(axis=0)  # (NR, dtile)
             acc[...], err[...] = _accumulate(acc[...], err[...], part)
 
-        @pl.when(i == n_chunks - 1)
-        def _flush():
-            out_ref[0] = acc[...]
-            out_ref[1] = err[...]
+    def kernel(i32_ref, f32_ref, out_ref):
+        # every grid step maps to the one output block, so it stays in VMEM
+        # for the whole table and is the accumulator: out[0] the running
+        # sums, out[1] what their roundings lost
+        @pl.when(pl.program_id(0) == 0)
+        def _init():
+            out_ref[...] = jnp.zeros((2,) + acc_shape, jnp.float32)
+
+        step = vpu_step if form == "vpu" else mxu_step
+        step(i32_ref, f32_ref, out_ref.at[0], out_ref.at[1])
 
     vmem = pltpu.VMEM
     step_s = _STEP_ROWS // _CHUNK_L
+    origin = (0,) * (1 + len(acc_shape))
     return pl.pallas_call(
         kernel,
         grid=(n_chunks,),
@@ -657,13 +709,9 @@ def _fused_kernel(recipe: _Recipe, n_chunks: int, interpret: bool):
             ),
         ],
         out_specs=pl.BlockSpec(
-            (2, nr, _DTILE), lambda i: (0, 0, 0), memory_space=vmem
+            (2,) + acc_shape, lambda i: origin, memory_space=vmem
         ),
-        out_shape=jax.ShapeDtypeStruct((2, nr, _DTILE), jnp.float32),
-        scratch_shapes=[
-            pltpu.VMEM((nr, _DTILE), jnp.float32),
-            pltpu.VMEM((nr, _DTILE), jnp.float32),
-        ],
+        out_shape=jax.ShapeDtypeStruct((2,) + acc_shape, jnp.float32),
         interpret=interpret,
         name="fused_scan",
     )
@@ -716,10 +764,16 @@ def run(recipe: _Recipe, scan_cols, live, *, interpret: bool = False):
     call = _fused_kernel(recipe, n_chunks, interpret)
     with jax.enable_x64(False):
         out = call(i32, f32)
-    totals = (
-        out[0].astype(jnp.float64) + out[1].astype(jnp.float64)
-    )[:, : recipe.domain]
-    return totals
+    return _totals(recipe, out)
+
+
+def _totals(recipe: _Recipe, out):
+    """The kernel's (acc, err) pair recombined in f64 -> (NR, D)."""
+    tot = out[0].astype(jnp.float64) + out[1].astype(jnp.float64)
+    if scatter_form(recipe)[0] == "vpu":
+        nr = len(recipe.streams)
+        return tot.reshape(recipe.domain, nr, -1).sum(axis=2).T
+    return tot[:, : recipe.domain]
 
 
 def assemble(recipe: _Recipe, totals):
