@@ -155,9 +155,14 @@ def test_radix_topk_compiles_for_v5e(one_chip, monkeypatch):
 @pytest.fixture(scope="module")
 def fused_recipes():
     """The q01 and q06 recipes as the engine plans them (captured at
-    SF0.01 under interpret mode; dictionaries, so domains, equal SF1's)."""
+    SF0.01 under interpret mode; dictionaries, so domains, equal SF1's), and
+    q06's as a prepared statement plans it: five `?` sites, five scalars."""
+    import json
+
     from tests.tpch_queries import QUERIES
     from trino_tpu.connectors.tpch import TpchConnector
+    from trino_tpu.exec.compilesvc import CompileService
+    from trino_tpu.ops import kernels
     from trino_tpu.ops.pallas import fused
     from trino_tpu.runtime.engine import Engine
 
@@ -171,23 +176,34 @@ def fused_recipes():
     eng = Engine()
     eng.register_catalog("tpch", TpchConnector(0.01))
     eng.session.set("pallas_interpret", "true")
+    # its own service: the process's may hold these programs already (another
+    # test file's, in this worker), and a joined program is never traced
+    eng.executor.compile_service = eng._local_fallback.compile_service = CompileService()
     fused.run = spy
     try:
         out = {}
         for name in ("q01", "q06"):
             eng.query(QUERIES[name])
             out[name] = captured.pop("last")
+        with open(os.path.join(os.path.dirname(os.path.dirname(
+                os.path.abspath(__file__))), "benchmarks", "templates", "q06.json")) as f:
+            prepared = "\n".join(json.load(f)["prepared_text"])
+        eng.execute("PREPARE q06 FROM " + prepared)
+        eng.execute("EXECUTE q06 USING DATE '1994-01-01', DATE '1995-01-01', 0.05, 0.07, 24")
+        out["q06-prepared"] = captured.pop("last")
     finally:
         fused.run = orig
+        kernels.set_policy(kernels.KernelPolicy())  # the engine set the process's
     return out
 
 
-@pytest.mark.parametrize("name", ["q01", "q06", "domain-129"])
+@pytest.mark.parametrize("name", ["q01", "q06", "domain-129", "q06-prepared"])
 def test_fused_scan_compiles_for_v5e(one_chip, fused_recipes, name):
     """q06 (keyless) and q01 (6 groups) take the select-and-add scatter;
     `domain-129` is q01's recipe with 65 return flags (130 groups: one more
     lane tile than 128), so the one-hot matmul form, at two lane tiles, is
-    still compiled somewhere."""
+    still compiled somewhere; `q06-prepared` is `EXECUTE q06`'s recipe, its
+    bindings scalar operands in SMEM (two int32 dates, three f32 pairs)."""
     import dataclasses
 
     from trino_tpu.ops.expr import ColumnVal
@@ -202,9 +218,10 @@ def test_fused_scan_compiles_for_v5e(one_chip, fused_recipes, name):
         assert fused.scatter_form(recipe) == ("mxu", 256)
     else:
         assert fused.scatter_form(recipe) == ("vpu", 128)
+    assert len(recipe.params) == (5 if name == "q06-prepared" else 0)
     used = {i for i, _ in recipe.cols}
 
-    def run(live, *arrays):
+    def run(live, params, *arrays):
         it = iter(arrays)
         scan = []
         for i, cv in enumerate(cols):
@@ -214,9 +231,11 @@ def test_fused_scan_compiles_for_v5e(one_chip, fused_recipes, name):
             data = next(it)
             valid = next(it) if cv.valid is not None else None
             scan.append(ColumnVal(data, valid, cv.dict, cv.type, None))
-        return fused.run(recipe, scan, live)
+        return fused.run(recipe, scan, live, params=params)
 
-    shapes = [_col(one_chip, jnp.bool_)]
+    shapes = [_col(one_chip, jnp.bool_), tuple(
+        jax.ShapeDtypeStruct((), jnp.dtype(p.type.np_dtype), sharding=one_chip)
+        for p in recipe.params)]
     for i, cv in enumerate(cols):
         if i in used:
             shapes.append(_col(one_chip, cv.data.dtype))

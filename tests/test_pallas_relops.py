@@ -537,3 +537,128 @@ def test_fused_accumulator_counts_exactly_past_2_24_rows():
     assert acc.dtype == err.dtype == jnp.float32
     assert float(acc) != float(counts.sum())  # the f32 sum alone has rounded
     assert int(np.float64(acc) + np.float64(err)) == int(counts.sum())
+
+
+# ------------------------------------------ prepared statements on the kernel
+
+
+def _bench(*names):
+    """benchmarks/loader.py, traffic.py, compare.py: the templates'
+    prepared texts, a binding's literals and reference arguments, and the
+    comparison that decides `correct` — what the benchmark's cells use."""
+    import importlib
+    import os
+    import sys
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+                         "benchmarks")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    return [importlib.import_module(n) for n in names]
+
+
+# the domain's corners (clause 2.4.6.3 / 2.4.1.3) and the validation values
+_PREPARED_BINDINGS = {
+    "q06": [{"year": 1994, "discount": 6, "quantity": 24},
+            {"year": 1993, "discount": 2, "quantity": 24},
+            {"year": 1997, "discount": 9, "quantity": 25},
+            {"year": 1993, "discount": 9, "quantity": 25},
+            {"year": 1997, "discount": 2, "quantity": 24},
+            {"year": 1995, "discount": 5, "quantity": 25}],
+    "q01": [{"delta": 90}, {"delta": 60}, {"delta": 120}, {"delta": 61},
+            {"delta": 119}, {"delta": 75}],
+}
+# how many of a template's bindings are also sent as text: a text with new
+# literals is a new program, and q01's compiles for ~10 s interpreted
+_AS_TEXT = {"q06": 6, "q01": 1}
+
+
+@pytest.mark.parametrize("name", ["q06", "q01"])
+def test_prepared_bindings_ride_the_fused_scan_as_scalars(name, tpch_tiny):
+    """PREPARE + EXECUTE of the benchmark's prepared texts: every binding is
+    answered by ONE compiled program whose fused scan takes the bindings as
+    scalar operands, equal to the last digit to the text statement with the
+    same literals (one kernel, the values constants there) and within the
+    benchmark's limits of the plain numpy reference."""
+    from trino_tpu.connectors.tpch import TpchConnector
+    from trino_tpu.exec.compilesvc import SERVICE
+    from trino_tpu.runtime.engine import Engine
+
+    loader, traffic, compare = _bench("loader", "traffic", "compare")
+    t = loader.load_json("templates", name + ".json")
+    reference = loader.load_module("reference", t["reference"]).reference
+    limits = loader.load_json("configs", "tpch_sf10_served_prepared.json")["limits"]
+    data = {"lineitem": tpch_tiny["lineitem"]}
+    eng = Engine()
+    eng.register_catalog("tpch", TpchConnector(0.01))
+    eng.session.set("pallas_interpret", "true")
+    eng.execute(f"PREPARE {name} FROM " + loader.sql_text(t, "prepared_text"))
+    bindings = [traffic.Binding(t, p) for p in _PREPARED_BINDINGS[name]]
+    assert len({b.key for b in bindings}) >= 6
+    builds = SERVICE.builds
+    scatter = kernels.FUSED_SCATTER.value("vpu")
+    got = [eng.execute(f"EXECUTE {name} USING " + ", ".join(b.literals))
+           for b in bindings]
+    assert SERVICE.builds == builds + 1, "one program per prepared statement"
+    assert kernels.FUSED_SCATTER.value("vpu") == scatter + 1
+    ex = [r[0] for r in eng.execute(
+        f"EXPLAIN ANALYZE EXECUTE {name} USING " + ", ".join(bindings[0].literals))]
+    assert SERVICE.builds == builds + 1
+    detail = [l for l in ex if l.startswith("-- kernel: pallas fused_pipeline")]
+    assert len(detail) == 1 and detail[0].endswith(
+        f"scatter vpu tile 128 params {len(t['sites'])})"), ex
+    assert any("plan_cache=hit" in l and f"bound={len(t['sites'])} baked=0" in l
+               for l in ex), ex
+    for b, rows in zip(bindings, got):
+        c = compare.compare(rows, reference(data, *b.args), t["ordered"])
+        assert all(c[k] <= limits[k] for k in limits), (b.params, c)
+    for b, rows in list(zip(bindings, got))[: _AS_TEXT[name]]:
+        sql = loader.sql_text(t, "prepared_text")
+        for lit in b.literals:
+            sql = sql.replace("?", lit, 1)
+        assert eng.execute(sql) == rows, b.params
+
+
+@pytest.mark.parametrize("case", ["varchar", "null", "wide_decimal", "wide_compared"])
+def test_prepared_parameter_the_kernel_cannot_take(case, tpch_tiny):
+    """What cannot ride as a kernel scalar still answers right: a varchar
+    and a NULL are baked per value by the fast path (constants of the plan),
+    a BIGINT in arithmetic could pass what a double-float pair holds and
+    declines the kernel; under a comparison any BIGINT orders right."""
+    from trino_tpu.connectors.tpch import TpchConnector
+    from trino_tpu.runtime.engine import Engine
+
+    text, using = {
+        "varchar": ("select count(*), sum(l_quantity) from lineitem where l_shipmode = ?"
+                    " and l_quantity < ?", "'MAIL', 24"),
+        "null": ("select count(*), sum(l_quantity) from lineitem where l_quantity < ?"
+                 " or l_discount = ?", "24, NULL"),
+        "wide_decimal": ("select sum(l_extendedprice * ?) from lineitem where l_quantity < ?",
+                         "3, 24"),
+        "wide_compared": ("select count(*), sum(l_quantity) from lineitem where l_quantity < ?",
+                          "100000000000000000"),
+    }[case]
+    eng = Engine()
+    eng.register_catalog("tpch", TpchConnector(0.01))
+    eng.session.set("pallas_interpret", "true")
+    eng.execute("PREPARE p FROM " + text)
+    got = eng.execute("EXECUTE p USING " + using)
+    ex = [r[0] for r in eng.execute("EXPLAIN ANALYZE EXECUTE p USING " + using)]
+    fused = [l for l in ex if l.startswith("-- kernel: pallas fused_pipeline")]
+    footer = next(l for l in ex if l.startswith("-- fastpath:"))
+    if case == "varchar":  # a constant of the plan; no string predicate fuses
+        assert "bound=1 baked=1" in footer and not fused, ex
+    elif case == "null":  # a constant NULL of the plan, the number a scalar
+        assert "bound=1 baked=1" in footer and fused[0].endswith("params 1)"), ex
+    elif case == "wide_decimal":  # a BIGINT factor: off the kernel, one scalar of the program
+        assert "bound=2 baked=0" in footer and not fused, ex
+    else:
+        assert "bound=1 baked=0" in footer and fused[0].endswith("params 1)"), ex
+    sql = text
+    for lit in using.split(", "):
+        sql = sql.replace("?", lit, 1)
+    eng.session.set("data_plane_kernels", "false")
+    want = eng.execute(sql)
+    assert got == want, (got, want)
+    if case == "wide_compared":
+        assert got[0][0] == len(tpch_tiny["lineitem"]["l_quantity"])

@@ -188,6 +188,69 @@ def test_http_spans_never_land_in_another_threads_tree(served):
             assert not below
 
 
+def test_prepared_request_leaves_the_spans_of_a_text_request():
+    """EXECUTE of a client-held prepared statement through client ->
+    coordinator: the fast path's `planner` says how the plan was come by,
+    the executor's span is the coordinator's `root_fragment`, its `dispatch`
+    names the fused scan with the bindings as kernel scalars, and the roots
+    are a text request's."""
+    from trino_tpu.client.client import StatementClient
+    from trino_tpu.connectors.tpch import TpchConnector
+    from trino_tpu.ops import kernels
+    from trino_tpu.testing.runner import DistributedQueryRunner
+
+    runner = DistributedQueryRunner(num_workers=1)
+    runner.register_catalog("tpch", TpchConnector(SCALE))
+    runner.start()
+    try:
+        coord = runner.coordinator
+        coord.session.set("pallas_interpret", "true")  # the kernel, on the CPU
+        exporter = InMemorySpanExporter()
+        coord.tracer.add_exporter(exporter)
+        client = StatementClient(runner.client_url)
+        client.prepared["q06"] = (
+            "select sum(l_extendedprice * l_discount) as revenue from lineitem "
+            "where l_shipdate >= ? and l_shipdate < ? "
+            "and l_discount between ? and ? and l_quantity < ?")
+        queries = []
+        for using in ("DATE '1994-01-01', DATE '1995-01-01', 0.05, 0.07, 24",
+                      "DATE '1996-01-01', DATE '1997-01-01', 0.02, 0.04, 25"):
+            _cols, rows = client.execute("EXECUTE q06 USING " + using)
+            assert len(rows) == 1
+            qid = client.last_query_id
+            deadline = time.time() + 5.0
+            while time.time() < deadline:  # finalize and the poll end later
+                roots = [s for s in exporter.snapshot()
+                         if s.attributes.get("query_id") == qid]
+                if {"finalize", "http.get"} <= {s.name for s in roots}:
+                    break
+                time.sleep(0.01)
+            assert sorted({s.name for s in roots}) == [
+                "finalize", "http.get", "http.post", "query"]
+            queries.append(next(s for s in roots if s.name == "query"))
+    finally:
+        runner.stop()
+        kernels.set_policy(kernels.KernelPolicy())  # the process's, not the session's
+    first, second = queries
+    assert [c.name for c in first.children] == [
+        "queued", "planner", "root_fragment", "to_rows"]
+    assert [c.name for c in first.find("root_fragment").children] == [
+        "scan_load", "compile", "dispatch", "device_wait"]
+    assert first.find("planner").attributes == {"preplanned": False, "plan_cache": "miss"}
+    # a new binding: the cached plan, the compiled program, the same kernel
+    assert second.find("planner").attributes == {"preplanned": True, "plan_cache": "hit"}
+    assert [c.name for c in second.find("root_fragment").children] == [
+        "scan_load", "dispatch", "device_wait"]
+    for q in queries:
+        kernels = q.find("dispatch").attributes["kernels"]
+        assert re.fullmatch(
+            r"pallas fused_pipeline \(5 filters 3 streams domain 1 scatter vpu "
+            r"tile 128 params 5\)", kernels), kernels
+        assert q.find("to_rows").attributes["rows"] == 1
+    assert (first.find("dispatch").attributes["signature"]
+            == second.find("dispatch").attributes["signature"])
+
+
 # ------------------------------------------------- (b) scan_load's counter
 
 

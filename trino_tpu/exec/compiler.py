@@ -1051,8 +1051,15 @@ def _make_call(plan: PlanNode, caps: dict[int, int], collect: bool):
     holder: dict = {"keys": None}
 
     def call(pages, params=(), _holder=holder):
+        from ..ops import kernels as _kernels
+
         with param_context(params):
             out_page, req = _trace_plan(plan, pages, caps, collect_stats=collect)
+        # what every `dispatch` span of this program says of it: the
+        # kernels the trace chose, as EXPLAIN ANALYZE's `-- kernel:` lines
+        chosen = _kernels.describe(plan)
+        if chosen:
+            _holder["dispatch"] = {"kernels": "; ".join(chosen)}
         return out_page, _pack_required(req, _holder)
 
     return call, holder
@@ -1306,10 +1313,16 @@ def _trace_plan(
         _kernels.record_dispatch(
             "fused_pipeline", "pallas",
             f"{len(filters)} filters {len(recipe.streams)} streams "
-            f"domain {recipe.domain} scatter {form} tile {tile}",
+            f"domain {recipe.domain} scatter {form} tile {tile} "
+            f"params {len(recipe.params)}",
         )
         _kernels.FUSED_SCATTER.labels(form=form).inc()
-        totals = _fused.run(recipe, scan_cols, live, interpret=policy.interpret)
+        # a prepared statement's bindings: scalars of the program (tracers
+        # under jit, from the parameter context), operands of the kernel
+        bound = [eval_expr(prm, (), 1).data[0] for prm in recipe.params]
+        totals = _fused.run(
+            recipe, scan_cols, live, params=bound, interpret=policy.interpret
+        )
         key_codes, agg_cols, out_live, n_groups = _fused.assemble(recipe, totals)
         report(nid, n_groups)
         if collect_stats:

@@ -17,7 +17,9 @@ module is the one place that decision is configured and observed:
     recorded at TRACE time (kernel selection), once per compiled program —
     a jit-cache hit re-runs the selected kernel without re-counting.
   * events_for(plan) — the captured events of the last trace of `plan`
-    (plans are frozen dataclasses, so they key a bounded dict directly).
+    (plans are frozen dataclasses, so they key a bounded dict directly);
+    describe(plan) — the same as text, one line an event: EXPLAIN ANALYZE's
+    `-- kernel:` lines and the `dispatch` span's `kernels`.
 
 ops: group_by (ops/pallas/hashagg.py), join (hashagg build + hashjoin
 probe), fused_pipeline (fused.py), segment_reduce (segreduce.py — the
@@ -44,7 +46,7 @@ from ..utils import metrics as _metrics
 __all__ = [
     "KernelPolicy", "get_policy", "set_policy", "policy_key",
     "record_dispatch", "begin_capture", "end_capture", "remember",
-    "events_for",
+    "events_for", "describe",
 ]
 
 
@@ -147,3 +149,11 @@ def events_for(plan) -> tuple:
         return ()
     with _EVENTS_LOCK:
         return _EVENTS.get(plan, ())
+
+
+def describe(plan) -> list[str]:
+    """`<impl> <op> (<detail>)` for each event of the plan's last trace."""
+    return [
+        f"{impl} {op}" + (f" ({detail})" if detail else "")
+        for op, impl, detail in events_for(plan)
+    ]

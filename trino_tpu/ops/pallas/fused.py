@@ -9,7 +9,16 @@ once and does everything else in VMEM:
 
   * the compiler (exec/compiler.py) substitutes the filter predicates and
     aggregate arguments down to scan level (plan/ir.substitute), so the
-    kernel receives raw column planes plus a closed IR tree;
+    kernel receives raw column planes plus a closed IR tree of FieldRef,
+    Const, Param and Call;
+  * a Param (a bound parameter of a prepared statement, plan/ir.py) is
+    checked like a constant of its type and scale and reaches the kernel
+    as a scalar operand in SMEM — an int32, or the hi/lo f32 pair made
+    outside the kernel from the traced value as _dd_const makes a
+    constant's — so every binding of one prepared statement runs one
+    compiled kernel and a statement without parameters has the operands
+    it always had.  No value is in the recipe, which is the kernel's cache
+    key;
   * numeric lanes travel as double-float pairs (hi = f32(v),
     lo = f32(v - f64(hi))): exact for |v| < 2^47, which covers the scaled
     decimals of the TPC-H fact columns; arithmetic uses the classic
@@ -70,7 +79,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from ...plan.ir import Call, Const, FieldRef, IrExpr
+from ...plan.ir import Call, Const, FieldRef, IrExpr, Param
 from .hashagg import (
     _CHUNK_L,
     _CHUNK_S,
@@ -123,6 +132,8 @@ class _Recipe:
     aggs: tuple     # ("count", si) | ("sum", hi, lo, cnt, scale_shift, wide)
                     # | ("avg", hi, lo, cnt, scale_shift) | ("fsum", hi, lo, cnt)
                     # | ("favg", hi, lo, cnt)
+    params: tuple = ()  # the distinct Param nodes, in the order `run` takes
+                        # their values
 
 
 def _kind_of_type(t) -> tuple[str, Optional[int]]:
@@ -140,6 +151,68 @@ def _kind_of_type(t) -> tuple[str, Optional[int]]:
     raise _Unsupported(f"type {name}")
 
 
+def _kind_of_param(t) -> tuple[str, Optional[int]]:
+    """A parameter rides like a constant of its type.  An EXECUTE's integer
+    literal is a BIGINT, which no column here may be (a BIGINT sum wraps mod
+    2^64) but a scalar can: a whole number at scale 0."""
+    if getattr(t, "name", "") == "bigint":
+        return "dd", 0
+    return _kind_of_type(t)
+
+
+def _kind_of(e: IrExpr) -> tuple[str, Optional[int]]:
+    return _kind_of_param(e.type) if isinstance(e, Param) else _kind_of_type(e.type)
+
+
+def _param_fits(t) -> bool:
+    """Whether the type alone bounds a parameter's payload to what a
+    double-float pair holds exactly: the value is not there to look at."""
+    kind, scale = _kind_of_param(t)
+    if kind != "dd" or scale is None:
+        return True  # an int32 scalar; a double is approximate by nature
+    return t.is_decimal and 10 ** t.precision <= (1 << _DD_EXACT_BITS)
+
+
+def _compared(e: Call) -> tuple[IrExpr, IrExpr, int, int]:
+    """A comparison's operands as the kernel compares them, and the power
+    of ten each is raised by first.  A decimal column beside a BIGINT
+    parameter is planned as doubles (plan/planner.py _cmp: the value that
+    would say how far the integer rescales is not there), and the kernel's
+    cast to double multiplies by a rounded 10^-s, which can put 24.00 on
+    either side of 24.  Both sides being exact numbers under their casts,
+    comparing them at their common scale is what the doubles say, for every
+    payload a double-float pair holds, and is the form a constant takes."""
+    inner = []
+    for a in e.args:
+        if not (isinstance(a, Call) and a.op == "cast"
+                and _kind_of_type(a.type) == ("dd", None)):
+            return e.args[0], e.args[1], 0, 0
+        try:
+            kind, scale = _kind_of(a.args[0])
+        except _Unsupported:
+            return e.args[0], e.args[1], 0, 0
+        if kind == "bool" or scale is None:
+            return e.args[0], e.args[1], 0, 0
+        inner.append((a.args[0], scale))
+    (a, sa), (b, sb) = inner
+    return a, b, max(sa, sb) - sa, max(sa, sb) - sb
+
+
+def _scalar_slots(params) -> tuple[list, int, int]:
+    """Where each parameter lies among the kernel's scalar operands:
+    ("i", k) the k-th int32, ("f", k) the f32 pair at k and k + 1
+    -> (slots, int32s in all, f32s in all)."""
+    slots, n_i, n_f = [], 0, 0
+    for prm in params:
+        if _kind_of_param(prm.type)[0] == "dd":
+            slots.append(("f", n_f))
+            n_f += 2
+        else:
+            slots.append(("i", n_i))
+            n_i += 1
+    return slots, n_i, n_f
+
+
 class _Planner:
     def __init__(self, cols):
         self.scan_cols = cols
@@ -148,6 +221,7 @@ class _Planner:
         self.n_f32 = 0
         self.streams: list = []
         self.stream_ix: dict = {}
+        self.params: list = []
 
     def use_col(self, i: int) -> tuple:
         got = self.col_plan.get(i)
@@ -191,7 +265,12 @@ class _Planner:
 
     # ---- static type/nullability check: returns (kind, scale, nullable)
 
-    def check(self, e: IrExpr) -> tuple[str, Optional[int], bool]:
+    def check(self, e: IrExpr, compared: bool = False) -> tuple[str, Optional[int], bool]:
+        """`compared`: `e` is an operand of a comparison, directly or under
+        casts.  Only there may a parameter be wider than a double-float pair
+        holds exactly: the pair of any int64 still orders right against the
+        exact pairs of the columns (rounding is monotone), while a sum or a
+        product over it would round in silence."""
         if isinstance(e, FieldRef):
             plan = self.use_col(e.index)
             cv = self.scan_cols[e.index]
@@ -204,11 +283,18 @@ class _Planner:
             if kind == "dd" and scale is not None and abs(int(e.value)) >= (1 << _DD_EXACT_BITS):
                 raise _Unsupported("decimal constant too wide")
             return kind, scale, False
+        if isinstance(e, Param):  # bound, so never NULL (a NULL is baked)
+            kind, scale = _kind_of_param(e.type)
+            if not (compared or _param_fits(e.type)):
+                raise _Unsupported("parameter too wide for arithmetic")
+            if e not in self.params:
+                self.params.append(e)
+            return kind, scale, False
         if isinstance(e, Call):
-            return self._check_call(e)
+            return self._check_call(e, compared)
         raise _Unsupported(f"expression {type(e).__name__}")
 
-    def _check_call(self, e: Call):
+    def _check_call(self, e: Call, compared: bool = False):
         op = e.op
         if op in ("add", "sub", "mul", "neg"):
             sub = [self.check(a) for a in e.args]
@@ -232,11 +318,12 @@ class _Planner:
                     raise _Unsupported(f"{op} operand scales differ")
             return "dd", oscale, nullable
         if op in ("eq", "ne", "lt", "le", "gt", "ge"):
-            (k1, s1, n1), (k2, s2, n2) = self.check(e.args[0]), self.check(e.args[1])
+            a, b, up_a, up_b = _compared(e)
+            (k1, s1, n1), (k2, s2, n2) = self.check(a, True), self.check(b, True)
             if "bool" in (k1, k2):
                 raise _Unsupported("comparison over boolean")
             if (s1 is None) != (s2 is None) or (
-                s1 is not None and s1 != s2
+                s1 is not None and s1 + up_a != s2 + up_b
             ):
                 raise _Unsupported("comparison operand scales differ")
             return "bool", 0, n1 or n2
@@ -254,7 +341,7 @@ class _Planner:
             self.check(e.args[0])
             return "bool", 0, False
         if op == "cast":
-            k, s, nl = self.check(e.args[0])
+            k, s, nl = self.check(e.args[0], compared)
             okind, oscale = _kind_of_type(e.type)
             if okind != "dd":
                 raise _Unsupported(f"cast to {e.type}")
@@ -357,6 +444,7 @@ def plan_pipeline(scan_cols, filters, key_exprs, agg_fns, agg_args, agg_types):
         domain=domain,
         streams=tuple(p.streams),
         aggs=tuple(aggs),
+        params=tuple(p.params),
     )
     return recipe, ""
 
@@ -422,11 +510,13 @@ class _Eval:
     """Evaluates the closed IR over one (8, 128) sub-chunk.  Values are
     (kind, payload..., valid) with valid None when statically non-null."""
 
-    def __init__(self, recipe, i32, f32, shape):
+    def __init__(self, recipe, i32, f32, shape, scalars=()):
         self.col_plan = dict(recipe.cols)
         self.i32 = i32  # list of (8, 128) int32 planes
         self.f32 = f32  # list of (8, 128) f32 planes
         self.shape = shape
+        # Param -> its scalar(s) read from SMEM: an int32, or (hi, lo) f32
+        self.scalars = dict(zip(recipe.params, scalars))
         self.memo: dict = {}
 
     def _valid(self, vplane):
@@ -467,6 +557,15 @@ class _Eval:
             if kind == "bool":
                 return ("bool", jnp.full(self.shape, bool(e.value)), None)
             return ("i32", jnp.full(self.shape, int(e.value), jnp.int32), None)
+        if isinstance(e, Param):  # a constant's forms, the value a scalar
+            kind, _ = _kind_of_param(e.type)
+            v = self.scalars[e]
+            if kind == "dd":
+                full = jnp.full(self.shape, 1.0, jnp.float32)
+                return ("dd", (v[0] * full, v[1] * full), None)
+            if kind == "bool":
+                return ("bool", jnp.full(self.shape, v, jnp.int32) > 0, None)
+            return ("i32", jnp.full(self.shape, v, jnp.int32), None)
         assert isinstance(e, Call)
         return self._call(e)
 
@@ -495,8 +594,9 @@ class _Eval:
                 return ("dd", _dd_add(x, _dd_neg(y)), valid)
             return ("dd", _dd_mul(x, y), valid)
         if op in ("eq", "ne", "lt", "le", "gt", "ge"):
-            a, b = self.ev(e.args[0]), self.ev(e.args[1])
-            if a[0] == "i32" and b[0] == "i32":
+            a, b, up_a, up_b = _compared(e)
+            a, b = self.ev(a), self.ev(b)
+            if a[0] == "i32" and b[0] == "i32" and not (up_a or up_b):
                 x, y = a[1], b[1]
                 data = {
                     "eq": x == y, "ne": x != y, "lt": x < y,
@@ -504,6 +604,10 @@ class _Eval:
                 }[op]
             else:
                 (x, vx), (y, vy) = self._dd(a), self._dd(b)
+                if up_a:
+                    x = _dd_mul(x, _dd_const(10 ** up_a))
+                if up_b:
+                    y = _dd_mul(y, _dd_const(10 ** up_b))
                 if op == "eq":
                     data = _dd_eq(x, y)
                 elif op == "ne":
@@ -534,7 +638,7 @@ class _Eval:
             return ("bool", ~v[-1], None)
         if op == "cast":
             v = self.ev(e.args[0])
-            s = _kind_of_type(e.args[0].type)[1]
+            s = _kind_of(e.args[0])[1]
             oscale = _kind_of_type(e.type)[1]
             (x, _), valid = self._dd(v), v[-1]
             if oscale is None:
@@ -631,12 +735,26 @@ def _fused_kernel(recipe: _Recipe, n_chunks: int, interpret: bool):
         (domain * nr, _CHUNK_S, _CHUNK_L) if form == "vpu" else (nr, dtile)
     )
 
-    def sub_chunk(i32_ref, f32_ref, c):
+    # an operand is there only if some parameter needs it, so a statement
+    # without parameters has the two plane operands alone
+    slots, n_pi, n_pf = _scalar_slots(recipe.params)
+    scalar_shapes = [(1, n) for n in (n_pi, n_pf) if n]
+
+    def read_scalars(refs):
+        refs = list(refs)
+        pi_ref = refs.pop(0) if n_pi else None
+        pf_ref = refs.pop(0) if n_pf else None
+        return [
+            (pf_ref[0, k], pf_ref[0, k + 1]) if where == "f" else pi_ref[0, k]
+            for where, k in slots
+        ]
+
+    def sub_chunk(i32_ref, f32_ref, c, scalars):
         """-> (key code | None, masked streams) of a step's c-th sub-chunk."""
         rows = slice(c * _CHUNK_S, (c + 1) * _CHUNK_S)
         i32 = [i32_ref[p, rows, :] for p in range(recipe.n_i32)]
         f32 = [f32_ref[p, rows, :] for p in range(max(recipe.n_f32, 1))]
-        ev = _Eval(recipe, i32, f32, (_CHUNK_S, _CHUNK_L))
+        ev = _Eval(recipe, i32, f32, (_CHUNK_S, _CHUNK_L), scalars)
         mask = i32[0] > 0
         for f in recipe.filters:
             mask = mask & ev.pred(f)
@@ -646,14 +764,14 @@ def _fused_kernel(recipe: _Recipe, n_chunks: int, interpret: bool):
             code = term if code is None else code + term
         return code, [ev.masked_stream(tag, e, mask) for tag, e in recipe.streams]
 
-    def vpu_step(i32_ref, f32_ref, acc, err):
+    def vpu_step(i32_ref, f32_ref, scalars, acc, err):
         # tile g * nr + s sums stream s over the rows of group g, lane by
         # lane: a step adds 8 rows to each of a tile's 1,024 lanes, and the
         # lanes are summed in f64 outside the kernel (_totals).  One group
         # (no keys, or a dictionary of one value) needs no compare.
         part = [None] * (domain * nr)
         for c in range(_STEP_CHUNKS):
-            code, streams = sub_chunk(i32_ref, f32_ref, c)
+            code, streams = sub_chunk(i32_ref, f32_ref, c, scalars)
             for g in range(domain):
                 hit = None if domain == 1 else code == jnp.int32(g)
                 for s, x in enumerate(streams):
@@ -663,12 +781,12 @@ def _fused_kernel(recipe: _Recipe, n_chunks: int, interpret: bool):
         for k, p in enumerate(part):
             acc[k], err[k] = _accumulate(acc[k], err[k], p)
 
-    def mxu_step(i32_ref, f32_ref, acc, err):
+    def mxu_step(i32_ref, f32_ref, scalars, acc, err):
         lane = jax.lax.broadcasted_iota(
             jnp.int32, (_CHUNK_S, _CHUNK_L, dtile), 2
         )
         for c in range(_STEP_CHUNKS):
-            code, streams = sub_chunk(i32_ref, f32_ref, c)
+            code, streams = sub_chunk(i32_ref, f32_ref, c, scalars)
             upd = jnp.stack(streams, axis=1)  # (8, NR, 128)
             oh = (code[:, :, None] == lane).astype(jnp.float32)
             part = jax.lax.dot_general(
@@ -679,16 +797,19 @@ def _fused_kernel(recipe: _Recipe, n_chunks: int, interpret: bool):
             ).sum(axis=0)  # (NR, dtile)
             acc[...], err[...] = _accumulate(acc[...], err[...], part)
 
-    def kernel(i32_ref, f32_ref, out_ref):
+    def kernel(i32_ref, f32_ref, *rest):
         # every grid step maps to the one output block, so it stays in VMEM
         # for the whole table and is the accumulator: out[0] the running
         # sums, out[1] what their roundings lost
+        *scalar_refs, out_ref = rest
+
         @pl.when(pl.program_id(0) == 0)
         def _init():
             out_ref[...] = jnp.zeros((2,) + acc_shape, jnp.float32)
 
         step = vpu_step if form == "vpu" else mxu_step
-        step(i32_ref, f32_ref, out_ref.at[0], out_ref.at[1])
+        step(i32_ref, f32_ref, read_scalars(scalar_refs),
+             out_ref.at[0], out_ref.at[1])
 
     vmem = pltpu.VMEM
     step_s = _STEP_ROWS // _CHUNK_L
@@ -707,6 +828,9 @@ def _fused_kernel(recipe: _Recipe, n_chunks: int, interpret: bool):
                 lambda i: (0, i, 0),
                 memory_space=vmem,
             ),
+        ] + [
+            pl.BlockSpec(shape, lambda i: (0, 0), memory_space=pltpu.SMEM)
+            for shape in scalar_shapes
         ],
         out_specs=pl.BlockSpec(
             (2,) + acc_shape, lambda i: origin, memory_space=vmem
@@ -727,8 +851,9 @@ def _dd_planes(data):
     return hi, lo
 
 
-def run(recipe: _Recipe, scan_cols, live, *, interpret: bool = False):
-    """Execute the fused pipeline.
+def run(recipe: _Recipe, scan_cols, live, *, params=(), interpret: bool = False):
+    """Execute the fused pipeline.  `params`: the value of each of
+    `recipe.params` as a scalar of its SQL type's dtype, traced or concrete.
 
     Returns (totals f64 (NR, D), n_groups int array) — per-stream per-group
     sums; the caller assembles aggregate columns via `assemble`."""
@@ -760,10 +885,18 @@ def run(recipe: _Recipe, scan_cols, live, *, interpret: bool = False):
         if recipe.n_f32 == 0:
             f32_planes[0] = _prep(jnp.zeros((1,), jnp.float32), n_pad, 0.0)
         i32, f32 = jnp.stack(i32_planes), jnp.stack(f32_planes)
+        pi, pf = [], []
+        slots, _, _ = _scalar_slots(recipe.params)
+        for (where, _k), v in zip(slots, params, strict=True):
+            if where == "f":
+                pf.extend(_dd_planes(jnp.asarray(v)))  # as _dd_const's pair
+            else:
+                pi.append(jnp.asarray(v).astype(jnp.int32))
+        scalars = [jnp.stack(p).reshape(1, -1) for p in (pi, pf) if p]
 
     call = _fused_kernel(recipe, n_chunks, interpret)
     with jax.enable_x64(False):
-        out = call(i32, f32)
+        out = call(i32, f32, *scalars)
     return _totals(recipe, out)
 
 

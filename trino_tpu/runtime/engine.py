@@ -58,6 +58,14 @@ def _rescale_column(arr, src_type, dst_type):
     return np.ma.MaskedArray(out, mask=mask) if mask is not None else out
 
 
+def _kernel_lines(plan) -> list[str]:
+    """EXPLAIN ANALYZE's `-- kernel:` footer: what the data-plane kernels
+    dispatched in the plan's last trace (ops/kernels.py)."""
+    from ..ops.kernels import describe
+
+    return [f"-- kernel: {line}" for line in describe(plan)]
+
+
 class Engine:
     """distributed=True runs every query SPMD over `devices` (default: all
     jax.devices()) with exchange collectives — the in-process analogue of the
@@ -605,6 +613,7 @@ class Engine:
         sql_text = self._resolve_prepared(ex_stmt.name, prepared)
         fp = self.fastpath()
         t0 = _time.perf_counter()
+        self._apply_compile_props()  # the kernel policy keys the plan cache
         try:
             tmpl, n_params = fp._template(sql_text)
             if len(ex_stmt.parameters) != n_params:
@@ -613,7 +622,7 @@ class Engine:
                     f" got {len(ex_stmt.parameters)}"
                 )
             slots = fp._slots(ex_stmt.parameters)
-            entry = fp._lookup(sql_text, tmpl.query, slots)
+            entry, info = fp._lookup(sql_text, tmpl.query, slots)
         except NotFastpath:
             bound = S.parse_statement(sql_text, params=ex_stmt.parameters)
             if not isinstance(bound, S.QueryStmt):
@@ -622,17 +631,16 @@ class Engine:
             text = [r[0] for r in self._execute_explain(inner)]
             text.append("-- fastpath: off (legacy substitute-and-replan path)")
             return [(line,) for line in text]
-        info = fp.last_info
         text = format_plan(entry.plan).splitlines()
         if stmt.analyze:
             params = fp._param_values(entry.slots, slots)
-            self._apply_compile_props()
             page = fp._executor().execute(entry.plan, params=params)
             rows = page.to_pylist()
             wall = _time.perf_counter() - t0
             text.append(
                 f"-- output rows: {len(rows)}, wall: {wall * 1e3:.1f} ms"
             )
+            text.extend(_kernel_lines(entry.plan))
         window = float(self.session.get("execute_batch_window_ms") or 0.0)
         text.append(
             f"-- fastpath: plan_cache={info.cache} bound={info.bound}"
@@ -712,13 +720,7 @@ class Engine:
                 f"-- output rows: {len(page.to_pylist())}, wall: {wall * 1000:.1f} ms"
             )
             text.extend(self._profile_footer(ex, n_ev0))
-            from ..ops.kernels import events_for
-
-            for op, impl, detail in events_for(plan):
-                text.append(
-                    f"-- kernel: {impl} {op}"
-                    + (f" ({detail})" if detail else "")
-                )
+            text.extend(_kernel_lines(plan))
             return [(line,) for line in text]
         rows = self.query(stmt.query)
         wall = _time.perf_counter() - t0

@@ -12,11 +12,21 @@ analyzer binding) on top of the jit data plane:
   * at EXECUTE, bindable scalar parameters (numerics, booleans, dates,
     int64-range decimals) become `ir.Param` nodes — runtime jit ARGUMENTS,
     not plan constants — so every binding of one prepared statement shares
-    a single canonical plan and ONE compiled program (zero retrace);
+    a single canonical plan and ONE compiled program (zero retrace).  Where
+    the plan is a scan, filters and one aggregation, they are operands of
+    the fused scan kernel itself (ops/pallas/fused.py): a date, integer or
+    boolean as an int32 scalar, a double, a decimal or a BIGINT as the
+    hi/lo f32 pair made from the traced value — a BIGINT or a decimal of
+    more than 14 digits only under a comparison; in arithmetic such a one
+    keeps the plan off the kernel, a scalar of the operator-at-a-time
+    program.  That program sizes nothing by its bindings, so the one
+    warm-up execution learns all there is and the deferred overflow check
+    has nothing to trip on;
   * value-dependent parameters (varchar — string ops are lowered per
     distinct dictionary value on the host at trace time — NULLs, beyond-
     int64 decimals) are BAKED as constants, giving a per-value plan: the
-    classic generic-vs-custom-plan split, still cached per value;
+    classic generic-vs-custom-plan split, still cached per value; the
+    kernel sees them as the constants a text statement has;
   * plans land in a ParameterizedPlanCache: LRU, kill switch
     (`plan_cache_enabled`), pinned to the scanned tables' version vector
     (resultcache.py discipline — DML/snapshot bumps invalidate), counted in
@@ -102,7 +112,7 @@ class _Pending:
 
 @dataclass
 class _Info:
-    """Last fast-path disposition, surfaced by the EXPLAIN footer."""
+    """How a lookup came by its plan: the `planner` span and the EXPLAIN footer."""
 
     cache: str = "miss"
     bound: int = 0
@@ -121,7 +131,6 @@ class FastPath:
         self._templates: dict[str, tuple] = {}   # sql -> (template stmt, n)
         self._cache: OrderedDict = OrderedDict()
         self._lock = threading.RLock()
-        self.last_info: Optional[_Info] = None
         self.last_columns: Optional[list] = None
         # the template the last EXECUTE resolved to; the coordinator stamps
         # it into the query-history record so recurrence counts replicate
@@ -245,21 +254,21 @@ class FastPath:
         PLAN_CACHE_EVENTS.labels("invalidated").inc()
         return None
 
-    def _lookup(self, sql: str, query, slots) -> _PlanEntry:
+    def _lookup(self, sql: str, query, slots) -> tuple[_PlanEntry, _Info]:
+        """-> (the plan's entry, how it was come by: hit | miss | bypass)."""
         eng = self.engine
         cache_on = bool(eng.session.get("plan_cache_enabled"))
         key = self._entry_key(sql, slots)
 
-        def info(kind, entry_slots):
-            bound = sum(1 for m, _t, _v in entry_slots if m == "bind")
-            return _Info(kind, bound, len(entry_slots) - bound)
+        def found(kind, entry):
+            PLAN_CACHE_EVENTS.labels(kind).inc()
+            bound = sum(1 for m, _t, _v in entry.slots if m == "bind")
+            return entry, _Info(kind, bound, len(entry.slots) - bound)
 
         with self._lock:
             entry = self._cache_get(key) if cache_on else None
             if entry is not None:
-                PLAN_CACHE_EVENTS.labels("hit").inc()
-                self.last_info = info("hit", entry.slots)
-                return entry
+                return found("hit", entry)
         plan, used = self._plan(query, slots)
         if used != slots:
             # planning REBAKED the parameters (literal-required positions):
@@ -269,9 +278,7 @@ class FastPath:
             with self._lock:
                 entry = self._cache_get(key) if cache_on else None
                 if entry is not None:
-                    PLAN_CACHE_EVENTS.labels("hit").inc()
-                    self.last_info = info("hit", entry.slots)
-                    return entry
+                    return found("hit", entry)
         entry = _PlanEntry(
             plan=plan,
             slots=used,
@@ -280,9 +287,7 @@ class FastPath:
         )
         if not cache_on or entry.version_vector is None:
             # kill switch / time-travel scans: plan served, never cached
-            PLAN_CACHE_EVENTS.labels("bypass").inc()
-            self.last_info = info("bypass", used)
-            return entry
+            return found("bypass", entry)
         with self._lock:
             self._cache[key] = entry
             self._cache.move_to_end(key)
@@ -302,9 +307,7 @@ class FastPath:
                 )
                 del self._cache[victim]
                 PLAN_CACHE_EVENTS.labels("evicted").inc()
-        PLAN_CACHE_EVENTS.labels("miss").inc()
-        self.last_info = info("miss", used)
-        return entry
+        return found("miss", entry)
 
     def _recurring_templates(self, min_n: int = 2) -> frozenset:
         """Templates that recurred across the query history — the plan
@@ -372,24 +375,36 @@ class FastPath:
         eng = self.engine
         if not bool(eng.session.get("prepared_fastpath_enabled")):
             raise NotFastpath("prepared_fastpath_enabled=false")
-        stmt, n_params = self._template(sql)
-        self.last_template = sql
-        if len(param_exprs) != n_params:
-            raise ValueError(
-                f"prepared statement takes {n_params} parameters,"
-                f" got {len(param_exprs)}"
+        # the spans a text request has (utils/tracing.py; PERF.md section 3):
+        # `planner` is the template, the bindings and the plan cache
+        with eng.tracer.span("planner") as span:
+            # first: the kernel policy is part of the plan cache's key
+            eng._apply_compile_props()
+            stmt, n_params = self._template(sql)
+            self.last_template = sql
+            if len(param_exprs) != n_params:
+                raise ValueError(
+                    f"prepared statement takes {n_params} parameters,"
+                    f" got {len(param_exprs)}"
+                )
+            slots = self._slots(param_exprs)
+            entry, info = self._lookup(sql, stmt.query, slots)
+            span.attributes.update(
+                preplanned=info.cache == "hit", plan_cache=info.cache
             )
-        slots = self._slots(param_exprs)
-        entry = self._lookup(sql, stmt.query, slots)
         self.last_columns = list(entry.output_names)
-        eng._apply_compile_props()
         params = self._param_values(entry.slots, slots)
         window_s = float(eng.session.get("execute_batch_window_ms") or 0.0) / 1e3
         if window_s > 0.0 and not analyze:
-            rows = self._submit_batched(entry, params, window_s)
-        else:
+            return self._submit_batched(entry, params, window_s)
+        # the coordinator runs the whole plan itself, as it runs a text
+        # statement's result fragment; an Engine calls that `execute`
+        on_coordinator = getattr(eng, "_coord", None) is not None
+        with eng.tracer.span("root_fragment" if on_coordinator else "execute"):
             page = self._executor().execute(entry.plan, params=params)
+        with eng.tracer.span("to_rows", d2h_bytes=page.nbytes) as span:
             rows = page.to_pylist()
+            span.attributes["rows"] = len(rows)
         return rows
 
     # ------------------------------------------------- shared query batching
@@ -461,7 +476,7 @@ class FastPath:
         return inputs
 
     def _compiled(self, ex, plan, params):
-        """(fn, holder, caps, inputs) for the plan's cached program, forcing
+        """(fn, holder, caps, inputs, signature) for the plan's cached program, forcing
         one warm-up execute to learn capacities/compile if needed; None when
         the plan has no jittable cached program (host aggs, fallback)."""
         caps = ex._learned_caps.get(plan)
@@ -478,8 +493,8 @@ class FastPath:
             cached = ex._jit_cache.get(key)
             if cached is None:
                 return None
-        fn, holder, _sig = cached
-        return fn, holder, caps, inputs
+        fn, holder, sig = cached
+        return fn, holder, caps, inputs, sig
 
     def _dispatch_pipelined(self, ex, entry: _PlanEntry, params_list) -> list:
         compiled = self._compiled(ex, entry.plan, params_list[0])
@@ -487,11 +502,18 @@ class FastPath:
             return [
                 ex.execute(entry.plan, params=p).to_pylist() for p in params_list
             ]
-        fn, holder, caps, inputs = compiled
-        inflight = [fn(inputs, p) for p in params_list]  # no host sync yet
+        fn, holder, caps, inputs, sig = compiled
+        inflight = []
+        for p in params_list:  # no host sync yet
+            with ex._span("dispatch", signature=sig, **holder.get("dispatch", {})):
+                inflight.append(fn(inputs, p))
         out = []
         for (page, packed), p in zip(inflight, params_list):
-            required = dict(zip(holder["keys"], np.asarray(packed).tolist()))
+            with ex._span("device_wait", signature=sig):
+                vals = np.asarray(packed)
+            # nothing to trip on where the program sizes nothing by its
+            # bindings (a fused scan masks rows itself)
+            required = dict(zip(holder["keys"], vals.tolist()))
             if any(
                 isinstance(k, int) and k in caps and int(v) > caps[k]
                 for k, v in required.items()
@@ -516,7 +538,7 @@ class FastPath:
         compiled = self._compiled(ex, entry.plan, params_list[0])
         if compiled is None:
             return False
-        _fn, _holder, caps, inputs = compiled
+        _fn, _holder, caps, inputs, _sig = compiled
         call, _h = _make_call(entry.plan, dict(caps), False)
         stacked = tuple(
             np.stack([np.asarray(p[i]) for p in params_list[:2]])
@@ -542,7 +564,7 @@ class FastPath:
         compiled = self._compiled(ex, entry.plan, params_list[0])
         if compiled is None:
             raise RuntimeError("no compiled program to batch over")
-        _fn, _holder, caps, inputs = compiled
+        _fn, _holder, caps, inputs, _sig = compiled
         b = len(params_list)
         bp = _pow2(b)
         padded = list(params_list) + [params_list[0]] * (bp - b)
