@@ -25,6 +25,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 N_SF1 = 6_001_215  # lineitem rows at SF1
+N_SF10 = 60_000_466  # the generator's lineitem at SF10 (`served_sf10_scan`)
 
 
 @pytest.fixture(scope="module")
@@ -51,15 +52,17 @@ def one_chip():
 
 
 def _compile(fn, *shapes, kernel):
-    """AOT-compile for the described chip; the kernel must be in the
-    program as a Mosaic custom call that carries its name (`name=` on the
-    pallas_call: a profiler trace then says `%hash_agg.1`, not `%call.130`)."""
+    """AOT-compile for the described chip -> (seconds, the compiled program);
+    the kernel must be in the program as a Mosaic custom call that carries
+    its name (`name=` on the pallas_call: a profiler trace then says
+    `%hash_agg.1`, not `%call.130`)."""
     t0 = time.perf_counter()
     compiled = jax.jit(fn).lower(*shapes).compile()
+    seconds = time.perf_counter() - t0
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     assert f"%{kernel}" in text, kernel
-    return time.perf_counter() - t0
+    return seconds, compiled
 
 
 def _col(one_chip, dtype, n=N_SF1):
@@ -70,7 +73,7 @@ def _col(one_chip, dtype, n=N_SF1):
 def test_hash_build_compiles_for_v5e(one_chip, n_words, cap):
     from trino_tpu.ops.pallas import hashagg
 
-    seconds = _compile(
+    seconds, _ = _compile(
         lambda live, *w: hashagg.build_hash_table(list(w), live, cap),
         _col(one_chip, jnp.bool_),
         *[_col(one_chip, jnp.int32)] * n_words,
@@ -197,28 +200,13 @@ def fused_recipes():
     return out
 
 
-@pytest.mark.parametrize("name", ["q01", "q06", "domain-129", "q06-prepared"])
-def test_fused_scan_compiles_for_v5e(one_chip, fused_recipes, name):
-    """q06 (keyless) and q01 (6 groups) take the select-and-add scatter;
-    `domain-129` is q01's recipe with 65 return flags (130 groups: one more
-    lane tile than 128), so the one-hot matmul form, at two lane tiles, is
-    still compiled somewhere; `q06-prepared` is `EXECUTE q06`'s recipe, its
-    bindings scalar operands in SMEM (two int32 dates, three f32 pairs)."""
-    import dataclasses
-
+def _fused_run_shapes(one_chip, recipe, cols, n, live_mask=False):
+    """`fused.run` over `n`-row columns of the dtypes the engine holds them
+    in -> (function, its argument shapes).  `live_mask`: the page has a mask
+    of its own; else it hands over its row count, as a TPC-H scan does."""
     from trino_tpu.ops.expr import ColumnVal
     from trino_tpu.ops.pallas import fused
 
-    recipe, cols = fused_recipes["q01" if name == "domain-129" else name]
-    if name == "domain-129":
-        (c0, _, s0), (c1, d1, s1) = recipe.keys
-        recipe = dataclasses.replace(
-            recipe, keys=((c0, 65, s0), (c1, d1, s1)), domain=65 * d1
-        )
-        assert fused.scatter_form(recipe) == ("mxu", 256)
-    else:
-        assert fused.scatter_form(recipe) == ("vpu", 128)
-    assert len(recipe.params) == (5 if name == "q06-prepared" else 0)
     used = {i for i, _ in recipe.cols}
 
     def run(live, params, *arrays):
@@ -231,14 +219,65 @@ def test_fused_scan_compiles_for_v5e(one_chip, fused_recipes, name):
             data = next(it)
             valid = next(it) if cv.valid is not None else None
             scan.append(ColumnVal(data, valid, cv.dict, cv.type, None))
-        return fused.run(recipe, scan, live, params=params)
+        return fused.run(recipe, scan, live if live_mask else n, params=params)
 
-    shapes = [_col(one_chip, jnp.bool_), tuple(
+    shapes = [_col(one_chip, jnp.bool_, n if live_mask else 1), tuple(
         jax.ShapeDtypeStruct((), jnp.dtype(p.type.np_dtype), sharding=one_chip)
         for p in recipe.params)]
     for i, cv in enumerate(cols):
         if i in used:
-            shapes.append(_col(one_chip, cv.data.dtype))
+            shapes.append(_col(one_chip, cv.data.dtype, n))
             if cv.valid is not None:
-                shapes.append(_col(one_chip, jnp.bool_))
+                shapes.append(_col(one_chip, jnp.bool_, n))
+    return run, shapes
+
+
+@pytest.mark.parametrize("name", ["q01", "q06", "domain-129", "q06-prepared"])
+def test_fused_scan_compiles_for_v5e(one_chip, fused_recipes, name):
+    """q06 (keyless) and q01 (6 groups) take the select-and-add scatter;
+    `domain-129` is q01's recipe with 65 return flags (130 groups: one more
+    lane tile than 128), so the one-hot matmul form, at two lane tiles, is
+    still compiled somewhere; `q06-prepared` is `EXECUTE q06`'s recipe, its
+    bindings scalar operands in SMEM (two int32 dates, three f32 pairs).  The
+    columns are int32 (money narrowed on upload, dates, dictionary codes), as
+    the engine hands them: (n,) operands in (8192,) blocks, the last ragged."""
+    import dataclasses
+
+    from trino_tpu.ops.pallas import fused
+
+    recipe, cols = fused_recipes["q01" if name == "domain-129" else name]
+    assert {str(cols[i].data.dtype) for i, _ in recipe.cols} == {"int32"}
+    if name == "domain-129":
+        (c0, _, s0), (c1, d1, s1) = recipe.keys
+        recipe = dataclasses.replace(
+            recipe, keys=((c0, 65, s0), (c1, d1, s1)), domain=65 * d1
+        )
+        assert fused.scatter_form(recipe) == ("mxu", 256)
+    else:
+        assert fused.scatter_form(recipe) == ("vpu", 128)
+    assert len(recipe.params) == (5 if name == "q06-prepared" else 0)
+    run, shapes = _fused_run_shapes(one_chip, recipe, cols, N_SF1)
     _compile(run, *shapes, kernel="fused_scan")
+
+
+@pytest.mark.parametrize("name,rows,live_mask", [
+    ("q01", N_SF10, False), ("q06", N_SF10, False),
+    ("q01", N_SF10, True), ("q06", 100, True),
+], ids=["q01-sf10", "q06-sf10", "q01-sf10-masked", "q06-100-rows-masked"])
+def test_fused_scan_reads_resident_columns_in_place(
+        one_chip, fused_recipes, name, rows, live_mask):
+    """`served_sf10_scan`'s two programs at its 60,000,466 rows: the kernel's
+    operands are the resident columns themselves, so `fused.run` may hold no
+    temporary the size of a column — the f64 casts, hi/lo planes, pads and
+    stacks it made before were 5.28 GB (q01) and 2.88 GB (q06).  A page with a
+    mask of its own pays for that mask's cast to int32 and nothing else; a
+    page of 512 rows or fewer, which the TPU compiler lays out in smaller
+    tiles than the kernel's blocks are read in, compiles too (it is copied
+    into one sub-chunk's length)."""
+    recipe, cols = fused_recipes[name]
+    run, shapes = _fused_run_shapes(one_chip, recipe, cols, rows, live_mask)
+    _, compiled = _compile(run, *shapes, kernel="fused_scan")
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    # ISSUE 36 allowed a pad a column (4 bytes x rows x referenced columns);
+    # in place it is, the mask's cast aside, under a megabyte
+    assert temp <= 4 * rows * live_mask + (1 << 20), temp

@@ -390,12 +390,15 @@ def test_fused_dispatch_metric_increments(kernel_engine):
 # ------------------------------------------------- the fused scan's scatter
 
 
-def _fused_case(domains, n=17_500, seed=34):
+def _fused_case(domains, n=17_500, seed=34, v_dtype=np.int32, v_nulls=False):
     """Synthetic scan columns + the recipe of
     `select k.., sum(v), sum(v * w), avg(d), count(*) where q < 40 group by k..`
     -> (recipe, scan_cols, live, numpy columns).  Values are small whole
     numbers (and quarters), so every f32 partial sum is exact whatever the
-    scatter's form and a fault shows as a wrong group, not as rounding."""
+    scatter's form and a fault shows as a wrong group, not as rounding.
+    `v_dtype`: what the money column `v` is resident as; `v_nulls`: it has a
+    validity mask (a tenth of its rows NULL; `v` is then zero where NULL in
+    the numpy columns handed back, which is what the sums skip)."""
     from trino_tpu.data.page import Dictionary
     from trino_tpu.data.types import VARCHAR
     from trino_tpu.ops.pallas import fused
@@ -403,13 +406,16 @@ def _fused_case(domains, n=17_500, seed=34):
 
     rng = np.random.default_rng(seed)
     dec, dec4 = DecimalType(12, 2), DecimalType(18, 4)
-    v = rng.integers(-500, 1_600, n).astype(np.int32)
+    v = rng.integers(-500, 1_600, n).astype(v_dtype)
     w = rng.integers(0, 11, n).astype(np.int32)
     d = rng.integers(-4_000, 4_000, n) / 4.0
     q = rng.integers(0, 50, n).astype(np.int32)
     keys = [rng.integers(0, k, n).astype(np.int32) for k in domains]
     live = rng.random(n) < 0.9
-    cols = [_cv(v, typ=dec), _cv(w, typ=dec), _cv(d, typ=DOUBLE), _cv(q, typ=INTEGER)]
+    v_ok = rng.random(n) >= 0.1 if v_nulls else None
+    cols = [_cv(v, v_ok, typ=dec), _cv(w, typ=dec), _cv(d, typ=DOUBLE), _cv(q, typ=INTEGER)]
+    if v_nulls:
+        v = np.where(v_ok, v, 0)
     cols += [
         _cv(k, dict_=Dictionary(np.array([f"k{i}" for i in range(dom)], object)), typ=VARCHAR)
         for k, dom in zip(keys, domains)
@@ -425,6 +431,29 @@ def _fused_case(domains, n=17_500, seed=34):
     )
     assert recipe is not None, why
     return recipe, cols, jnp.asarray(live), (v, w, d, q, keys, live)
+
+
+def _fused_sums(recipe, cols, live):
+    """`_fused_case`'s four aggregates off the kernel, interpreted:
+    -> (sum(v), sum(v * w), avg(d), count(*)) as numpy arrays over the groups."""
+    from trino_tpu.ops.pallas import fused
+
+    totals = fused.run(recipe, cols, live, interpret=True)
+    _, aggs, _, _ = fused.assemble(recipe, totals)
+    (sv, _, _, _), (svw, _, _, _), (avg, _), (cnt, _) = aggs
+    return np.asarray(sv), np.asarray(svw), np.asarray(avg), np.asarray(cnt)
+
+
+def _numpy_sums(domains, columns, keep):
+    v, w, d, q, keys, _ = columns
+    domain = int(np.prod(domains)) if domains else 1
+    keep = keep & (q < 40)
+    code = np.zeros(len(v), np.int64)
+    for k, dom in zip(keys, domains):
+        code = code * dom + k
+    by = lambda x: np.bincount(  # noqa: E731
+        code[keep], weights=np.asarray(x, np.float64)[keep], minlength=domain)
+    return by(v), by(v.astype(np.int64) * w), by(d), by(np.ones(len(v)))
 
 
 @pytest.mark.parametrize(
@@ -455,14 +484,8 @@ def test_fused_scatter_by_domain(monkeypatch, domains, tile):
     (shape,) = shapes
     assert shape[-1] == tile == fused.scatter_form(recipe)[1]
 
-    keep = live_np & (q < 40)
-    code = np.zeros(len(v), np.int64)
-    for k, dom in zip(keys, domains):
-        code = code * dom + k
-    want = {
-        name: np.bincount(code[keep], weights=x[keep].astype(np.float64), minlength=domain)
-        for name, x in (("n", np.ones_like(v)), ("v", v), ("vw", v * w), ("d", d))
-    }
+    want = dict(zip(("v", "vw", "d", "n"), _numpy_sums(
+        domains, (v, w, d, q, keys, live_np), live_np)))
     (sv, _, _, _), (svw, _, _, _), (avg, avg_ok), (cnt, _) = aggs
     np.testing.assert_array_equal(np.asarray(cnt), want["n"].astype(np.int64))
     if domains:
@@ -479,6 +502,117 @@ def test_fused_scatter_by_domain(monkeypatch, domains, tile):
     np.testing.assert_allclose(
         np.asarray(avg)[some], want["d"][some] / want["n"][some], rtol=1e-9, atol=1e-12
     )
+
+
+# ------------------------------------------- the fused scan's operands in place
+
+
+@pytest.mark.parametrize("masked", [False, True], ids=["count", "mask"])
+@pytest.mark.parametrize("n", [1, 8_191, 8_192, 8_193, 17_500, 3 * 8_192 + 127])
+def test_fused_scan_reads_columns_in_place(n, masked):
+    """Each column goes to the kernel as its own (n,) operand at the dtype it
+    is resident in, nothing padded: a page shorter than a sub-chunk, one row
+    short of a grid step, a step exactly, one row over, a ragged last block;
+    a page with a live mask of its own and one that hands over its row count
+    (every row live, rows past the end dead by their index alone)."""
+    from trino_tpu.ops.pallas import fused
+
+    domains = (3, 2)
+    recipe, cols, live, columns = _fused_case(domains, n=n, seed=n)
+    # int32 columns as they are; the float64 one split outside, no cast
+    assert sorted(recipe.operands) == [
+        ("data", 0), ("data", 1), ("data", 3), ("data", 4), ("data", 5),
+        ("hi", 2), ("lo", 2)]
+    assert fused.operand_counts(recipe, masked) == (7 + masked, 5)
+    live_np = columns[-1] if masked else np.ones(n, bool)
+    sv, svw, avg, cnt = _fused_sums(recipe, cols, live if masked else n)
+    want_v, want_vw, want_d, want_n = _numpy_sums(domains, columns, live_np)
+    np.testing.assert_array_equal(cnt, want_n.astype(np.int64))
+    np.testing.assert_array_equal(sv, want_v.astype(np.int64))
+    np.testing.assert_array_equal(svw, want_vw.astype(np.int64))
+    some = want_n > 0
+    np.testing.assert_allclose(avg[some], want_d[some] / want_n[some], rtol=1e-12)
+
+
+@pytest.mark.parametrize("resident", ["int32-nullable", "int64", "float64"])
+def test_fused_scan_operand_follows_resident_dtype(resident):
+    """What a money column's operand is follows the dtype it is resident in
+    and nothing else: int32 (narrowed on upload) is read as it is and lifted
+    in the kernel, its validity mask a cast of its own; int64 (not narrowed)
+    and float64 keep the hi/lo f32 split outside the kernel, two operands."""
+    from trino_tpu.ops.pallas import fused
+
+    n, domains = 17_500, (2,)
+    if resident == "float64":  # a DOUBLE column in v's place: d is one
+        recipe, cols, live, columns = _fused_case(domains, n=n)
+        assert cols[2].data.dtype == jnp.float64
+        _, hi, lo, _, _ = dict(recipe.cols)[2]
+        assert (recipe.operands[hi], recipe.operands[lo]) == (("hi", 2), ("lo", 2))
+    else:
+        recipe, cols, live, columns = _fused_case(
+            domains, n=n, v_dtype=np.int64 if resident == "int64" else np.int32,
+            v_nulls=resident == "int32-nullable")
+        forms = [what for what, ci in recipe.operands if ci == 0]
+        assert forms == (["hi", "lo"] if resident == "int64" else ["valid", "data"])
+        assert str(cols[0].data.dtype) == resident.split("-")[0]
+    n_ops, n_resident = fused.operand_counts(recipe, True)
+    assert n_ops == len(recipe.operands) + 1
+    assert n_resident == sum(w == "data" for w, _ in recipe.operands) < n_ops
+    sv, svw, avg, cnt = _fused_sums(recipe, cols, live)
+    want_v, want_vw, want_d, want_n = _numpy_sums(domains, columns, columns[-1])
+    np.testing.assert_array_equal(cnt, want_n.astype(np.int64))
+    np.testing.assert_array_equal(sv, want_v.astype(np.int64))
+    np.testing.assert_array_equal(svw, want_vw.astype(np.int64))
+    np.testing.assert_allclose(avg, want_d / want_n, rtol=1e-12)
+
+
+_LIFT_EDGES = [0, 1, -1, 2**24 + 1, -(2**24 + 1), 2**31 - 64, -(2**31 - 64),
+               2**31 - 1, -(2**31 - 1), -(2**31)]
+
+
+@pytest.mark.parametrize("x", _LIFT_EDGES)
+def test_fused_lift_of_an_int32_is_exact(x):
+    """The kernel's lift of an int32 to a double-float pair: hi is f32(x)
+    rounded to nearest, lo what that lost, for every int32 — also where
+    f32(x) is 2^31, which no int32 holds (|x| > 2^31 - 64)."""
+    from trino_tpu.ops.pallas.fused import _dd_planes, _lift_i32
+
+    hi, lo = (np.asarray(a) for a in _lift_i32(jnp.full((8, 128), x, jnp.int32)))
+    assert hi.dtype == lo.dtype == np.float32
+    assert (hi == np.float32(x)).all()
+    assert (hi.astype(np.float64) + lo.astype(np.float64) == x).all()
+    # the pair the planes made outside the kernel held, to the bit
+    phi, plo = _dd_planes(jnp.asarray([x], jnp.int32))
+    assert hi[0, 0] == np.asarray(phi)[0] and lo[0, 0] == np.asarray(plo)[0]
+
+
+def test_fused_scan_sums_the_int32_edges_exactly():
+    """The same edges through the kernel: a decimal(10,0) column resident as
+    int32 holding them, each its own group, so a group's sum is its value."""
+    from trino_tpu.data.page import Dictionary
+    from trino_tpu.data.types import VARCHAR
+    from trino_tpu.ops.pallas import fused
+    from trino_tpu.plan.ir import FieldRef
+
+    vals = np.array(_LIFT_EDGES, np.int64)
+    k = len(vals)
+    cols = [
+        _cv(vals.astype(np.int32), typ=DecimalType(10, 0)),
+        _cv(np.arange(k, dtype=np.int32),
+            dict_=Dictionary(np.array([f"k{i:02d}" for i in range(k)], object)), typ=VARCHAR),
+    ]
+    f = [FieldRef(i, c.type) for i, c in enumerate(cols)]
+    recipe, why = fused.plan_pipeline(
+        cols, [], [f[1]], ["sum", "count_star"], [f[0], None],
+        [DecimalType(38, 0), BIGINT])
+    assert recipe is not None, why
+    assert recipe.operands == (("data", 1), ("data", 0))
+    totals = fused.run(recipe, cols, k, interpret=True)
+    _, ((total, ok, _, hi), (cnt, _)), _, n_groups = fused.assemble(recipe, totals)
+    assert int(n_groups) == k and np.asarray(ok).all()
+    np.testing.assert_array_equal(np.asarray(cnt), np.ones(k, np.int64))
+    np.testing.assert_array_equal(np.asarray(total), vals)
+    np.testing.assert_array_equal(np.asarray(hi), np.where(vals < 0, -1, 0))
 
 
 # ------------------------------------------------- the fused scan's running sums
@@ -605,8 +739,10 @@ def test_prepared_bindings_ride_the_fused_scan_as_scalars(name, tpch_tiny):
         f"EXPLAIN ANALYZE EXECUTE {name} USING " + ", ".join(bindings[0].literals))]
     assert SERVICE.builds == builds + 1
     detail = [l for l in ex if l.startswith("-- kernel: pallas fused_pipeline")]
+    columns = {"q06": 4, "q01": 7}[name]  # every one resident as int32
     assert len(detail) == 1 and detail[0].endswith(
-        f"scatter vpu tile 128 params {len(t['sites'])})"), ex
+        f"scatter vpu tile 128 operands {columns} resident {columns} "
+        f"params {len(t['sites'])})"), ex
     assert any("plan_cache=hit" in l and f"bound={len(t['sites'])} baked=0" in l
                for l in ex), ex
     for b, rows in zip(bindings, got):
@@ -617,6 +753,55 @@ def test_prepared_bindings_ride_the_fused_scan_as_scalars(name, tpch_tiny):
         for lit in b.literals:
             sql = sql.replace("?", lit, 1)
         assert eng.execute(sql) == rows, b.params
+
+
+@pytest.mark.parametrize("name", ["q06", "q01", "execute-q06"])
+def test_fused_dispatch_detail_counts_operands_read_in_place(name):
+    """`EXPLAIN ANALYZE`'s kernel line says how many row operands the fused
+    scan takes and how many are resident arrays read in place — every one for
+    a TPC-H statement: the lineitem columns are all resident as int32 and the
+    page has no mask — and still ends in `params <n>`, where the benchmark's
+    `fused_param_dispatch_share` looks for it; `/metrics` counts the same
+    once per traced program, not per execution."""
+    import re
+
+    from tests.tpch_queries import QUERIES
+    from trino_tpu.connectors.tpch import TpchConnector
+    from trino_tpu.exec.compilesvc import CompileService
+    from trino_tpu.runtime.engine import Engine
+
+    eng = Engine()
+    eng.register_catalog("tpch", TpchConnector(0.01))
+    eng.session.set("pallas_interpret", "true")
+    # its own service, so the program is traced here whatever this process
+    # holds already: the counters move at trace time
+    eng.executor.compile_service = eng._local_fallback.compile_service = CompileService()
+    if name == "execute-q06":
+        (loader,) = _bench("loader")
+        t = loader.load_json("templates", "q06.json")
+        eng.execute("PREPARE q06 FROM " + loader.sql_text(t, "prepared_text"))
+        sql = "EXECUTE q06 USING DATE '1994-01-01', DATE '1995-01-01', 0.05, 0.07, 24"
+        columns, params = 4, 5
+    else:
+        sql, columns, params = QUERIES[name], {"q06": 4, "q01": 7}[name], 0
+    before = {f: kernels.FUSED_OPERANDS.value(f) for f in ("resident", "prepared")}
+    eng.execute(sql)
+    traced = {f: kernels.FUSED_OPERANDS.value(f) for f in ("resident", "prepared")}
+    assert traced == {"resident": before["resident"] + columns,
+                      "prepared": before["prepared"]}
+    eng.execute(sql)  # the program again, not a trace
+    assert {f: kernels.FUSED_OPERANDS.value(f) for f in traced} == traced
+    ex = [r[0] for r in eng.execute("EXPLAIN ANALYZE " + sql)]
+    (detail,) = [l for l in ex if l.startswith("-- kernel: pallas fused_pipeline")]
+    assert detail.endswith(
+        f"operands {columns} resident {columns} params {params})"), detail
+    # layer_metrics/fused_param_dispatch_share.py's pattern
+    got = re.search(r"fused_pipeline \([^)]*\bparams (\d+)", detail)
+    assert got and int(got.group(1)) == params
+    from trino_tpu.utils import metrics
+
+    text = metrics.GLOBAL.render()
+    assert 'trino_tpu_fused_operands_total{form="resident"}' in text
 
 
 @pytest.mark.parametrize("case", ["varchar", "null", "wide_decimal", "wide_compared"])

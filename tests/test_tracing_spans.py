@@ -245,7 +245,7 @@ def test_prepared_request_leaves_the_spans_of_a_text_request():
         kernels = q.find("dispatch").attributes["kernels"]
         assert re.fullmatch(
             r"pallas fused_pipeline \(5 filters 3 streams domain 1 scatter vpu "
-            r"tile 128 params 5\)", kernels), kernels
+            r"tile 128 operands 4 resident 4 params 5\)", kernels), kernels
         assert q.find("to_rows").attributes["rows"] == 1
     assert (first.find("dispatch").attributes["signature"]
             == second.find("dispatch").attributes["signature"])

@@ -1310,18 +1310,26 @@ def _trace_plan(
         counter[0] = scan_nid + 1  # consume the whole chain's id range
         live = page_live(page)
         form, tile = _fused.scatter_form(recipe)
+        masked = page.live is not None
+        operands, resident = _fused.operand_counts(recipe, masked)
         _kernels.record_dispatch(
             "fused_pipeline", "pallas",
             f"{len(filters)} filters {len(recipe.streams)} streams "
             f"domain {recipe.domain} scatter {form} tile {tile} "
+            f"operands {operands} resident {resident} "
             f"params {len(recipe.params)}",
         )
         _kernels.FUSED_SCATTER.labels(form=form).inc()
+        _kernels.FUSED_OPERANDS.labels(form="resident").inc(resident)
+        _kernels.FUSED_OPERANDS.labels(form="prepared").inc(operands - resident)
         # a prepared statement's bindings: scalars of the program (tracers
         # under jit, from the parameter context), operands of the kernel
         bound = [eval_expr(prm, (), 1).data[0] for prm in recipe.params]
+        # a page with no mask of its own hands the kernel its row count, not
+        # the ones page_live made up
         totals = _fused.run(
-            recipe, scan_cols, live, params=bound, interpret=policy.interpret
+            recipe, scan_cols, live if masked else page.capacity,
+            params=bound, interpret=policy.interpret,
         )
         key_codes, agg_cols, out_live, n_groups = _fused.assemble(recipe, totals)
         report(nid, n_groups)

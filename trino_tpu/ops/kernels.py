@@ -80,6 +80,16 @@ FUSED_SCATTER = _metrics.GLOBAL.counter(
     ("form",),
 )
 
+FUSED_OPERANDS = _metrics.GLOBAL.counter(
+    "trino_tpu_fused_operands_total",
+    "Row operands of the fused scan programs traced (columns, validity "
+    "masks, a page's live mask), by how each reaches the kernel "
+    "(ops/pallas/fused.py: resident = the resident array as it is, read in "
+    "place; prepared = a cast or a 64-bit column's hi/lo split made before "
+    "the kernel, once a query)",
+    ("form",),
+)
+
 
 def get_policy() -> KernelPolicy:
     return _POLICY

@@ -9,8 +9,30 @@ once and does everything else in VMEM:
 
   * the compiler (exec/compiler.py) substitutes the filter predicates and
     aggregate arguments down to scan level (plan/ir.substitute), so the
-    kernel receives raw column planes plus a closed IR tree of FieldRef,
+    kernel receives the scan's columns plus a closed IR tree of FieldRef,
     Const, Param and Call;
+  * layout: every column the recipe references is an operand of its own,
+    the (n,) array as it lies in HBM, read in blocks of 8,192 rows (a grid
+    step) and viewed a sub-chunk at a time as an (8, 128) tile — no pad, no
+    stack, no plane written before the kernel.  What an operand is follows
+    the dtype the column is RESIDENT in and nothing else (`_Planner`,
+    `recipe.operands`): an int32 column — a date, an integer, a dictionary's
+    codes, and every DECIMAL that data/page.py narrowed on upload, which is
+    every TPC-H money column — is the resident array itself, and the kernel
+    lifts a decimal to its double-float pair in registers (`_lift_i32`,
+    exact for every int32); an int64 or floating column has no 64-bit lane
+    to ride in, so its hi/lo f32 pair is split outside the kernel
+    (`_dd_planes`) and goes in as two operands; a boolean, a validity mask,
+    the page's live mask and a narrower integer go in as a cast to int32.
+    A page without a live mask of its own hands over its row count and the
+    kernel tells live rows by their index; the last block hangs over the
+    arrays' end, and rows past n are dead by index too.  `operand_counts`
+    (the dispatch detail's `operands <k> resident <r>`,
+    `trino_tpu_fused_operands_total`) says how many operands a program's
+    kernel takes and how many of them it reads in place.  One exception, the
+    TPU compiler's: it lays a 1-D array of up to 512 elements out in smaller
+    tiles than the kernel's blocks are read in, so a page that short is
+    copied into one sub-chunk's length first;
   * a Param (a bound parameter of a prepared statement, plan/ir.py) is
     checked like a constant of its type and scale and reaches the kernel
     as a scalar operand in SMEM — an int32, or the hi/lo f32 pair made
@@ -19,7 +41,7 @@ once and does everything else in VMEM:
     compiled kernel and a statement without parameters has the operands
     it always had.  No value is in the recipe, which is the kernel's cache
     key;
-  * numeric lanes travel as double-float pairs (hi = f32(v),
+  * numeric values are computed as double-float pairs (hi = f32(v),
     lo = f32(v - f64(hi))): exact for |v| < 2^47, which covers the scaled
     decimals of the TPC-H fact columns; arithmetic uses the classic
     error-free transforms (Knuth two-sum, Dekker two-product with a 4097
@@ -41,15 +63,19 @@ once and does everything else in VMEM:
         (8, 128, lanes) at HIGHEST contracted over lanes and summed over
         sublanes into an (NR, lanes) accumulator, one compensated step a
         sub-chunk.
-    On a v5e at 60,000,466 rows (PERF.md, PR 34) a 1,024-row sub-chunk of
-    q01 takes 102 ns in the first form (6.0 ms a scan: 59% of HBM for its
-    twelve planes) where the 512-lane one-hot of PRs 22-33 took 1,248 ns
-    and the one-hot at 128 lanes takes ~600 ns (35 ms a scan, whether 3 or
-    15 streams ride on it: building the one-hot and loading it into the MXU
-    is the cost).  The first form grows by ~0.7 ns a pair: 240 pairs 12.9
-    ms a scan, 448 pairs 22.4 ms — the widest measured, still a third
-    under the narrowest one-hot — so the rule keeps it to 448 pairs.  q06
-    takes 53 ns (3.1 ms, 75% of HBM).
+    On a v5e at 60,000,466 rows (PERF.md, PR 34, over twelve stacked f32
+    planes) a 1,024-row sub-chunk of q01 took 102 ns in the first form (6.0
+    ms a scan) where the 512-lane one-hot of PRs 22-33 took 1,248 ns and the
+    one-hot at 128 lanes takes ~600 ns (35 ms a scan, whether 3 or 15
+    streams ride on it: building the one-hot and loading it into the MXU is
+    the cost).  The first form grows by ~0.7 ns a pair: 240 pairs 12.9 ms a
+    scan, 448 pairs 22.4 ms — the widest measured, still a third under the
+    narrowest one-hot — so the rule keeps it to 448 pairs.  Over the seven
+    resident int32 columns themselves (PERF.md, PR 36) q01 takes 6.8 ms a
+    scan — 1.68 GB at 248 GB/s, bound by its schedule (the spills of its 90
+    tiles, the lift, the ragged block's guards), not by its bytes — and q06
+    2.5 ms (0.96 GB at 390 GB/s: four 32 KB blocks in flight a step); what
+    went is everything that ran before the kernel, 26 ms a pair of scans.
 
 Every aggregate lowers to a handful of f32 *streams* (per-row values summed
 per group): count -> the row mask; sum -> the hi and lo parts (summed as
@@ -63,6 +89,9 @@ partial rounds (compensated across partials), and that averages out as
 2.2e-11 relative in the first form (8-row partials; 5.3e-10 with the
 1,024-row partials of the 512-lane one-hot) and q01's AVGs 5.0e-9 in
 either (4.7e-9): their last digits are lost outside the partial sums.
+The pair the kernel lifts from a resident int32 is the pair the planes held
+(hi = f32(x) to nearest, lo the rest), so reading the columns in place left
+every answer as it was, to the last digit (2.220e-11 and 5.022e-9, PR 36).
 Exactness-critical cases (BIGINT sum's mod-2^64 semantics) are rejected at
 plan time and take the sort path.
 
@@ -85,7 +114,7 @@ from .hashagg import (
     _CHUNK_S,
     _STEP_CHUNKS,
     _STEP_ROWS,
-    _prep,
+    _SUB_ROWS,
 )
 from . import hashagg as _hashagg
 
@@ -98,6 +127,11 @@ _MAX_DOMAIN = 512
 # unrolled, ~10,000 vector ops a grid step there
 _VPU_PAIRS = 448
 _MAX_STREAMS = 64
+# the TPU compiler lays a 1-D array of up to 512 elements out in tiles of 128,
+# 256 or 512, a longer one in tiles of 1,024 — the only ones Mosaic takes an
+# (8192,) block over ("XLA layout ({0:T(128)}) does not match Mosaic layout
+# ({0:T(1024)})").  A page that short is copied into one sub-chunk's length
+_XLA_SMALL_ROWS = 512
 # double-float pairs are exact only while the integer payload fits hi+lo
 _DD_EXACT_BITS = 47
 
@@ -120,11 +154,16 @@ class _Unsupported(Exception):
 @dataclass(frozen=True)
 class _Recipe:
     n_cols: int
-    # col_idx -> ("i32", plane, valid_plane|-1) | ("dd", hi, lo, valid|-1)
-    #          | ("dict", plane)
+    # col_idx -> ("i32" | "bool", operand, valid operand|-1, scale)
+    #          | ("dd", hi operand, lo operand, valid operand|-1, scale): lo is
+    #            -1 where the column is ONE int32 operand, lifted in the kernel
+    #          | ("dict", operand)
     cols: tuple
-    n_i32: int
-    n_f32: int
+    # the kernel's column operands, in its order: (what, col_idx), what one of
+    # "data" (the resident array as it is: 32 bits wide already), "cast" (its
+    # data as int32), "hi" / "lo" (the f32 pair of a 64-bit dd column, split
+    # outside the kernel), "valid" (its validity mask as int32)
+    operands: tuple
     filters: tuple  # IrExpr, scan-level
     keys: tuple     # (col_idx, domain, stride)
     domain: int
@@ -217,11 +256,21 @@ class _Planner:
     def __init__(self, cols):
         self.scan_cols = cols
         self.col_plan: dict[int, tuple] = {}
-        self.n_i32 = 1  # plane 0 is the live mask
-        self.n_f32 = 0
+        self.operands: list = []
         self.streams: list = []
         self.stream_ix: dict = {}
         self.params: list = []
+
+    def operand(self, what: str, i: int) -> int:
+        self.operands.append((what, i))
+        return len(self.operands) - 1
+
+    def data_operand(self, i: int) -> int:
+        """The column's data as one int32 operand: the resident array itself
+        where that is what it holds, a cast of it (a narrower dictionary, a
+        boolean, a smallint) where not."""
+        resident = self.scan_cols[i].data.dtype == jnp.int32
+        return self.operand("data" if resident else "cast", i)
 
     def use_col(self, i: int) -> tuple:
         got = self.col_plan.get(i)
@@ -233,19 +282,16 @@ class _Planner:
         if cv.dict is not None:
             raise _Unsupported("dictionary column in expression")
         kind, scale = _kind_of_type(cv.type)
-        vplane = -1
-        if cv.valid is not None:
-            vplane = self.n_i32
-            self.n_i32 += 1
-        if kind == "dd":
-            plan = ("dd", self.n_f32, self.n_f32 + 1, vplane, scale)
-            self.n_f32 += 2
-        elif kind == "i32":
-            plan = ("i32", self.n_i32, vplane, scale)
-            self.n_i32 += 1
-        else:  # bool rides as an i32 plane
-            plan = ("bool", self.n_i32, vplane, scale)
-            self.n_i32 += 1
+        vop = -1 if cv.valid is None else self.operand("valid", i)
+        if kind != "dd":  # an integer or a date; a bool rides as int32 too
+            plan = (kind, self.data_operand(i), vop, scale)
+        elif cv.data.dtype == jnp.int32:
+            # a narrowed decimal (data/page.py): the kernel lifts it to its
+            # double-float pair in registers (_Eval._dd)
+            plan = ("dd", self.data_operand(i), -1, vop, scale)
+        else:  # int64 or a float: no 64-bit lanes in the kernel, so the pair
+            # is split outside it (_dd_planes)
+            plan = ("dd", self.operand("hi", i), self.operand("lo", i), vop, scale)
         self.col_plan[i] = plan
         return plan
 
@@ -258,8 +304,7 @@ class _Planner:
             if got[0] != "dict":
                 raise _Unsupported("key column also used as a value")
             return got[1]
-        plan = ("dict", self.n_i32)
-        self.n_i32 += 1
+        plan = ("dict", self.data_operand(i))
         self.col_plan[i] = plan
         return plan[1]
 
@@ -437,8 +482,7 @@ def plan_pipeline(scan_cols, filters, key_exprs, agg_fns, agg_args, agg_types):
     recipe = _Recipe(
         n_cols=len(scan_cols),
         cols=tuple(sorted((i, plan) for i, plan in p.col_plan.items())),
-        n_i32=p.n_i32,
-        n_f32=p.n_f32,
+        operands=tuple(p.operands),
         filters=tuple(filters),
         keys=tuple((i, d, s) for (i, d), s in zip(keys, strides)),
         domain=domain,
@@ -498,6 +542,19 @@ def _dd_eq(x, y):
     return (x[0] == y[0]) & (x[1] == y[1])
 
 
+def _lift_i32(x):
+    """An int32 tile as its double-float pair (hi = f32(x) rounded to nearest,
+    lo = x - hi), exact for EVERY int32 and the pair _dd_planes makes of the
+    same integer.  The top 24 bits and the low 8 are each exact in f32 and
+    add up to x; |top| >= 256 > low unless top is 0, so Dekker's fast two-sum
+    takes their rounded sum and what the rounding lost.  No int32 is made of
+    hi, which is 2^31 for x > 2^31 - 64."""
+    top = (x & jnp.int32(-256)).astype(jnp.float32)
+    low = (x & jnp.int32(255)).astype(jnp.float32)
+    hi = top + low
+    return hi, low - (hi - top)
+
+
 def _dd_const(v: float):
     import numpy as np
 
@@ -510,17 +567,18 @@ class _Eval:
     """Evaluates the closed IR over one (8, 128) sub-chunk.  Values are
     (kind, payload..., valid) with valid None when statically non-null."""
 
-    def __init__(self, recipe, i32, f32, shape, scalars=()):
+    def __init__(self, recipe, planes, shape, scalars=()):
         self.col_plan = dict(recipe.cols)
-        self.i32 = i32  # list of (8, 128) int32 planes
-        self.f32 = f32  # list of (8, 128) f32 planes
+        # an (8, 128) tile per operand of recipe.operands: int32, but f32
+        # for the "hi" / "lo" pair of a 64-bit dd column
+        self.planes = planes
         self.shape = shape
         # Param -> its scalar(s) read from SMEM: an int32, or (hi, lo) f32
         self.scalars = dict(zip(recipe.params, scalars))
         self.memo: dict = {}
 
     def _valid(self, vplane):
-        return None if vplane < 0 else (self.i32[vplane] > 0)
+        return None if vplane < 0 else (self.planes[vplane] > 0)
 
     def ev(self, e: IrExpr):
         got = self.memo.get(e)
@@ -534,12 +592,14 @@ class _Eval:
             plan = self.col_plan[e.index]
             if plan[0] == "dd":
                 _, hi, lo, vp, _ = plan
-                return ("dd", (self.f32[hi], self.f32[lo]), self._valid(vp))
+                if lo < 0:  # resident as int32: lifted here, in registers
+                    return ("dd", _lift_i32(self.planes[hi]), self._valid(vp))
+                return ("dd", (self.planes[hi], self.planes[lo]), self._valid(vp))
             if plan[0] == "i32":
                 _, p, vp, _ = plan
-                return ("i32", self.i32[p], self._valid(vp))
+                return ("i32", self.planes[p], self._valid(vp))
             _, p, vp, _ = plan
-            return ("bool", self.i32[p] > 0, self._valid(vp))
+            return ("bool", self.planes[p] > 0, self._valid(vp))
         if isinstance(e, Const):
             kind, scale = _kind_of_type(e.type)
             if e.value is None:
@@ -573,10 +633,7 @@ class _Eval:
         """Lift a value to dd."""
         if v[0] == "dd":
             return v[1], v[2]
-        x = v[1].astype(jnp.float32)
-        hi = x  # |i32| < 2^31: hi rounds, lo recovers the residual exactly
-        lo = (v[1] - hi.astype(jnp.int32)).astype(jnp.float32)
-        return (hi, lo), v[2]
+        return _lift_i32(v[1]), v[2]
 
     def _call(self, e: Call):
         op = e.op
@@ -722,8 +779,26 @@ def scatter_form(recipe: _Recipe) -> tuple[str, int]:
     return "mxu", _CHUNK_L * -(-recipe.domain // _CHUNK_L)
 
 
+def _acc_shape(recipe: _Recipe) -> tuple:
+    form, dtile = scatter_form(recipe)
+    nr = len(recipe.streams)
+    if form == "vpu":
+        return (recipe.domain * nr, _CHUNK_S, _CHUNK_L)
+    return (nr, dtile)
+
+
+def operand_counts(recipe: _Recipe, has_live: bool) -> tuple[int, int]:
+    """-> (row operands the kernel takes — columns, validity masks and the
+    page's live mask where it has one —, how many of them are resident
+    arrays handed over as they are: no cast, no split, no copy)."""
+    resident = sum(what == "data" for what, _ in recipe.operands)
+    return len(recipe.operands) + bool(has_live), resident
+
+
 @functools.lru_cache(maxsize=64)
-def _fused_kernel(recipe: _Recipe, n_chunks: int, interpret: bool):
+def _fused_kernel(recipe: _Recipe, n: int, has_live: bool, interpret: bool):
+    """The scan of `n` rows: an (n,) operand per entry of recipe.operands,
+    then the live mask if the page has one, then the parameters' scalars."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -731,14 +806,14 @@ def _fused_kernel(recipe: _Recipe, n_chunks: int, interpret: bool):
     domain = recipe.domain
     key_planes = {i: dict(recipe.cols)[i][1] for i, _, _ in recipe.keys}
     form, dtile = scatter_form(recipe)
-    acc_shape = (
-        (domain * nr, _CHUNK_S, _CHUNK_L) if form == "vpu" else (nr, dtile)
-    )
+    acc_shape = _acc_shape(recipe)
+    n_rows = len(recipe.operands) + has_live
+    tile = (_CHUNK_S, _CHUNK_L)
 
     # an operand is there only if some parameter needs it, so a statement
-    # without parameters has the two plane operands alone
+    # without parameters has its row operands alone
     slots, n_pi, n_pf = _scalar_slots(recipe.params)
-    scalar_shapes = [(1, n) for n in (n_pi, n_pf) if n]
+    scalar_shapes = [(1, k) for k in (n_pi, n_pf) if k]
 
     def read_scalars(refs):
         refs = list(refs)
@@ -749,29 +824,40 @@ def _fused_kernel(recipe: _Recipe, n_chunks: int, interpret: bool):
             for where, k in slots
         ]
 
-    def sub_chunk(i32_ref, f32_ref, c, scalars):
+    def sub_chunk(row_refs, c, scalars):
         """-> (key code | None, masked streams) of a step's c-th sub-chunk."""
-        rows = slice(c * _CHUNK_S, (c + 1) * _CHUNK_S)
-        i32 = [i32_ref[p, rows, :] for p in range(recipe.n_i32)]
-        f32 = [f32_ref[p, rows, :] for p in range(max(recipe.n_f32, 1))]
-        ev = _Eval(recipe, i32, f32, (_CHUNK_S, _CHUNK_L), scalars)
-        mask = i32[0] > 0
+        at = pl.ds(c * _SUB_ROWS, _SUB_ROWS)
+        planes = [r[at].reshape(tile) for r in row_refs]
+        mask = planes.pop() > 0 if has_live else None
+        if n % _STEP_ROWS:
+            # the last step's blocks hang over the end of the arrays, and
+            # what they hold past row n is not data: live by index alone
+            row = (
+                jax.lax.broadcasted_iota(jnp.int32, tile, 0) * jnp.int32(_CHUNK_L)
+                + jax.lax.broadcasted_iota(jnp.int32, tile, 1)
+                + jnp.int32(c * _SUB_ROWS)
+            )
+            inside = row < jnp.int32(n) - pl.program_id(0) * jnp.int32(_STEP_ROWS)
+            mask = inside if mask is None else mask & inside
+        if mask is None:
+            mask = jnp.full(tile, True)
+        ev = _Eval(recipe, planes, tile, scalars)
         for f in recipe.filters:
             mask = mask & ev.pred(f)
         code = None
         for ci, _, stride in recipe.keys:
-            term = i32[key_planes[ci]] * jnp.int32(stride)
+            term = planes[key_planes[ci]] * jnp.int32(stride)
             code = term if code is None else code + term
         return code, [ev.masked_stream(tag, e, mask) for tag, e in recipe.streams]
 
-    def vpu_step(i32_ref, f32_ref, scalars, acc, err):
+    def vpu_step(row_refs, scalars, acc, err):
         # tile g * nr + s sums stream s over the rows of group g, lane by
         # lane: a step adds 8 rows to each of a tile's 1,024 lanes, and the
         # lanes are summed in f64 outside the kernel (_totals).  One group
         # (no keys, or a dictionary of one value) needs no compare.
         part = [None] * (domain * nr)
         for c in range(_STEP_CHUNKS):
-            code, streams = sub_chunk(i32_ref, f32_ref, c, scalars)
+            code, streams = sub_chunk(row_refs, c, scalars)
             for g in range(domain):
                 hit = None if domain == 1 else code == jnp.int32(g)
                 for s, x in enumerate(streams):
@@ -781,12 +867,12 @@ def _fused_kernel(recipe: _Recipe, n_chunks: int, interpret: bool):
         for k, p in enumerate(part):
             acc[k], err[k] = _accumulate(acc[k], err[k], p)
 
-    def mxu_step(i32_ref, f32_ref, scalars, acc, err):
+    def mxu_step(row_refs, scalars, acc, err):
         lane = jax.lax.broadcasted_iota(
             jnp.int32, (_CHUNK_S, _CHUNK_L, dtile), 2
         )
         for c in range(_STEP_CHUNKS):
-            code, streams = sub_chunk(i32_ref, f32_ref, c, scalars)
+            code, streams = sub_chunk(row_refs, c, scalars)
             upd = jnp.stack(streams, axis=1)  # (8, NR, 128)
             oh = (code[:, :, None] == lane).astype(jnp.float32)
             part = jax.lax.dot_general(
@@ -797,38 +883,29 @@ def _fused_kernel(recipe: _Recipe, n_chunks: int, interpret: bool):
             ).sum(axis=0)  # (NR, dtile)
             acc[...], err[...] = _accumulate(acc[...], err[...], part)
 
-    def kernel(i32_ref, f32_ref, *rest):
+    def kernel(*refs):
         # every grid step maps to the one output block, so it stays in VMEM
         # for the whole table and is the accumulator: out[0] the running
         # sums, out[1] what their roundings lost
-        *scalar_refs, out_ref = rest
+        row_refs, scalar_refs, out_ref = refs[:n_rows], refs[n_rows:-1], refs[-1]
 
         @pl.when(pl.program_id(0) == 0)
         def _init():
             out_ref[...] = jnp.zeros((2,) + acc_shape, jnp.float32)
 
         step = vpu_step if form == "vpu" else mxu_step
-        step(i32_ref, f32_ref, read_scalars(scalar_refs),
-             out_ref.at[0], out_ref.at[1])
+        step(row_refs, read_scalars(scalar_refs), out_ref.at[0], out_ref.at[1])
 
     vmem = pltpu.VMEM
-    step_s = _STEP_ROWS // _CHUNK_L
     origin = (0,) * (1 + len(acc_shape))
     return pl.pallas_call(
         kernel,
-        grid=(n_chunks,),
+        grid=(-(-n // _STEP_ROWS),),
+        # the arrays as they lie in HBM, a step's 8,192 rows of each a block:
+        # nothing is padded, so the last block is ragged (sub_chunk)
         in_specs=[
-            pl.BlockSpec(
-                (recipe.n_i32, step_s, _CHUNK_L),
-                lambda i: (0, i, 0),
-                memory_space=vmem,
-            ),
-            pl.BlockSpec(
-                (max(recipe.n_f32, 1), step_s, _CHUNK_L),
-                lambda i: (0, i, 0),
-                memory_space=vmem,
-            ),
-        ] + [
+            pl.BlockSpec((_STEP_ROWS,), lambda i: (i,), memory_space=vmem)
+        ] * n_rows + [
             pl.BlockSpec(shape, lambda i: (0, 0), memory_space=pltpu.SMEM)
             for shape in scalar_shapes
         ],
@@ -852,39 +929,43 @@ def _dd_planes(data):
 
 
 def run(recipe: _Recipe, scan_cols, live, *, params=(), interpret: bool = False):
-    """Execute the fused pipeline.  `params`: the value of each of
-    `recipe.params` as a scalar of its SQL type's dtype, traced or concrete.
+    """Execute the fused pipeline.  `live`: the page's live mask, or — an
+    int — the row count of a page that has none (every row live: the kernel
+    then takes no mask and tells rows by their index).  `params`: the value
+    of each of `recipe.params` as a scalar of its SQL type's dtype, traced
+    or concrete.
 
     Returns (totals f64 (NR, D), n_groups int array) — per-stream per-group
     sums; the caller assembles aggregate columns via `assemble`."""
     interpret = bool(interpret or _hashagg.INTERPRET)
-    n = live.shape[0]
-    n_pad = -(-max(n, 1) // _STEP_ROWS) * _STEP_ROWS
-    n_chunks = n_pad // _STEP_ROWS
+    has_live = not isinstance(live, int)
+    n = live.shape[0] if has_live else live
+    if n == 0:  # no row, no block to read
+        return _totals(recipe, jnp.zeros((2,) + _acc_shape(recipe), jnp.float32))
 
-    i32_planes: list = [None] * recipe.n_i32
-    f32_planes: list = [None] * max(recipe.n_f32, 1)
-    # the casts, splits, pads and stacks that lay the kernel's planes out:
-    # device work of their own, told apart in a trace by this scope
+    # what is not resident in the form the kernel reads — a cast, a 64-bit
+    # column's split — is device work of its own, told apart in a trace by
+    # this scope; a resident int32 column goes to the kernel as it is
     with jax.named_scope("fused_scan_prep"):
-        i32_planes[0] = _prep(live.astype(jnp.int32), n_pad, 0)
-        for ci, plan in recipe.cols:
+        split = functools.cache(lambda ci: _dd_planes(scan_cols[ci].data))
+        rows = []
+        for what, ci in recipe.operands:
             cv = scan_cols[ci]
-            if plan[0] == "dd":
-                _, hp, lp, vp, _ = plan
-                hi, lo = _dd_planes(cv.data)
-                f32_planes[hp] = _prep(hi, n_pad, 0.0)
-                f32_planes[lp] = _prep(lo, n_pad, 0.0)
-            elif plan[0] == "dict":
-                i32_planes[plan[1]] = _prep(cv.data.astype(jnp.int32), n_pad, 0)
-            else:
-                _, p, vp, _ = plan
-                i32_planes[p] = _prep(cv.data.astype(jnp.int32), n_pad, 0)
-            if plan[0] != "dict" and plan[-2] >= 0:
-                i32_planes[plan[-2]] = _prep(cv.valid.astype(jnp.int32), n_pad, 0)
-        if recipe.n_f32 == 0:
-            f32_planes[0] = _prep(jnp.zeros((1,), jnp.float32), n_pad, 0.0)
-        i32, f32 = jnp.stack(i32_planes), jnp.stack(f32_planes)
+            if what == "data":
+                rows.append(cv.data)
+            elif what == "cast":
+                rows.append(cv.data.astype(jnp.int32))
+            elif what == "valid":
+                rows.append(cv.valid.astype(jnp.int32))
+            else:  # "hi" | "lo"
+                rows.append(split(ci)[what == "lo"])
+        if has_live:
+            rows.append(live.astype(jnp.int32))
+        if n <= _XLA_SMALL_ROWS:
+            rows = [
+                jnp.concatenate([r, jnp.zeros((_SUB_ROWS - n,), r.dtype)])
+                for r in rows
+            ]
         pi, pf = [], []
         slots, _, _ = _scalar_slots(recipe.params)
         for (where, _k), v in zip(slots, params, strict=True):
@@ -894,9 +975,9 @@ def run(recipe: _Recipe, scan_cols, live, *, params=(), interpret: bool = False)
                 pi.append(jnp.asarray(v).astype(jnp.int32))
         scalars = [jnp.stack(p).reshape(1, -1) for p in (pi, pf) if p]
 
-    call = _fused_kernel(recipe, n_chunks, interpret)
+    call = _fused_kernel(recipe, n, has_live, interpret)
     with jax.enable_x64(False):
-        out = call(i32, f32, *scalars)
+        out = call(*rows, *scalars)
     return _totals(recipe, out)
 
 
