@@ -75,7 +75,7 @@ def test_tracing_spans(engine):
         assert root.find("planner") is not None
         assert root.find("execute") is not None
         assert root.duration_ms >= root.find("planner").duration_ms
-        d = root.to_dict()
+        d = root.to_export_dict()
         assert d["name"] == "query"
         assert [c["name"] for c in d["children"]] == ["planner", "execute", "to_rows"]
     finally:

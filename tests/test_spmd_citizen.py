@@ -138,7 +138,10 @@ def test_third_execution_builds_nothing_and_says_what_it_moves(cell, name):
     again = cell.run(name)
     assert again["rows"] == cell.first[name]["rows"]
     assert again["builds"] == 0
-    assert again["names"] == ["execute", "scan_load", "dispatch", "device_wait"]
+    assert again["names"] == ["execute", "scan_load", "size", "program_lookup",
+                              "dispatch", "device_wait"]
+    assert again["spans"][2].attributes["source"] == "learned"
+    assert again["spans"][3].attributes["jit_cache"] == "hit"
     by_name = {s.name: s.attributes for s in again["spans"]}
     scan = by_name["scan_load"]
     assert scan["h2d_bytes"] == 0 and scan["columns_cached"] == scan["columns"] > 0

@@ -7,7 +7,9 @@ not `joined` appears exactly when the compile service builds; (d)
 `Tracer.record` and handler-thread roots; (e) an executor without a tracer;
 (f) plan-node scopes and kernel names in the lowered fragment, and nothing
 else changed by them; (g) the benchmark's span readers against a recorded
-fixture.
+fixture; (h) the thread's CPU clock on every span (ISSUE 37); (i)
+benchmarks/hostpath.py: a request cut into pieces that add up to it, by hand
+and against a slice recorded on the chip.
 """
 
 import contextlib
@@ -34,6 +36,11 @@ def _flat(span, depth=0):
     yield span, depth
     for c in span.children:
         yield from _flat(c, depth + 1)
+
+
+def _attrs(span):
+    """A span's attributes without its CPU clock."""
+    return {k: v for k, v in span.attributes.items() if k != "cpu_ms"}
 
 
 def _engine():
@@ -64,6 +71,7 @@ def served():
     try:
         coord = runner.coordinator
         coord.session.set("result_cache_enabled", "false")
+        coord.tracer._cpu = time.thread_time  # whatever `_cpu_clock` made of a busy machine
         exporter = InMemorySpanExporter()
         coord.tracer.add_exporter(exporter)
         client = StatementClient(runner.client_url)
@@ -99,14 +107,30 @@ def test_served_query_leaves_the_four_kinds_of_root(served):
 def test_served_query_tree_has_every_span_nested_as_documented(served):
     query = next(s for s in served["roots"] if s.name == "query")
     assert [c.name for c in query.children] == [
-        "queued", "planner", "schedule", "root_fragment", "to_rows", "query_info"]
+        "queued", "parse", "planner", "schedule", "root_fragment", "to_rows",
+        "query_info", "cleanup", "commit"]
     root_fragment = query.find("root_fragment")
     assert [c.name for c in root_fragment.children] == [
-        "scan_load", "compile", "dispatch", "device_wait", "operator_stats"]
-    assert query.find("planner").attributes == {"preplanned": False, "fragments": 1}
-    assert query.find("schedule").attributes == {"stages": 0, "tasks": 0}
-    assert root_fragment.attributes == {"fragment_id": 0}
-    assert query.find("to_rows").attributes["rows"] == 1
+        "scan_load", "size", "program_lookup", "compile", "dispatch",
+        "device_wait", "settle", "operator_stats"]
+    assert _attrs(query.find("parse")) == {
+        "sql_bytes": len(QUERIES["q06"]), "statement": "QueryStmt"}
+    assert _attrs(query.find("planner")) == {"preplanned": False, "fragments": 1}
+    assert _attrs(query.find("schedule")) == {"stages": 0, "tasks": 0}
+    assert _attrs(root_fragment) == {"fragment_id": 0}
+    # a new executor has learned nothing: its capacities come from the
+    # capacity cache or from statistics, its own program cache misses, and
+    # what the run converged on is kept
+    assert _attrs(query.find("size"))["source"] in ("cached", "initial")
+    assert _attrs(query.find("program_lookup")) == {"jit_cache": "miss"}
+    assert _attrs(query.find("settle")) == {"stored": True}
+    assert _attrs(query.find("cleanup")) == {"tasks": 0}
+    assert _attrs(query.find("commit")) == {"state": "FINISHED"}
+    to_rows = query.find("to_rows")
+    assert to_rows.attributes["rows"] == 1
+    # the live mask, and the sum's data, validity and upper limb
+    assert to_rows.attributes["d2h_arrays"] == 4
+    assert 0.0 < to_rows.attributes["fetch_ms"] <= to_rows.duration_ms
     compile_ = query.find("compile")
     assert compile_.attributes["signature"] == query.find("dispatch").attributes["signature"]
     assert compile_.attributes["cause"] in ("new_plan", "caps_tier", "new_avals", "joined")
@@ -122,7 +146,13 @@ def test_served_children_lie_inside_their_parents(served):
             if child.name == "queued":
                 continue
             assert span.start_s <= child.start_s <= child.end_s <= span.end_s, child.name
-            assert child.start_s >= before, child.name  # in order, never overlapping
+            if child.name == "compile":
+                # recorded from the miss, which `program_lookup` found: the
+                # one child that begins inside its elder sibling
+                lookup = span.find("program_lookup")
+                assert lookup.start_s <= child.start_s <= lookup.end_s <= child.end_s
+            else:
+                assert child.start_s >= before, child.name  # in order, never overlapping
             before = child.end_s
     # `queued` began on the handler's thread, before `query` opened, and
     # ends where `query` starts
@@ -134,6 +164,8 @@ def test_served_children_lie_inside_their_parents(served):
 def test_served_http_spans_say_what_the_metrics_read(served):
     post = next(s for s in served["roots"] if s.name == "http.post")
     assert post.attributes["body_bytes"] == len(QUERIES["q06"].encode())
+    assert 0.0 < post.attributes["admit_ms"] <= post.duration_ms
+    assert 0.0 <= post.attributes["cpu_ms"] <= post.duration_ms + 1.0
     gets = [s for s in served["roots"] if s.name == "http.get"]
     last = [s for s in gets if s.attributes["served"]]
     assert len(last) == 1 and last[0].attributes["since_finished_ms"] >= 0.0
@@ -144,6 +176,11 @@ def test_served_http_spans_say_what_the_metrics_read(served):
     attrs = last[0].attributes
     finished_s = last[0].start_s + attrs["held_ms"] / 1e3 - attrs["since_finished_ms"] / 1e3
     assert query.start_s < finished_s <= query.end_s
+    # the answer's way out, after the hold: `json.dumps`, then the socket
+    assert attrs["encode_ms"] > 0.0 and attrs["write_ms"] > 0.0
+    after_hold_ms = last[0].duration_ms - attrs["held_ms"]
+    assert attrs["encode_ms"] + attrs["write_ms"] <= after_hold_ms + 1e-6
+    assert 0.0 <= attrs["cpu_ms"] <= last[0].duration_ms + 1.0
 
 
 def test_served_query_is_taken_by_one_held_poll(served):
@@ -233,15 +270,33 @@ def test_prepared_request_leaves_the_spans_of_a_text_request():
         kernels.set_policy(kernels.KernelPolicy())  # the process's, not the session's
     first, second = queries
     assert [c.name for c in first.children] == [
-        "queued", "planner", "root_fragment", "to_rows"]
+        "queued", "parse", "planner", "bind", "root_fragment", "to_rows", "commit"]
     assert [c.name for c in first.find("root_fragment").children] == [
-        "scan_load", "compile", "dispatch", "device_wait"]
-    assert first.find("planner").attributes == {"preplanned": False, "plan_cache": "miss"}
-    # a new binding: the cached plan, the compiled program, the same kernel
-    assert second.find("planner").attributes == {"preplanned": True, "plan_cache": "hit"}
+        "scan_load", "size", "program_lookup", "compile", "dispatch",
+        "device_wait", "settle"]
+    assert _attrs(first.find("planner")) == {"preplanned": False, "plan_cache": "miss"}
+    assert _attrs(first.find("program_lookup")) == {"jit_cache": "miss"}
+    assert _attrs(first.find("size"))["source"] in ("cached", "initial")
+    # a new binding: the cached plan, the compiled program, the same kernel,
+    # and an executor that is kept: its own capacities, its own program
+    assert _attrs(second.find("planner")) == {"preplanned": True, "plan_cache": "hit"}
+    assert [c.name for c in second.children] == [c.name for c in first.children]
     assert [c.name for c in second.find("root_fragment").children] == [
-        "scan_load", "dispatch", "device_wait"]
+        "scan_load", "size", "program_lookup", "dispatch", "device_wait"]
+    assert _attrs(second.find("size")) == {"source": "learned"}
+    assert _attrs(second.find("program_lookup")) == {"jit_cache": "hit"}
     for q in queries:
+        assert _attrs(q.find("parse")) == {
+            "sql_bytes": len("EXECUTE q06 USING DATE '1994-01-01', DATE '1995-01-01', "
+                             "0.05, 0.07, 24"), "statement": "ExecuteStmt"}
+        assert _attrs(q.find("bind")) == {"params": 5}
+        assert _attrs(q.find("commit")) == {"state": "FINISHED"}
+        assert q.find("to_rows").attributes["d2h_arrays"] == 4
+        assert q.find("to_rows").attributes["fetch_ms"] > 0.0
+        for span, _depth in _flat(q):  # every span inside its parent
+            for child in span.children:
+                if child.name != "queued":
+                    assert span.start_s <= child.start_s <= child.end_s <= span.end_s
         kernels = q.find("dispatch").attributes["kernels"]
         assert re.fullmatch(
             r"pallas fused_pipeline \(5 filters 3 streams domain 1 scatter vpu "
@@ -264,6 +319,7 @@ def test_scan_load_counts_bytes_once():
     assert not engine.executor._table_cols
     # where the columns came from, and the host's part of loading them
     # (read + code + narrow, the uploads left out)
+    first.attributes.pop("cpu_ms"), second.attributes.pop("cpu_ms")
     assert first.attributes.pop("source") in ("generated", "file")
     assert 0 < first.attributes.pop("host_prepare_ms") < 1e3 * (first.end_s - first.start_s)
     assert first.attributes == {"h2d_bytes": resident, "columns": 4, "columns_cached": 0}
@@ -486,4 +542,258 @@ def test_reader_against_the_recorded_slice(recorded, metric, capsys):
         unnamed = dict(ctx, trace=dict(ctx["trace"], path=os.path.join(
             REPO, "benchmarks", "testdata", "served_q06_slice.xplane.pb")))
         assert loader.layer_reader(metric)(unnamed) == 0.0
+    assert loader.layer_reader(metric)(dict(ctx, trace=None)) is None
+
+
+# ------------------------------------------- (h) the thread's CPU clock
+
+
+def test_every_with_span_carries_the_threads_cpu_clock(served):
+    seen = set()
+    for root in served["all"]:
+        for span, _depth in _flat(root):
+            if span.name == "queued":  # begins on the handler's thread, ends on the query's
+                assert "cpu_ms" not in span.attributes
+                continue
+            seen.add(span.name)
+            # at most its wall, and a clock tick
+            assert 0.0 <= span.attributes["cpu_ms"] <= span.duration_ms + 1.0, span.name
+    assert {"http.post", "http.get", "query", "finalize", "parse", "planner", "schedule",
+            "root_fragment", "scan_load", "size", "program_lookup", "compile", "dispatch",
+            "device_wait", "settle", "operator_stats", "to_rows", "query_info", "cleanup",
+            "commit"} <= seen
+
+
+def test_cpu_clock_tells_a_busy_thread_from_a_waiting_one():
+    tracer = Tracer()
+    tracer._cpu = time.thread_time
+    with tracer.span("busy") as busy:
+        until = time.perf_counter() + 0.05
+        while time.perf_counter() < until:
+            pass
+    with tracer.span("asleep") as asleep:
+        time.sleep(0.05)
+    # other tests share the machine: a busy thread may be held off its core
+    assert 0.5 * busy.duration_ms <= busy.attributes["cpu_ms"] <= busy.duration_ms + 1.0
+    assert asleep.duration_ms >= 50.0 and asleep.attributes["cpu_ms"] < 5.0
+
+
+def test_record_takes_the_recording_threads_cpu_start():
+    tracer = Tracer()
+    tracer._cpu = time.thread_time
+    with tracer.span("query") as query:
+        t0, cpu0 = time.perf_counter(), tracer.cpu_now()
+        while time.perf_counter() < t0 + 0.02:
+            pass
+        worked = tracer.record("http.get", t0, cpu_start_s=cpu0, served=True)
+        plain = tracer.record("queued", t0)
+    assert query.children == [worked, plain]
+    assert worked.attributes["served"] is True
+    assert 0.5 * worked.duration_ms <= worked.attributes["cpu_ms"] <= worked.duration_ms + 1.0
+    assert "cpu_ms" not in plain.attributes  # no start, no clock
+
+
+def test_a_host_whose_cpu_clock_is_unfit_gets_no_cpu_ms(monkeypatch):
+    """The chip tool's machines serve `thread_time()` in 6 us from a counter
+    that ticks every 10 ms (PERF.md, PR 37): there the tracer reads no CPU
+    clock at all, and a span is what it was."""
+    from trino_tpu.utils import tracing
+
+    assert tracing._cpu_clock() in (None, time.thread_time)  # measured once, kept
+    slow = iter(range(10 ** 6))  # a clock that never moves while the thread spins
+    monkeypatch.setattr(tracing.time, "thread_time", lambda: next(slow) * 0.0)
+    assert tracing._cpu_clock.__wrapped__() is None
+    monkeypatch.undo()
+
+    def dear():  # a clock that is right and costs 20 us a read
+        until = time.perf_counter() + 20e-6
+        while time.perf_counter() < until:
+            pass
+        return time.process_time()
+
+    monkeypatch.setattr(tracing.time, "thread_time", dear)
+    assert tracing._cpu_clock.__wrapped__() is None
+    monkeypatch.undo()
+
+    tracer = Tracer()
+    tracer._cpu = None
+    assert tracer.cpu_now() is None
+    with tracer.span("query") as query:
+        with tracer.span("planner"):
+            pass
+        recorded = tracer.record("http.get", 1.0, 2.0, cpu_start_s=tracer.cpu_now(), served=True)
+    assert query.attributes == {} and query.children[0].attributes == {}
+    assert recorded.attributes == {"served": True}
+
+
+# ---------------------------------------- (i) a request cut into its pieces
+
+
+@pytest.fixture(scope="module")
+def hostpath():
+    bench = os.path.join(REPO, "benchmarks")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    import hostpath
+    import loader
+    import tracered
+
+    return hostpath, loader, tracered
+
+
+def _span(name, t0, t1, depth=0, **attrs):
+    return {"name": name, "t0": t0, "t1": t1, "depth": depth, "attrs": attrs}
+
+
+def _by_hand():
+    """One request of three threads, in seconds: the POST's handler, the
+    query's thread, and a poll that came while the query was queued and was
+    held over all of it; the device busy for 2 ms of `device_wait`'s 4."""
+    spans = [
+        _span("http.post", 10.001, 10.004, query_id="q1", cpu_ms=0.6, admit_ms=0.9),
+        _span("query", 10.005, 10.0165, query_id="q1", cpu_ms=5.0),
+        _span("queued", 10.003, 10.005, 1),
+        _span("parse", 10.005, 10.006, 1, cpu_ms=1.0),
+        _span("root_fragment", 10.006, 10.014, 1, cpu_ms=3.0),
+        _span("program_lookup", 10.0065, 10.0075, 2, cpu_ms=1.0),
+        _span("compile", 10.007, 10.008, 2, cpu_ms=0.5),  # from the miss, inside the lookup
+        _span("dispatch", 10.008, 10.009, 2, cpu_ms=0.5),
+        _span("device_wait", 10.009, 10.013, 2, cpu_ms=0.4),
+        _span("to_rows", 10.014, 10.0148, 1, cpu_ms=0.5, fetch_ms=0.6, d2h_arrays=22),
+        _span("commit", 10.015, 10.016, 1, cpu_ms=0.1),  # the terminal transition inside it
+        _span("finalize", 10.0165, 10.0170, query_id="q1", cpu_ms=0.5),
+        # terminal at 10.0158, the handler runs again at 10.0163, then 1.2 ms
+        # of its own, 1.0 ms of json.dumps and 0.5 ms of socket
+        _span("http.get", 10.0045, 10.019, query_id="q1", served=True, cpu_ms=1.35,
+              held_ms=11.8, since_finished_ms=0.5, encode_ms=1.0, write_ms=0.5),
+    ]
+    records = [{"template": "q01", "stream": 0, "t0": 10.0, "t1": 10.02,
+                "query_id": "q1", "error": None}]
+    trace = {"busy": [(10.010, 10.012)], "slice": (9.9, 10.1)}
+    return {"spans": spans, "records": records, "trace": trace}
+
+
+def test_a_request_by_hand_is_cut_into_exact_pieces(hostpath, capsys):
+    hp, loader, _tracered = hostpath
+    ctx = _by_hand()
+    rep = hp.report(ctx)
+    ((rec, pieces, inside),) = rep["requests"]
+    want = {  # label -> (wall ms, cpu ms)
+        "client": (2.0, None),              # before the POST, after the answer
+        "http.post (self)": (2.0, 0.4),     # until `queued` begins
+        "queued": (2.0, None),
+        "parse": (1.0, 1.0),
+        "root_fragment (self)": (1.5, 0.6),
+        "program_lookup": (1.0, 1.0),       # keeps what `compile` lies over
+        "compile": (0.5, 0.25),
+        "dispatch": (1.0, 0.5),
+        "device": (2.0, 0.2),
+        "device_wait": (2.0, 0.2),
+        "to_rows": (0.8, 0.5),
+        "query (self)": (0.2, 0.4 * 0.2 / 0.7),
+        "commit": (0.8, 0.08),              # up to the terminal transition
+        "http.get (wake)": (0.5, 0.0),
+        "http.get (self)": (1.2, 0.6),
+        "http.get encode": (1.0, 0.5),
+        "http.get write": (0.5, 0.25),
+    }
+    assert pieces.keys() == want.keys()
+    for label, (wall, cpu) in want.items():
+        assert math.isclose(pieces[label][0] * 1e3, wall, abs_tol=1e-6), label
+        if cpu is None:
+            assert pieces[label][1] is None, label
+        else:
+            assert math.isclose(pieces[label][1] * 1e3, cpu, abs_tol=1e-6), label
+    assert abs(sum(w for w, _c in pieces.values()) - (rec["t1"] - rec["t0"])) < 1e-9
+    # at work in company: the handler's 0.7 ms while the query's thread
+    # closes its span and finalizes
+    assert math.isclose(inside["contended_ms"], 0.7, abs_tol=1e-6)
+    assert {k: v for k, v in inside.items() if k != "contended_ms"} == {
+        "to_rows.fetch_ms": 0.6, "to_rows.d2h_arrays": 22, "http.post.admit_ms": 0.9}
+    # what the eight metrics make of it (one template: its median)
+    for metric, value in {
+            "to_rows_ms": 0.8, "executor_setup_ms": 3.0, "dispatch_ms": 1.0,
+            "device_sync_ms": 2.0, "response_write_ms": 1.5, "client_outside_ms": 2.0,
+            "host_contended_ms": 0.7,
+            "host_path_unnamed_share": 100 * (0.2 + 1.5 + 2.0 + 1.2) / 20.0}.items():
+        assert math.isclose(loader.layer_reader(metric)(ctx), value, abs_tol=1e-6), metric
+    # printed once a run, whichever reader came first
+    out = capsys.readouterr().out
+    assert out.count("bench: host path by template (median ms a request, wall | cpu): ") == 1
+    table = json.loads(out.split("wall | cpu): ")[1].splitlines()[0])
+    assert table["q01"]["n"] == 1 and table["q01"]["client_ms"] == 20.0
+    assert table["q01"]["pieces"]["device"] == [2.0, 0.2]
+    walls = [wall for wall, _cpu in table["q01"]["pieces"].values()]
+    assert walls == sorted(walls, reverse=True)  # the largest piece first
+    assert out.count("bench: device idle by span (s of the slice): ") == 1
+
+
+def test_an_idle_gap_is_split_where_the_spans_change(hostpath):
+    """One idle gap of a second under `to_rows` for 0.3 s and `query_info`
+    for 0.7 s: the midpoint rule hands the whole of it to the span over its
+    middle, the partition cuts it at the boundary."""
+    hp, _loader, tracered = hostpath
+    spans = [
+        _span("query", 4.0, 5.0, query_id="q1", cpu_ms=900.0),
+        _span("to_rows", 4.0, 4.3, 1, cpu_ms=300.0),
+        _span("query_info", 4.3, 5.0, 1, cpu_ms=600.0),
+        _span("commit", 5.0, 5.0, 1, cpu_ms=0.0),  # this PR's spans: there is a host path
+    ]
+    records = [{"template": "q01", "stream": 0, "t0": 4.0, "t1": 5.0,
+                "query_id": "q1", "error": None}]
+    trace = {"busy": [(0.0, 4.0), (5.0, 10.0)], "slice": (0.0, 10.0), "ops": {}}
+    rep = hp.report({"spans": spans, "records": records, "trace": trace})
+    assert rep["idle"].keys() == {"q01 / to_rows", "q01 / query_info"}
+    assert math.isclose(rep["idle"]["q01 / to_rows"], 0.3, abs_tol=1e-9)
+    assert math.isclose(rep["idle"]["q01 / query_info"], 0.7, abs_tol=1e-9)
+    whole = tracered.breakdown(trace, records, spans)["idle_gaps"]
+    assert whole == [["bench:q01 / query_info", 1.0]]
+    # two requests open at once share an idle instant equally
+    both = spans + [_span("query", 4.0, 5.0, query_id="q2", cpu_ms=1.0)]
+    records2 = records + [dict(records[0], template="q06", query_id="q2")]
+    idle = hp.report({"spans": both, "records": records2, "trace": trace})["idle"]
+    assert math.isclose(idle["q01 / to_rows"], 0.15, abs_tol=1e-9)
+    assert math.isclose(idle["q06 / query"], 0.5, abs_tol=1e-9)
+
+
+HOSTPATH_READERS = ["to_rows_ms", "executor_setup_ms", "dispatch_ms", "device_sync_ms",
+                    "response_write_ms", "client_outside_ms", "host_contended_ms",
+                    "host_path_unnamed_share"]
+NEW_SPANS = {"parse", "bind", "size", "program_lookup", "settle", "cleanup", "commit"}
+NEW_ATTRS = {"cpu_ms", "encode_ms", "write_ms", "admit_ms", "fetch_ms", "d2h_arrays"}
+
+
+@pytest.fixture(scope="module")
+def recorded_hostpath(hostpath):
+    _hp, loader, _tracered = hostpath
+    ctx = loader.load_json("testdata", "served_sf10_scan_hostpath.json")
+    ctx["trace"]["busy"] = [tuple(b) for b in ctx["trace"]["busy"]]
+    ctx["trace"]["slice"] = tuple(ctx["trace"]["slice"])
+    return ctx, loader.load_json("testdata", "served_sf10_scan_hostpath.expected.json")
+
+
+@pytest.mark.parametrize("metric", HOSTPATH_READERS)
+def test_hostpath_reader_against_the_recorded_slice(hostpath, recorded_hostpath, metric):
+    """~20 passes of q06 + q01 cut from a traced chip run of
+    `served_sf10_scan` (ISSUE 37)."""
+    hp, loader, _tracered = hostpath
+    ctx, expected = recorded_hostpath
+    value = loader.layer_reader(metric)(ctx)
+    assert math.isclose(value, expected[metric], rel_tol=1e-6), (value, expected[metric])
+    rep = hp.report(ctx)
+    assert len(rep["requests"]) == expected["requests"]
+    for rec, pieces, _inside in rep["requests"]:  # a partition, not a sample
+        assert abs(sum(w for w, _c in pieces.values()) - (rec["t1"] - rec["t0"])) < 1e-6
+    # a host without a fit CPU clock: the same pieces, no `cpu` beside them
+    bare = dict(ctx, spans=[
+        dict(s, attrs={k: v for k, v in s["attrs"].items() if k != "cpu_ms"})
+        for s in ctx["spans"]])
+    assert math.isclose(loader.layer_reader(metric)(bare), expected[metric], rel_tol=1e-6)
+    assert all(cpu is None for _r, pieces, _in in hp.report(bare)["requests"]
+               for _wall, cpu in pieces.values())
+    # the parent commit's spans: none of the new ones, no CPU clock
+    old = dict(ctx, spans=[
+        dict(s, attrs={k: v for k, v in s["attrs"].items() if k not in NEW_ATTRS})
+        for s in ctx["spans"] if s["name"] not in NEW_SPANS])
+    assert loader.layer_reader(metric)(old) is None
     assert loader.layer_reader(metric)(dict(ctx, trace=None)) is None

@@ -375,9 +375,11 @@ class Page:
         return np.asarray(everything[0]), everything[1:]
 
     # -- host-side materialization (result sets, test assertions) -----------
-    def to_pylist(self) -> list[tuple]:
-        """Compact live rows to host as Python tuples (None for NULL)."""
-        live, host_cols = self._fetch_host()
+    def to_pylist(self, fetched=None) -> list[tuple]:
+        """Compact live rows to host as Python tuples (None for NULL).
+        `fetched`: what `_fetch_host` returned, from a caller that times the
+        transfer apart from the conversion."""
+        live, host_cols = fetched if fetched is not None else self._fetch_host()
         idx = np.nonzero(live)[0]
         cols: list[np.ndarray] = []
         valids: list[Optional[np.ndarray]] = []

@@ -468,41 +468,49 @@ class LocalExecutor:
         self.execute_events = {}
         nodes = _node_ids(plan)
         inputs = self._load_inputs(nodes, remote_pages)
-        caps = known = self._learned_caps.get(plan)
-        # the protocol's one step down belongs to the run that first
-        # converges the plan: in this executor, and (capcache) this process
-        tighten = False
-        if caps is None:
-            cached, settled = load_caps(plan, inputs, self._caps_scope)
-            tighten = not settled
-            # nothing learned: the stats-sized capacities, which the compiled
-            # program's overflow-retry loop below corrects, unless the cache
-            # has them.  A cached entry from an older code version may size
-            # fewer node kinds than the current tracer reads — only trust it
-            # when it covers every currently-sized node (else KeyError
-            # mid-trace)
-            caps = self._initial_caps(nodes, inputs)
-            if cached is not None and set(cached) >= set(caps):
-                caps = cached
-        # capacity bucketing (ROADMAP 2a): every cap — planner-fed, stats-
-        # fed, cached from an older code version, or learned — lands on a
-        # pow2 tier, so near-identical shapes collapse onto ONE jit
-        # signature instead of each minting its own compiled program.  Also
-        # snapshots the dict: the retry loop below mutates caps in place,
-        # and learned/cached dicts must not alias it.
-        caps = {nid: _pow2(max(int(c), 1)) for nid, c in caps.items()}
-        budget = self.memory_budget_bytes
-        if budget:
-            est = self._estimate_bytes(inputs, caps)
-            # recorded for the memory-governance plane: the worker reports
-            # this alongside its NodeMemoryPool reservation so the cluster
-            # memory manager sees estimated vs reserved bytes per task
-            self.last_estimated_bytes = est
-            if est > budget:
-                raise MemoryBudgetExceeded(
-                    f"task needs ~{est} bytes of device memory,"
-                    f" budget is {budget}"
-                )
+        # `size`: where this run's capacities come from — `learned` by this
+        # executor, `cached` by another (capcache), or `initial` from
+        # statistics — their tiers and the memory estimate
+        with self._span("size", source="learned") as span:
+            caps = known = self._learned_caps.get(plan)
+            # the protocol's one step down belongs to the run that first
+            # converges the plan: in this executor, and (capcache) this process
+            tighten = False
+            if caps is None:
+                cached, settled = load_caps(plan, inputs, self._caps_scope)
+                tighten = not settled
+                # nothing learned: the stats-sized capacities, which the
+                # compiled program's overflow-retry loop below corrects,
+                # unless the cache has them.  A cached entry from an older
+                # code version may size fewer node kinds than the current
+                # tracer reads — only trust it when it covers every
+                # currently-sized node (else KeyError mid-trace)
+                caps = self._initial_caps(nodes, inputs)
+                source = "initial"
+                if cached is not None and set(cached) >= set(caps):
+                    caps, source = cached, "cached"
+                if span is not None:
+                    span.attributes["source"] = source
+            # capacity bucketing (ROADMAP 2a): every cap — planner-fed,
+            # stats-fed, cached from an older code version, or learned —
+            # lands on a pow2 tier, so near-identical shapes collapse onto
+            # ONE jit signature instead of each minting its own compiled
+            # program.  Also snapshots the dict: the retry loop below mutates
+            # caps in place, and learned/cached dicts must not alias it.
+            caps = {nid: _pow2(max(int(c), 1)) for nid, c in caps.items()}
+            budget = self.memory_budget_bytes
+            if budget:
+                est = self._estimate_bytes(inputs, caps)
+                # recorded for the memory-governance plane: the worker
+                # reports this alongside its NodeMemoryPool reservation so
+                # the cluster memory manager sees estimated vs reserved
+                # bytes per task
+                self.last_estimated_bytes = est
+                if est > budget:
+                    raise MemoryBudgetExceeded(
+                        f"task needs ~{est} bytes of device memory,"
+                        f" budget is {budget}"
+                    )
         # plans with host-collected aggregates (array_agg/map_agg/listagg)
         # cannot trace: their outputs intern structured values on the host.
         # Run them eagerly — op-by-op dispatch with concrete arrays.
@@ -533,11 +541,8 @@ class LocalExecutor:
                 if nid in caps and int(req) > caps[nid]
             }
             if not overflow:
-                if tighten and self._tighten(nodes, caps, required, grown):
-                    self._tightened.add(plan)
-                self._learned_caps[plan] = caps
-                if caps != known:  # learned or tightened in this run
-                    store_caps(plan, inputs, caps, self._caps_scope)
+                self._settle(plan, nodes, inputs, caps, known, required,
+                             grown, tighten)
                 # execute wall = everything this call that wasn't compile
                 # (table IO, kernel dispatch, an eager fallback); the compile
                 # side was accumulated by _run as it hit jit-cache misses
@@ -557,6 +562,24 @@ class LocalExecutor:
             grown.update(overflow)
             tier_cause = "caps_tier"
         raise RuntimeError(f"capacity retry loop did not converge: {caps}")
+
+    def _settle(self, plan, nodes, inputs, caps, known, required, grown,
+                tighten: bool) -> None:
+        """After a converged run: the one step down, and the tiers kept for
+        this executor's next run and (capcache) for other executors.  A run
+        at tiers this executor had learned settles nothing and opens no
+        span."""
+        self._learned_caps[plan] = caps
+        if not tighten and caps == known:
+            return
+        with self._span("settle") as span:
+            if tighten and self._tighten(nodes, caps, required, grown):
+                self._tightened.add(plan)
+            stored = caps != known  # learned or tightened in this run
+            if stored:
+                store_caps(plan, inputs, caps, self._caps_scope)
+            if span is not None:
+                span.attributes["stored"] = stored
 
     @staticmethod
     def _tighten(nodes, caps, required, grown) -> bool:
@@ -839,24 +862,31 @@ class LocalExecutor:
 
         collect = self.collect_operator_stats
         params = tuple(params)
-        cache_key, treedef, avals = self._cache_key(plan, inputs, caps, params)
-        _JIT_CACHE_LOOKUPS.labels(
-            "hit" if cache_key in self._jit_cache else "miss"
-        ).inc()
-        if cache_key not in self._jit_cache:
-            # A capacity-overflow retry lands here again with new caps — a
-            # new SIGNATURE: the signature carries the tiers, so a compile
-            # on a statement that was warm names the node whose capacity
-            # grew (profiler ledger, `compile` span), not just the plan.
-            t_miss = _time.perf_counter()
-            sig = signature_of(plan, caps)
-            svc = self.compile_service or SERVICE
-            # snapshot caps for the traced closure: execute()'s overflow
-            # retry loop mutates its dict in place, and a compile still
-            # queued in the service after a fallback must trace the tiers
-            # its signature was named for
-            call, holder = self._make_call(plan, dict(caps), collect)
-
+        # `program_lookup`: this executor's own cache of compiled programs,
+        # and on a miss what names and makes the program to ask the compile
+        # service for (the `compile` record begins at the miss, beside it)
+        with self._span("program_lookup") as span:
+            cache_key, treedef, avals = self._cache_key(plan, inputs, caps, params)
+            outcome = "hit" if cache_key in self._jit_cache else "miss"
+            _JIT_CACHE_LOOKUPS.labels(outcome).inc()
+            if span is not None:
+                span.attributes["jit_cache"] = outcome
+            if outcome == "miss":
+                # A capacity-overflow retry lands here again with new caps —
+                # a new SIGNATURE: the signature carries the tiers, so a
+                # compile on a statement that was warm names the node whose
+                # capacity grew (profiler ledger, `compile` span), not just
+                # the plan.
+                t_miss = _time.perf_counter()
+                cpu_miss = self.tracer.cpu_now() if self.tracer is not None else None
+                sig = signature_of(plan, caps)
+                svc = self.compile_service or SERVICE
+                # snapshot caps for the traced closure: execute()'s overflow
+                # retry loop mutates its dict in place, and a compile still
+                # queued in the service after a fallback must trace the
+                # tiers its signature was named for
+                call, holder = self._make_call(plan, dict(caps), collect)
+        if outcome == "miss":
             def build(_call=call, _holder=holder):
                 # AOT lower+compile (instead of letting the first dispatch
                 # compile lazily) so compile wall is measured apart from
@@ -922,7 +952,8 @@ class LocalExecutor:
                 # program some other execution built (cause `joined`)
                 built = out.result if out.fresh and out.status == "ready" else {}
                 self.tracer.record(
-                    "compile", t_miss, signature=sig, cause=out.cause,
+                    "compile", t_miss, cpu_start_s=cpu_miss,
+                    signature=sig, cause=out.cause,
                     status=out.status, compile_s=built.get("compile_s", 0.0),
                     cache=built.get("cache"),
                 )
@@ -1038,6 +1069,22 @@ class LocalExecutor:
         else:
             e["executes"] += 1
             e["execute_s"] = round(e["execute_s"] + float(seconds), 6)
+
+
+def page_rows(tracer, page: Page) -> list[tuple]:
+    """A result page as Python rows, under a `to_rows` span of the owner's
+    tracer (Coordinator, the fast path, Engine.query): `d2h_arrays` arrays
+    asked of the device in one `jax.device_get`, which takes `fetch_ms` of
+    the span; the rest is numpy and Python values."""
+    with tracer.span("to_rows", d2h_bytes=page.nbytes) as span:
+        t0 = time.perf_counter()
+        fetched = page._fetch_host()
+        span.attributes["fetch_ms"] = (time.perf_counter() - t0) * 1e3
+        span.attributes["d2h_arrays"] = 1 + sum(
+            a is not None for c in page.columns for a in (c.data, c.valid, c.data2))
+        rows = page.to_pylist(fetched)
+        span.attributes["rows"] = len(rows)
+    return rows
 
 
 def _make_call(plan: PlanNode, caps: dict[int, int], collect: bool):

@@ -37,7 +37,7 @@ from typing import Optional
 
 from ..connectors.spi import CatalogManager
 from ..data.page import Page
-from ..exec.compiler import LocalExecutor
+from ..exec.compiler import LocalExecutor, page_rows
 from ..exec.resident import ResidentStore
 from ..plan.distribute import distribute
 from ..plan.fragmenter import Fragment, fragment_plan
@@ -2027,10 +2027,12 @@ class Coordinator:
         if isinstance(record["sql"], str):
             from ..sql import statements as S
 
-            try:
-                stmt = S.parse_statement(record["sql"])
-            except Exception:
-                stmt = None  # let the query path report the syntax error
+            with self.tracer.span("parse", sql_bytes=len(record["sql"])) as span:
+                try:
+                    stmt = S.parse_statement(record["sql"])
+                except Exception:
+                    stmt = None  # let the query path report the syntax error
+                span.attributes["statement"] = type(stmt).__name__
             if stmt is not None and not isinstance(stmt, S.QueryStmt):
                 try:
                     sm.transition("PLANNING")
@@ -2084,7 +2086,7 @@ class Coordinator:
                         record["addedPrepare"] = {stmt.name: stmt.sql}
                     elif isinstance(stmt, S.Deallocate):
                         record["deallocatedPrepare"] = [stmt.name]
-                    sm.transition("FINISHED")
+                    self._commit(record, None)
                 except InjectedCommitCrash:
                     # simulated hard death at a write-phase boundary: die
                     # exactly like kill() mid-statement — no abort, no
@@ -2125,8 +2127,7 @@ class Coordinator:
                 try:
                     sm.transition("PLANNING")
                     self._run_once(record, attempt)
-                    self._result_cache_commit(record, cs)
-                    sm.transition("FINISHED")
+                    self._commit(record, cs)
                     return
                 except Exception as e:
                     if self._killed:
@@ -2142,8 +2143,7 @@ class Coordinator:
                         record["cancel"] = False
                         try:
                             self._requeue_out_of_core(record)
-                            self._result_cache_commit(record, cs)
-                            sm.transition("FINISHED")
+                            self._commit(record, cs)
                             return
                         except Exception as e2:
                             traceback.print_exc()
@@ -2259,6 +2259,16 @@ class Coordinator:
             # leader failed or timed out: execute ourselves, lead nothing
         cache.count("miss")
         return cs
+
+    def _commit(self, record: dict, cs) -> None:
+        """What ends a statement that ran: the result cache's part, then the
+        terminal transition (its listeners and the event a held poll waits
+        for) — the last thing between a finished answer and its client."""
+        sm: QueryStateMachine = record["sm"]
+        with self.tracer.span("commit") as span:
+            self._result_cache_commit(record, cs)
+            sm.transition("FINISHED")
+            span.attributes["state"] = sm.state
 
     def _result_cache_commit(self, record: dict, cs) -> None:
         """After a successful execution: attach the cache disposition (and
@@ -2803,7 +2813,7 @@ class Coordinator:
                 )
 
         try:
-            t_schedule = time.perf_counter()
+            t_schedule, cpu_schedule = time.perf_counter(), self.tracer.cpu_now()
             non_result = [f for f in fragments if f.output_kind != "result"]
             if phased:
                 # PHASED with overlap (reference: scheduler/policy/
@@ -2907,7 +2917,8 @@ class Coordinator:
             # the non-result stages posted (phased: run) and the root's
             # inputs collected; with one worker there is neither
             self.tracer.record(
-                "schedule", t_schedule, stages=len(non_result),
+                "schedule", t_schedule, cpu_start_s=cpu_schedule,
+                stages=len(non_result),
                 tasks=len(all_tasks),
             )
             sm.transition("FINISHING")
@@ -2937,9 +2948,7 @@ class Coordinator:
                             ] = round(s["ms"], 3)
                 else:
                     page = executor.execute(root.root, remote_pages)
-            with self.tracer.span("to_rows", d2h_bytes=page.nbytes) as span:
-                record["result"] = page.to_pylist()
-                span.attributes["rows"] = len(record["result"])
+            record["result"] = page_rows(self.tracer, page)
             with self.tracer.span("query_info"):
                 # stats are pulled from the workers BEFORE cleanup deletes
                 # the tasks; a stats failure must never fail a finished query
@@ -2975,9 +2984,10 @@ class Coordinator:
                         traceback.print_exc()
         finally:
             if not self._killed:
-                self._cleanup_tasks(all_tasks)
-                if spool is not None:  # committed output dies with the query
-                    spool.remove_query(sm.query_id)
+                with self.tracer.span("cleanup", tasks=len(all_tasks)):
+                    self._cleanup_tasks(all_tasks)
+                    if spool is not None:  # committed output dies with the query
+                        spool.remove_query(sm.query_id)
             # on kill: leave tasks and spool dirs exactly where the crash
             # found them — the restarted coordinator resumes from them
 
@@ -3941,8 +3951,7 @@ def _make_handler(coord: Coordinator):
         def log_message(self, *args):
             pass
 
-        def _send_json(self, code: int, obj, headers=None) -> int:
-            body = json.dumps(obj, default=_json_default).encode()
+        def _send_body(self, code: int, body: bytes, headers=None) -> None:
             self.send_response(code)
             self.send_header("Content-Type", "application/json")
             self.send_header("Content-Length", str(len(body)))
@@ -3950,10 +3959,14 @@ def _make_handler(coord: Coordinator):
                 self.send_header(k, v)
             self.end_headers()
             self.wfile.write(body)
+
+        def _send_json(self, code: int, obj, headers=None) -> int:
+            body = json.dumps(obj, default=_json_default).encode()
+            self._send_body(code, body, headers)
             return len(body)
 
         def do_POST(self):
-            t_http = time.perf_counter()
+            t_http, cpu_http = time.perf_counter(), coord.tracer.cpu_now()
             n = int(self.headers.get("Content-Length", 0))
             body = self.rfile.read(n)
             parts = self.path.strip("/").split("/")
@@ -4020,20 +4033,24 @@ def _make_handler(coord: Coordinator):
                         if prepared is None:
                             prepared = {}
                         prepared[unquote(name)] = unquote(enc)
+                t_admit = time.perf_counter()
                 qid = coord.submit_query(
                     sql, spooled=spooled, prepared=prepared,
                     # router-minted id (fleet sharding); absent on direct
                     # client submits
                     query_id=self.headers.get("X-Trino-Query-Id") or None,
                 )
+                admit_ms = (time.perf_counter() - t_admit) * 1e3
                 self._send_json(
                     200,
                     {"id": qid, "nextUri": f"{coord.url}/v1/statement/{qid}/0"},
                 )
                 # a root of this handler thread: admission of one statement,
-                # from the request's first byte read to the answer written
+                # from the request's first byte read to the answer written.
+                # admit_ms: `submit_query`, the query thread's start included
                 coord.tracer.record(
-                    "http.post", t_http, query_id=qid, body_bytes=n
+                    "http.post", t_http, cpu_start_s=cpu_http, query_id=qid,
+                    body_bytes=n, admit_ms=admit_ms,
                 )
                 return
             if (
@@ -4454,7 +4471,7 @@ def _make_handler(coord: Coordinator):
                 )
             if parts[:2] == ["v1", "statement"] and len(parts) >= 4:
                 qid = parts[2]
-                t_http = time.perf_counter()
+                t_http, cpu_http = time.perf_counter(), coord.tracer.cpu_now()
                 with coord._lock:
                     record = coord.queries.get(qid)
 
@@ -4465,14 +4482,23 @@ def _make_handler(coord: Coordinator):
                     # included.  `served`: this poll carried the answer;
                     # held_ms: how long the handler waited for the query;
                     # since_finished_ms: how long the finished answer had
-                    # lain when the handler began to answer
-                    n = self._send_json(code, obj)
+                    # lain when the handler began to answer; encode_ms and
+                    # write_ms: `json.dumps`, then headers and body onto the
+                    # socket — the span's last two stretches, end to end
+                    t_encode = time.perf_counter()
+                    body = json.dumps(obj, default=_json_default).encode()
+                    t_write = time.perf_counter()
+                    self._send_body(code, body)
+                    t_end = time.perf_counter()
                     done_pc = record["sm"].finished_pc if record else None
                     coord.tracer.record(
-                        "http.get", t_http, query_id=qid, served=served,
-                        body_bytes=n, held_ms=(t_answer - t_http) * 1e3,
+                        "http.get", t_http, t_end, cpu_start_s=cpu_http,
+                        query_id=qid, served=served, body_bytes=len(body),
+                        held_ms=(t_answer - t_http) * 1e3,
                         since_finished_ms=None if done_pc is None
                         else (t_answer - done_pc) * 1e3,
+                        encode_ms=(t_write - t_encode) * 1e3,
+                        write_ms=(t_end - t_write) * 1e3,
                     )
 
                 if record is None:

@@ -20,7 +20,7 @@ import numpy as np
 
 from ..connectors.spi import CatalogManager, ColumnSchema, Connector
 from ..data.page import Page
-from ..exec.compiler import LocalExecutor
+from ..exec.compiler import LocalExecutor, page_rows
 from ..exec.resident import ResidentStore
 from ..plan.nodes import PlanNode, TableScan, format_plan
 from ..plan.planner import Planner
@@ -291,9 +291,7 @@ class Engine:
         try:
             with self.tracer.span("query", query_id=qid):
                 page = self.execute_page(sql)
-                with self.tracer.span("to_rows", d2h_bytes=page.nbytes) as span:
-                    rows = page.to_pylist()
-                    span.attributes["rows"] = len(rows)
+                rows = page_rows(self.tracer, page)
                 self.tracer.annotate(rows=len(rows))
         except Exception as e:
             self.events.fire(

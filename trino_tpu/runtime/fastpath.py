@@ -58,6 +58,7 @@ from typing import Optional
 
 import numpy as np
 
+from ..exec.compiler import page_rows
 from ..utils.metrics import GLOBAL as _METRICS
 
 __all__ = ["FastPath", "NotFastpath", "PLAN_CACHE_EVENTS", "EXECUTE_BATCH"]
@@ -392,9 +393,11 @@ class FastPath:
             span.attributes.update(
                 preplanned=info.cache == "hit", plan_cache=info.cache
             )
-        self.last_columns = list(entry.output_names)
-        params = self._param_values(entry.slots, slots)
-        window_s = float(eng.session.get("execute_batch_window_ms") or 0.0) / 1e3
+        # `bind`: this request's bindings as the cached plan's typed scalars
+        with eng.tracer.span("bind", params=len(slots)):
+            self.last_columns = list(entry.output_names)
+            params = self._param_values(entry.slots, slots)
+            window_s = float(eng.session.get("execute_batch_window_ms") or 0.0) / 1e3
         if window_s > 0.0 and not analyze:
             return self._submit_batched(entry, params, window_s)
         # the coordinator runs the whole plan itself, as it runs a text
@@ -402,10 +405,7 @@ class FastPath:
         on_coordinator = getattr(eng, "_coord", None) is not None
         with eng.tracer.span("root_fragment" if on_coordinator else "execute"):
             page = self._executor().execute(entry.plan, params=params)
-        with eng.tracer.span("to_rows", d2h_bytes=page.nbytes) as span:
-            rows = page.to_pylist()
-            span.attributes["rows"] = len(rows)
-        return rows
+        return page_rows(eng.tracer, page)
 
     # ------------------------------------------------- shared query batching
     def _submit_batched(self, entry: _PlanEntry, params, window_s: float):

@@ -22,10 +22,28 @@ spans (and only spans) may be laid against device ops.  The phase ledger
 (runtime/statemachine.py, QueryStateMachine.phase_seconds) and the history
 records stamp `time.time()`: another clock, good for durations and for
 telling a human when, never for alignment with a span or a device event.
+
+The CPU clock: beside perf_counter a `with` span reads `time.thread_time()`
+on entry and on exit and writes `attributes["cpu_ms"]`: the CPU time of the
+thread that OPENED the span (a span opens and closes on one thread), user
+and system, whatever else the process ran meanwhile.  A span's wall length
+minus its `cpu_ms` is how long its thread was not running: waiting for the
+GIL, the device, a socket or an event.  `record` writes `cpu_ms` only when
+handed `cpu_start_s`, the recording thread's `Tracer.cpu_now()` at the
+interval's start — for intervals that begin and end on the thread that
+records them (`http.post`, `http.get`, `compile`); without it the span has
+no CPU clock (`queued` begins on the handler's thread and ends on the
+query's).  The clock is read only where it is fit to be read twice a span
+(`_cpu_clock`): on Linux a read is a system call of a quarter of a
+microsecond from a nanosecond counter, but a sandboxed kernel may serve it
+in 6 us from a counter that ticks every 10 ms (the chip tool's machines:
+PERF.md section 6, PR 37: 40 reads a request were +4% of a request and every
+`cpu_ms` read 0).  There `cpu_now()` is None and no span carries `cpu_ms`.
 """
 
 from __future__ import annotations
 
+import functools
 import json
 import os
 import random
@@ -67,6 +85,32 @@ def _new_span_id() -> str:
         return f"{_ids.getrandbits(64):016x}"
 
 
+@functools.cache
+def _cpu_clock() -> Optional[Callable[[], float]]:
+    """`time.thread_time` where this host's thread CPU clock is fit to be
+    read twice a span, else None; measured once a process, in about a
+    millisecond.  Fit: the cheapest of a few batches of reads costs under
+    2 us a read, and the clock has moved by at least a quarter of the 0.4 ms
+    this thread then spins (another thread may take the core meanwhile: the
+    best of three)."""
+    read, now = time.thread_time, time.perf_counter
+    cost = float("inf")
+    for _ in range(5):
+        t0 = now()
+        for _ in range(8):
+            read()
+        cost = min(cost, (now() - t0) / 8)
+    if cost > 2e-6:
+        return None
+    for _ in range(3):
+        cpu0, until = read(), now() + 0.4e-3
+        while now() < until:
+            pass
+        if read() - cpu0 > 0.1e-3:
+            return read
+    return None
+
+
 @dataclass
 class Span:
     name: str
@@ -81,14 +125,6 @@ class Span:
     @property
     def duration_ms(self) -> float:
         return (self.end_s - self.start_s) * 1e3
-
-    def to_dict(self) -> dict:
-        return {
-            "name": self.name,
-            "attributes": dict(self.attributes),
-            "duration_ms": round(self.duration_ms, 3),
-            "children": [c.to_dict() for c in self.children],
-        }
 
     def to_export_dict(self) -> dict:
         """Wire/export form: trace identity at EVERY level, not just the
@@ -153,6 +189,12 @@ class Tracer:
         self._ctx = _Ctx()
         self._exporters: list[Callable[[Span], None]] = []
         self._lock = threading.Lock()
+        self._cpu = _cpu_clock()  # None: this host's is not fit to be read
+
+    def cpu_now(self) -> Optional[float]:
+        """This thread's CPU clock, for `record`'s `cpu_start_s`; None where
+        the host's clock is not fit to be read (`_cpu_clock`)."""
+        return self._cpu() if self._cpu is not None else None
 
     def add_exporter(self, exporter: Callable[[Span], None]) -> None:
         with self._lock:
@@ -170,15 +212,21 @@ class Tracer:
             cur.attributes.update(attributes)
 
     def record(self, name: str, start_s: float,
-               end_s: Optional[float] = None, **attributes) -> Span:
+               end_s: Optional[float] = None,
+               cpu_start_s: Optional[float] = None, **attributes) -> Span:
         """Add a FINISHED span with explicit perf_counter times (end_s None
         == now) as a child of this thread's current span, or export it as a
         root when none is open.  For intervals a `with` cannot bracket: one
         that began on another thread (`queued`: admitted by the HTTP handler,
-        started by the query thread) or whose code is not one block."""
+        started by the query thread) or whose code is not one block.
+        `cpu_start_s`: this thread's `cpu_now()` at `start_s`, for an
+        interval that began on the thread that records it; the span then
+        carries `cpu_ms` up to now."""
         span = Span(name, dict(attributes), start_s,
                     time.perf_counter() if end_s is None else end_s,
                     span_id=_new_span_id())
+        if cpu_start_s is not None:
+            span.attributes["cpu_ms"] = (self._cpu() - cpu_start_s) * 1e3
         parent = self.current()
         if parent is None:
             span.trace_id = _new_trace_id()
@@ -215,6 +263,7 @@ class _SpanCm:
         self.span = Span(name, dict(attributes))
 
     def __enter__(self) -> Span:
+        self._cpu_s = self.tracer.cpu_now()
         self.span.start_s = time.perf_counter()
         ctx = self.tracer._ctx
         stack = ctx.stack
@@ -235,6 +284,8 @@ class _SpanCm:
 
     def __exit__(self, exc_type, exc, tb) -> None:
         self.span.end_s = time.perf_counter()
+        if self._cpu_s is not None:
+            self.span.attributes["cpu_ms"] = (self.tracer._cpu() - self._cpu_s) * 1e3
         if exc is not None:
             self.span.attributes["error"] = repr(exc)
         stack = self.tracer._ctx.stack
