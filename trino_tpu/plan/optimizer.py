@@ -10,32 +10,57 @@ rewrites.  Current passes:
   (reference: PruneUnreferencedOutputs / PruneTableScanColumns rules).
   Matters doubly on TPU: narrower pages mean fewer HBM-resident arrays
   gathered through every join.
+- push_filters: predicates sink to the smallest subtree that covers their
+  columns, and so do the joins that ARE predicates: a filtering join (semi /
+  anti / null_anti: IN, EXISTS, NOT EXISTS, NOT IN) tests one row of its
+  left input at a time against a set that does not depend on that row's
+  neighbours, so it commutes with an inner join over its left input exactly
+  as `x > 5` does.  The planner lays it over the whole FROM clause; here it
+  sinks, subquery plan and all, to the input that makes its key, wherever
+  plan/stats.py estimates that input no larger than the one it stood on
+  (TPC-H q18: from a 6M-row three-way join down to orders — every join above
+  then runs on the ~60 orders that pass; reference:
+  PredicatePushDown.java's semi-join handling).  Needs catalogs.
 - reorder_joins (plan/reorder.py): Selinger-style cost-based join order
   over connected inner-equi-join regions (reference: ReorderJoins.java,
   EliminateCrossJoins.java); needs catalogs for stats, so it only runs
-  when the caller passes them.
+  when the caller passes them.  A sunk filtering join is a leaf of its
+  region, estimated as the filter it is.
 """
 
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional
+from typing import NamedTuple, Optional
 
-from .ir import FieldRef, IrExpr, field_refs, remap
+from ..utils.metrics import GLOBAL as _METRICS
+from .ir import FieldRef, IrExpr, field_refs, remap, substitute
 from .nodes import (
     Aggregate, AggCall, Concat, Distinct, EnforceSingleRow, Filter, Join,
     Limit, PlanNode, Project, Sort, SortKey, TableScan, TopN, Unnest, Values,
     Window, WindowCall,
 )
 
-__all__ = ["optimize", "prune_columns"]
+__all__ = ["optimize", "prune_columns", "SEMI_JOINS_SUNK"]
+
+# joins that filter their left input and add nothing to it (mark joins add a
+# column: they are projections, and stay where the planner put them)
+_FILTERING = ("semi", "anti", "null_anti")
+
+SEMI_JOINS_SUNK = _METRICS.counter(
+    "trino_tpu_plan_semi_join_sunk_total",
+    "Filtering joins (IN / EXISTS / NOT IN / NOT EXISTS) that the optimizer"
+    " sank below a join over their left input",
+    ("kind",),
+)
 
 
 def optimize(plan: PlanNode, catalogs=None, session=None) -> PlanNode:
     # push filters first: reorder's cost model reads relation stats AFTER
     # their local predicates (a filter stuck above the join region would make
-    # every order look cost-equal)
-    plan = push_filters(plan)
+    # every order look cost-equal) — and after the filtering joins that sank
+    # with them, each a leaf of the region it landed in
+    plan = push_filters(plan, catalogs)
     reorder_on = (
         session is None or session.get("join_reordering_strategy") == "AUTOMATIC"
     )
@@ -59,10 +84,30 @@ def optimize(plan: PlanNode, catalogs=None, session=None) -> PlanNode:
 # the executor shrinks the tier (exec/compiler.py) — the one extra
 # 2-operand sort then pays for itself because EVERY downstream
 # sort/join/aggregation runs at the collapsed capacity (TPC-H q18: the
-# semi-joined lineitem frame is 6M lanes with ~500 live rows; stats
-# cannot see HAVING selectivity, the runtime can).  Reference analogue:
-# AdaptivePlanner re-optimizing from runtime stats.
+# semi-joined orders frame — the semi join sinks there, push_filters — is
+# 1.5M lanes with 63 live rows at SF1, and both inner joins above it run in
+# 2,048-lane frames; stats cannot see HAVING selectivity, the runtime
+# can).  Reference analogue: AdaptivePlanner re-optimizing from runtime
+# stats.
 _COMPACT_MIN_SRC = 65536
+
+
+def _row_estimates(catalogs):
+    """-> rows(node): plan/stats.py's row estimate, kept per node.
+    estimate() is unmemoized by design ("memoization is the caller's
+    concern"); a pass that asks for every node of a plan is O(n^2) in its
+    depth without this."""
+    from .stats import estimate
+
+    memo: dict[PlanNode, float] = {}
+
+    def rows(n: PlanNode) -> float:
+        hit = memo.get(n)
+        if hit is None:
+            hit = memo[n] = estimate(n, catalogs).rows
+        return hit
+
+    return rows
 
 
 def insert_compaction(plan: PlanNode, catalogs) -> PlanNode:
@@ -70,21 +115,14 @@ def insert_compaction(plan: PlanNode, catalogs) -> PlanNode:
     semi/anti membership tests over large frames.  Idempotent: re-running
     over an already-compacted plan adds no second wrapper."""
     from .nodes import Compact
-    from .stats import estimate
 
-    memo: dict[PlanNode, float] = {}
+    rows = _row_estimates(catalogs)
 
     def child_rows(n: PlanNode) -> float:
-        # estimate() is unmemoized by design ("memoization is the caller's
-        # concern"); without this cache the pass is O(n^2) in plan depth
-        hit = memo.get(n)
-        if hit is None:
-            try:
-                hit = max(estimate(n, catalogs).rows, 1.0)
-            except Exception:
-                hit = 1.0
-            memo[n] = hit
-        return hit
+        try:
+            return max(rows(n), 1.0)
+        except Exception:
+            return 1.0
 
     def visit(node: PlanNode) -> PlanNode:
         if isinstance(node, Compact):
@@ -98,9 +136,7 @@ def insert_compaction(plan: PlanNode, catalogs) -> PlanNode:
         wrap = False
         if isinstance(node, Filter):
             wrap = child_rows(node.child) >= _COMPACT_MIN_SRC
-        elif isinstance(node, Join) and node.kind in (
-            "semi", "anti", "null_anti"
-        ):
+        elif isinstance(node, Join) and node.kind in _FILTERING:
             wrap = child_rows(node.left) >= _COMPACT_MIN_SRC
         if wrap:
             return Compact(node)
@@ -121,14 +157,16 @@ def _replace_kids(node: PlanNode, kids):
     return dataclasses.replace(node, child=kids[0])
 
 
-def push_filters(plan: PlanNode) -> PlanNode:
+def push_filters(plan: PlanNode, catalogs=None) -> PlanNode:
     """Predicate pushdown as a whole-plan pass (reference:
     PredicatePushDown.java / PushPredicateThroughProjectIntoRowNumber etc.):
     WHERE conjuncts written over explicit JOIN ... ON trees sink to the
     smallest subtree covering their column references.  The planner pushes
     single-relation predicates for comma-joins at plan time; this pass covers
-    the explicit-join and post-planning shapes."""
-    from .ir import Call, substitute
+    the explicit-join and post-planning shapes.  With `catalogs`, filtering
+    joins sink after them (`_sink_filtering_joins`): the predicates are at
+    their leaves by then, so the estimates the joins sink by include them."""
+    from .ir import Call
 
     def conjuncts_of(e: IrExpr) -> list[IrExpr]:
         if isinstance(e, Call) and e.op == "and":
@@ -195,7 +233,134 @@ def push_filters(plan: PlanNode) -> PlanNode:
                 node = dataclasses.replace(node, child=children[0])
         return wrap(node, preds)
 
-    return push(plan, [])
+    plan = push(plan, [])
+    if catalogs is not None:
+        plan = _sink_filtering_joins(plan, catalogs)
+    return plan
+
+
+def _sink_filtering_joins(plan: PlanNode, catalogs) -> PlanNode:
+    """Move every semi / anti / null_anti join down its left input to the
+    input it filters cheapest; SEMI_JOINS_SUNK counts the joins moved.
+
+    Such a join keeps or drops each left row by a function of that row's key
+    fields (and the left fields of its residual) and of the right input as a
+    whole; its output is its left schema.  So it moves wherever a predicate
+    over those fields may move (`push` above), its right subtree untouched:
+
+    - through Project, the keys and the residual's left references rewritten
+      by `substitute` over the projection's expressions (IR is pure);
+    - through Filter, Sort, Distinct, Compact and other filtering joins, and
+      through the left of a mark join: filters of one row set commute;
+    - into the left or the right of an `inner` join, whichever makes every
+      referenced field (right: indices less the left width); into the left of
+      a `cross` join (its right is one row) and the preserved (left) side of
+      a `left` join — never a null-extended side, whose rows the join above
+      would bring back as NULLs;
+    - not through Aggregate, Window, Limit, TopN, Unnest, Concat,
+      EnforceSingleRow: they change the row set, a filter below is another
+      query.  A join whose fields span both sides of a join stays above it.
+
+    Where it may lose is under a join far more selective than it (TPC-H q16:
+    partsupp's 800k rows against their join with a filtered part; q21: 6M
+    lineitems against the 74k that survive three joins), so it adapts by the
+    one thing it can observe: of the inputs on its way down it takes the one
+    plan/stats.py estimates smallest, the deepest of equals (an FK->PK join
+    estimates as its FK side: below it every join runs on the filtered
+    rows), and only if the way there crosses a join — under Projects and
+    Filters alone there is nothing to gain."""
+    rows = _row_estimates(catalogs)
+
+    def visit(node: PlanNode) -> PlanNode:
+        kids = node.children
+        if kids:
+            new_kids = tuple(visit(c) for c in kids)
+            if new_kids != kids:
+                node = _replace_kids(node, new_kids)
+        if isinstance(node, Join) and node.kind in _FILTERING:
+            moved = _sink(node, rows)
+            if moved is not None:
+                SEMI_JOINS_SUNK.labels(node.kind).inc()
+                return moved
+        return node
+
+    return visit(plan)
+
+
+class _Stand(NamedTuple):
+    """An input a filtering join may stand on, on its way down."""
+
+    node: PlanNode
+    keys: tuple[IrExpr, ...]  # the join's left keys over `node`'s output
+    residual: Optional[IrExpr]  # its residual over `node`'s output ++ right
+    slot: Optional[str]  # the field of the stand above that holds `node`
+    crossed: bool  # a join lies between the first stand and this one
+
+
+def _step_down(node: PlanNode, refs: set[int]):
+    """One step of a filter over fields `refs` of `node`'s output into a
+    child: (the child's slot, the child, each referenced field as an
+    expression over the child's output, whether the step crosses a join), or
+    None where the filter has to stay above `node`."""
+    from .nodes import Compact
+
+    def same(base: int = 0) -> dict[int, IrExpr]:
+        return {i: FieldRef(i - base, node.output_types[i]) for i in refs}
+
+    if isinstance(node, Project):
+        return "child", node.child, {i: node.expressions[i] for i in refs}, False
+    if isinstance(node, (Filter, Sort, Distinct, Compact)):
+        return "child", node.child, same(), False
+    if isinstance(node, Join) and refs:
+        nl = len(node.left.output_types)
+        if all(i < nl for i in refs):
+            if node.kind in _FILTERING + ("mark", "mark_in"):
+                return "left", node.left, same(), False
+            if node.kind in ("inner", "cross", "left"):
+                return "left", node.left, same(), True
+        elif node.kind == "inner" and all(i >= nl for i in refs):
+            return "right", node.right, same(nl), True
+    return None
+
+
+def _sink(join: Join, rows) -> Optional[PlanNode]:
+    """`join` on the input of its left subtree that `rows` estimates
+    smallest (see `_sink_filtering_joins`), or None if it stands there."""
+    right_types = join.right.output_types
+    way = [_Stand(join.left, join.left_keys, join.residual, None, False)]
+    while True:
+        at = way[-1]
+        nl = len(at.node.output_types)
+        refs: set[int] = set()
+        for k in at.keys:
+            refs |= field_refs(k)
+        if at.residual is not None:
+            refs |= {i for i in field_refs(at.residual) if i < nl}
+        step = _step_down(at.node, refs)
+        if step is None:
+            break
+        slot, child, exprs, crosses = step
+        residual = at.residual
+        if residual is not None:
+            # the residual reads left ++ right: its right half follows the
+            # left input's new width
+            new_nl = len(child.output_types)
+            both = dict(exprs)
+            for j, t in enumerate(right_types):
+                both[nl + j] = FieldRef(new_nl + j, t)
+            residual = substitute(residual, both)
+        keys = tuple(substitute(k, exprs) for k in at.keys)
+        way.append(_Stand(child, keys, residual, slot, at.crossed or crosses))
+    best = min(range(len(way)), key=lambda i: (rows(way[i].node), -i))
+    if not way[best].crossed:
+        return None
+    at = way[best]
+    new: PlanNode = dataclasses.replace(
+        join, left=at.node, left_keys=at.keys, residual=at.residual
+    )
+    for i in range(best, 0, -1):
+        new = dataclasses.replace(way[i - 1].node, **{way[i].slot: new})
+    return new
 
 
 def prune_columns(plan: PlanNode) -> PlanNode:

@@ -13,7 +13,12 @@ left-deep with a restoring projection on top.
 
 Only inner joins reorder (outer/semi join order is semantics-bearing), and
 only along connected edges (a reorder never introduces a cross product the
-author didn't write).
+author didn't write).  A filtering semi / anti join that push_filters sank
+into the region (plan/optimizer.py `_sink_filtering_joins`; it runs first)
+is a LEAF of it, like a relation under its Filter: it travels with the
+relation it stands on, and the orders are costed with its estimate (half
+its input, plan/stats.py) — TPC-H q18's region is lineitem, customer and
+the semi-joined orders.
 """
 
 from __future__ import annotations
