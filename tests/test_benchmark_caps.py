@@ -14,6 +14,17 @@ the cell does and asks for their keys among the shipped entries.
 Planning at SF1 asks the connector for column statistics only (the first time
 that generates a few columns into the connector's column directory, seconds);
 no table is loaded and nothing runs.
+
+The SF10 configuration (`tpch_sf10_embedded`, PR 40) is planned from
+statistics too, but no test may generate SF10's columns to have them (three
+tables' first passes: ~90 s and 6 GB in the sandbox).  So the case plans from
+the statistics the connector computed from those columns once, recorded in
+`tests/data/tpch_sf10_planned_stats.json` (eight columns and three row counts):
+milliseconds, on any machine.  A statistic planning asks for that is not
+recorded fails the case — on a fresh machine that column would be generated,
+which is the other thing PR 40 mended.  Where SF10's column files are on the
+machine (whoever learns the tiers anew has them) the recording is held to
+them; elsewhere that one case skips.
 """
 
 import glob
@@ -35,18 +46,69 @@ def _load(*parts):
         return json.load(f)
 
 
-@pytest.fixture(scope="module")
-def embedded_engine():
+# the configurations whose cells send `joins_text_1stream` through Engine()
+EMBEDDED = ["tpch_sf1_embedded", "tpch_sf10_embedded"]
+# from this scale on a test does not generate columns: it plans from a recording
+_BIG = 2.0
+_RECORDED = os.path.join(os.path.dirname(__file__), "data", "tpch_sf10_planned_stats.json")
+
+
+def _recorded_stats(scale: float) -> dict:
+    """table -> TableStats from the recording; a column it does not hold is
+    one planning must not ask of (the connector would generate it)."""
+    from trino_tpu.connectors.spi import ColumnStats, LazyStats, TableStats
+    from trino_tpu.connectors.tpch.generator import TPCH_SCHEMAS
+
+    recorded = _load(_RECORDED)
+    assert recorded["scale_factor"] == scale
+
+    def table(name: str, t: dict) -> TableStats:
+        def column(c: str) -> ColumnStats:
+            assert c in t["columns"], (
+                f"planning asked a statistic of {name}.{c}, which {_RECORDED} does not"
+                " hold: with an empty column directory the connector generates that"
+                " column (at SF10 a pass over the table).  If a statement names it,"
+                " record it; if none does, something walks the relation's statistics whole")
+            return ColumnStats(**t["columns"][c])
+
+        return TableStats(float(t["rows"]), LazyStats([c for c, _t in TPCH_SCHEMAS[name]], column))
+
+    return {name: table(name, t) for name, t in recorded["tables"].items()}
+
+
+def _columns_on_this_machine(scale: float) -> bool:
+    from trino_tpu.connectors.tpch import columns
+
+    return all(
+        os.path.exists(os.path.join(columns._folder(table, scale), c + ".npy"))
+        for table, t in _load(_RECORDED)["tables"].items() for c in t["columns"])
+
+
+@pytest.fixture(scope="module", params=EMBEDDED)
+def embedded_engine(request):
     """`benchmarks/entries/embedded.py` `Entry.__init__`, less the device."""
-    from trino_tpu.connectors.tpch import TpchConnector
+    from trino_tpu.connectors import tpch
     from trino_tpu.runtime.engine import Engine
 
-    config = _load("configs", "tpch_sf1_embedded.json")
+    config = _load("configs", f"{request.param}.json")
+    scale = float(config["scale_factor"])
+    mp = pytest.MonkeyPatch()
+    if scale >= _BIG:
+        # the connector's statistics and row counts as it keeps them once the
+        # columns have been read: nothing is generated, nothing is opened
+        stats = _recorded_stats(scale)
+        tables = {}
+        for name, s in stats.items():
+            tables[(name, scale)] = t = tpch._Table(name, scale)
+            t._rows = int(s.row_count)
+        mp.setattr(tpch, "_STATS", {(name, scale): s for name, s in stats.items()})
+        mp.setattr(tpch, "_TABLES", tables)
     engine = Engine()
-    engine.register_catalog("tpch", TpchConnector(float(config["scale_factor"])))
+    engine.register_catalog("tpch", tpch.TpchConnector(scale))
     for prop, value in config["session"].items():
         engine.session.set(prop, str(value))
-    return engine
+    yield engine
+    mp.undo()
 
 
 def _shipped_keys() -> dict:
@@ -78,12 +140,33 @@ def test_embedded_statement_finds_its_shipped_capacities(embedded_engine, name):
     key = _key(plan, _scan_stand_ins(embedded_engine, plan))
     shipped = _shipped_keys()
     assert key in shipped, (
-        f"{name}'s plan at SF1 has the capacities' key {key}; benchmarks/caps/"
+        f"{name}'s plan at scale {embedded_engine.catalogs.get('tpch').scale:g} has the"
+        f" capacities' key {key}; benchmarks/caps/"
         f" holds {shipped}.  The plan moved: learn its tiers anew (the"
-        " statement through Engine() at SF1 until an execution builds"
+        " statement through Engine() at that scale until an execution builds"
         " nothing) and add them as a new file under benchmarks/caps/.\n"
         + format_plan(plan)
     )
     # the entry sizes nodes of THIS plan: every id it names is a node's
     entry = _load("caps", shipped[key])["entries"][key]
     assert {int(i) for i in entry} <= set(_node_ids(plan))
+
+
+def test_recorded_sf10_statistics_are_the_columns_own():
+    """The recording the SF10 case plans from, against the connector's own
+    statistics — where SF10's column files are on the machine."""
+    import dataclasses
+
+    from trino_tpu.connectors.tpch import TpchConnector
+
+    recorded = _load(_RECORDED)
+    scale = recorded["scale_factor"]
+    if not _columns_on_this_machine(scale):
+        pytest.skip(f"the TPC-H columns of scale {scale:g} are not on this machine,"
+                    " and reading their statistics would generate them for minutes")
+    connector = TpchConnector(scale)
+    for name, t in recorded["tables"].items():
+        stats = connector.table_stats(name)
+        assert int(stats.row_count) == t["rows"]
+        for c, want in t["columns"].items():
+            assert dataclasses.asdict(stats.columns[c]) == pytest.approx(want), (name, c)

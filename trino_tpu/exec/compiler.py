@@ -541,6 +541,11 @@ class LocalExecutor:
                 if nid in caps and int(req) > caps[nid]
             }
             if not overflow:
+                for nid, cap in caps.items():
+                    if nid in required:
+                        op = type(nodes[nid]).__name__
+                        FRAME_LANES.labels(op).inc(cap)
+                        FRAME_LIVE_ROWS.labels(op).inc(required[nid])
                 self._settle(plan, nodes, inputs, caps, known, required,
                              grown, tighten)
                 # execute wall = everything this call that wasn't compile
@@ -1026,10 +1031,22 @@ class LocalExecutor:
         with self._span("device_wait", signature=sig) as span, \
                 jax.profiler.TraceAnnotation("device_wait"):
             vals = np.asarray(packed)  # ONE device->host transfer
+            required = dict(zip(holder["keys"], vals.tolist()))
             if span is not None:
                 span.attributes["d2h_bytes"] = vals.nbytes
+                # the sizing closes here, where the program says what each
+                # frame held: node -> [tier in lanes, rows live (`need`)];
+                # a need above its tier is the overflow the caller retries
+                names = holder.get("frame_names")
+                if names is None:  # once a program: its caps are in its key
+                    nodes = _node_ids(plan)
+                    names = holder["frame_names"] = {
+                        nid: f"{type(nodes[nid]).__name__}#{nid}" for nid in caps}
+                span.attributes["frames"] = {
+                    name: [caps[nid], required[nid]]
+                    for nid, name in names.items() if nid in required}
+        _note_device_memory()
         self._note_execute(sig, _time.perf_counter() - t0)
-        required = dict(zip(holder["keys"], vals.tolist()))
         return out_page, required
 
     # What a subclass that runs the same plans as another kind of program
@@ -1148,6 +1165,46 @@ _JIT_CACHE_LOOKUPS = _METRICS.counter(
     "Fragment jit-program cache lookups in LocalExecutor._run",
     ("result",),
 )
+
+# Kernel work scales with a sized node's tier (the lanes of its frame), not
+# with the rows live in it: one pair of counters says how full the frames of
+# every converged run were (benchmarks/layer_metrics/frame_fill_share.py
+# reads the same two numbers a node from the `device_wait` span's `frames`).
+FRAME_LANES = _METRICS.counter(
+    "trino_tpu_frame_lanes_total",
+    "Lanes of the capacity tiers that converged executions ran their sized"
+    " plan nodes at, by node kind (Aggregate, Join, Compact, TopN, ...)",
+    ("op",),
+)
+FRAME_LIVE_ROWS = _METRICS.counter(
+    "trino_tpu_frame_live_rows_total",
+    "Rows the compiled program reported live in those frames (each sized"
+    " node's `need`: groups, surviving rows, a join's expansion)",
+    ("op",),
+)
+DEVICE_MEMORY_PEAK = _METRICS.gauge(
+    "trino_tpu_device_memory_peak_bytes",
+    "peak_bytes_in_use of the device's memory_stats(), the largest over the"
+    " local devices, read when a statement's program has run",
+)
+DEVICE_MEMORY_LIMIT = _METRICS.gauge(
+    "trino_tpu_device_memory_limit_bytes",
+    "bytes_limit of the same device's memory_stats()",
+)
+
+
+def _note_device_memory() -> None:
+    """The two gauges from the devices' own counters; a backend that keeps
+    none (the CPU) moves neither."""
+    peak = limit = 0
+    for d in jax.local_devices():
+        stats = d.memory_stats()
+        if stats and int(stats.get("peak_bytes_in_use", 0)) > peak:
+            peak = int(stats["peak_bytes_in_use"])
+            limit = int(stats.get("bytes_limit", 0))
+    if peak:
+        DEVICE_MEMORY_PEAK.set(peak)
+        DEVICE_MEMORY_LIMIT.set(limit)
 
 
 def _has_host_aggs(plan: PlanNode) -> bool:

@@ -117,11 +117,7 @@ def estimate(node: PlanNode, catalogs: CatalogManager) -> PlanStats:
 
     if isinstance(node, Project):
         child = estimate(node.child, catalogs)
-        cols = {}
-        for i, e in enumerate(node.expressions):
-            if isinstance(e, FieldRef) and e.index in child.columns:
-                cols[i] = child.columns[e.index]
-        return PlanStats(child.rows, cols)
+        return PlanStats(child.rows, _passed_through(node.expressions, child))
 
     if isinstance(node, (Exchange, Sort, Window)):
         child = estimate(node.child, catalogs)
@@ -142,11 +138,7 @@ def estimate(node: PlanNode, catalogs: CatalogManager) -> PlanStats:
         if not known:
             groups = max(1.0, 0.1 * child.rows)
         rows = max(1.0, min(child.rows, groups))
-        cols = {}
-        for i, k in enumerate(node.group_keys):
-            if isinstance(k, FieldRef) and k.index in child.columns:
-                cols[i] = child.columns[k.index]
-        return PlanStats(rows, cols)
+        return PlanStats(rows, _passed_through(node.group_keys, child))
 
     if isinstance(node, Distinct):
         child = estimate(node.child, catalogs)
@@ -210,6 +202,20 @@ def estimate(node: PlanNode, catalogs: CatalogManager) -> PlanStats:
         return PlanStats(max(1.0, child.rows * 3.0), child.columns)
 
     return PlanStats(_DEFAULT_ROWS, {})
+
+
+def _passed_through(exprs, child: PlanStats) -> LazyStats:
+    """Statistics of the outputs that are plain references to a column of
+    `child`.  Which outputs have any is known from the keys; a column's are
+    asked of the child when someone asks them of this node — a Project over
+    an unpruned scan names every column of its table, and a connector may
+    have to generate a column to say anything of it."""
+    refs = {
+        i: e.index
+        for i, e in enumerate(exprs)
+        if isinstance(e, FieldRef) and e.index in child.columns
+    }
+    return LazyStats(refs, lambda i: child.columns[refs[i]])
 
 
 def _expr_ndv(e: IrExpr, stats: PlanStats) -> Optional[float]:
