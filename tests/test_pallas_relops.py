@@ -201,6 +201,320 @@ def test_groupby_overflow_inflates_then_sorts():
     assert ("group_by", "fallback") in {(e[0], e[1]) for e in ev}
 
 
+# ------------------------------------------- the sorted group-by by itself
+#
+# What group_aggregate runs above the hash kernel's gate (and for every
+# value-sorted or host-collected aggregate): one sort that carries the
+# aggregated columns, one compaction of the group ends.  Each case forces
+# that path and is held to a plain Python reference over the live rows.
+
+
+def _u128(lo, hi):
+    return int(hi) * (1 << 64) + int(np.uint64(lo))
+
+
+def _cell(cv, i):
+    """Row i of a test column as a Python value (None for NULL)."""
+    if cv.valid is not None and not bool(np.asarray(cv.valid)[i]):
+        return None
+    d = np.asarray(cv.data)[i]
+    if cv.data2 is not None:
+        return _u128(d, np.asarray(cv.data2)[i])
+    if cv.dict is not None:
+        return cv.dict.values[int(d)]
+    return d.item()
+
+
+def _nearest_rank(vals, p):
+    vals = sorted(vals)
+    return vals[int(np.floor(p * (len(vals) - 1) + 0.5))]
+
+
+def _reference_agg(spec, arg, arg2, rows):
+    if spec.fn == "count_star":
+        return len(rows)
+    vals = [v for v in (_cell(arg, i) for i in rows) if v is not None]
+    if spec.fn == "count":
+        return len(set(vals)) if spec.distinct else len(vals)
+    if spec.fn == "array_agg":
+        return tuple(sorted(vals))
+    if spec.fn in ("covar_pop", "corr"):
+        pairs = [(_cell(arg, i), _cell(arg2, i)) for i in rows]
+        pairs = [(y, x) for y, x in pairs if y is not None and x is not None]
+        if not pairs or (spec.fn == "corr" and len(pairs) < 2):
+            return None
+        y, x = (np.asarray(c, np.float64) for c in zip(*pairs))
+        cov = float(np.mean(x * y) - np.mean(x) * np.mean(y))
+        if spec.fn == "covar_pop":
+            return cov
+        den = float(np.std(x) * np.std(y))
+        return cov / den if den > 0 else None
+    if not vals:
+        return None
+    if spec.fn == "sum":
+        return sum(vals)
+    if spec.fn == "percentile":
+        return _nearest_rank(vals, spec.param)
+    return {"min": min, "max": max}[spec.fn](vals)
+
+
+def _reference_groups(keys, args, args2, specs, live):
+    groups: dict = {}
+    for i in np.flatnonzero(np.asarray(live)):
+        groups.setdefault(tuple(_cell(k, i) for k in keys), []).append(i)
+    return [
+        k + tuple(_reference_agg(s, a, a2, rows)
+                  for s, a, a2 in zip(specs, args, args2))
+        for k, rows in groups.items()
+    ]
+
+
+def _decoded_groups(out, keys, args, specs):
+    out_keys, out_aggs, out_live, n_groups = out
+    rows = []
+    for g in np.flatnonzero(np.asarray(out_live)):
+        row = []
+        for (d, v, hi), kv in zip(out_keys, keys):
+            got = ColumnVal(d, v, kv.dict, kv.type, hi)
+            row.append(_cell(got, g))
+        for a, arg, spec in zip(out_aggs, args, specs):
+            if len(a) == 4:  # decimal128: (lo, valid, None, hi)
+                got = ColumnVal(a[0], a[1], None, None, a[3])
+            elif len(a) == 3:  # host-collected: its own dictionary
+                got = ColumnVal(a[0], a[1], a[2])
+            else:
+                d = arg.dict if arg is not None and spec.fn in ("min", "max") else None
+                got = ColumnVal(a[0], a[1], d)
+            v = _cell(got, g)
+            row.append(tuple(sorted(v)) if isinstance(v, tuple) else v)
+        rows.append(tuple(row))
+    return rows, int(np.asarray(n_groups))
+
+
+def _same(a, b):
+    if isinstance(a, float) or isinstance(b, float):
+        return a is not None and b is not None and a == pytest.approx(b, rel=1e-9, abs=1e-9)
+    return a == b
+
+
+def _sorted_case(case):
+    """(keys, args, args2, specs, live, G) of one named case."""
+    from trino_tpu.data.page import Dictionary
+    from trino_tpu.data.types import VARCHAR
+
+    rng = np.random.default_rng(41)
+    n, G = 3000, 1024
+    d38 = DecimalType(38, 2)
+    key = _cv(rng.integers(0, 300, n).astype(np.int32), None, None, INTEGER)
+    arg = _cv(rng.integers(-1000, 1000, n), None, None, BIGINT)
+    live = np.ones(n, bool)
+    keys, args, args2 = [key], [arg], [None]
+    specs = [AggSpec("sum")]
+    if case == "nulls_in_keys_and_args":
+        kvalid = rng.random(n) > 0.1
+        keys = [_cv(np.where(kvalid, np.asarray(key.data), 0), kvalid, None, INTEGER)]
+        args = [_cv(np.asarray(arg.data), rng.random(n) > 0.15, None, BIGINT)] * 3 + [None]
+        specs = [AggSpec("sum"), AggSpec("count"), AggSpec("min"), AggSpec("count_star")]
+    elif case == "dead_lanes_interleaved":
+        live = rng.random(n) > 0.4
+        args, specs = [arg, arg], [AggSpec("sum"), AggSpec("max")]
+    elif case == "all_dead_page":
+        live = np.zeros(n, bool)
+    elif case == "one_group":
+        keys = [_cv(np.full(n, 7, np.int32), None, None, INTEGER)]
+        args, specs = [arg, None], [AggSpec("sum"), AggSpec("count_star")]
+    elif case == "every_row_its_own_group":
+        n = 900
+        keys = [_cv(rng.permutation(n).astype(np.int32), None, None, INTEGER)]
+        args = [_cv(rng.integers(-9, 9, n), None, None, BIGINT)] * 2
+        specs, live = [AggSpec("sum"), AggSpec("min")], np.ones(n, bool)
+    elif case == "more_groups_than_the_frame":
+        keys = [_cv(rng.integers(0, 2500, n).astype(np.int32), None, None, INTEGER)]
+    elif case == "decimal128_key":
+        keys = [_cv(rng.integers(0, 25, n), None, None, DecimalType(38, 0),
+                    data2=rng.integers(-2, 2, n))]
+    elif case == "wide_sum_of_an_int32_past_2_31":
+        # 3,000 rows of ~2^30 into 5 groups: every total passes 2^31
+        keys = [_cv(rng.integers(0, 5, n).astype(np.int32), None, None, INTEGER)]
+        args = [_cv(rng.integers(1 << 29, 1 << 30, n).astype(np.int32),
+                    None, None, DecimalType(9, 2))]
+        specs = [AggSpec("sum", type=d38)]
+    elif case == "wide_sum_of_an_int64_past_2_63":
+        keys = [_cv(rng.integers(0, 5, n).astype(np.int32), None, None, INTEGER)]
+        args = [_cv(rng.integers(-(1 << 61), 1 << 62, n), rng.random(n) > 0.1,
+                    None, DecimalType(18, 2))]
+        specs = [AggSpec("sum", type=d38)]
+    elif case == "wide_sum_of_two_limbs":
+        args = [_cv(rng.integers(-(1 << 62), 1 << 62, n), rng.random(n) > 0.1,
+                    None, d38, data2=rng.integers(-4, 4, n))]
+        specs = [AggSpec("sum", type=d38)]
+    elif case == "min_max_over_a_dictionary":
+        words = np.asarray([f"w{(i * 37) % 50:02d}" for i in range(50)], object)
+        dcol = _cv(rng.integers(0, 50, n).astype(np.int32), rng.random(n) > 0.1,
+                   Dictionary(words), VARCHAR)
+        args, specs = [dcol, dcol], [AggSpec("min"), AggSpec("max")]
+    elif case == "count_distinct":
+        darg = _cv(rng.integers(0, 6, n), rng.random(n) > 0.2, None, BIGINT)
+        args, specs = [darg, arg], [AggSpec("count", distinct=True), AggSpec("sum")]
+        args2 = [None, None]
+    elif case == "two_value_sorted_aggregates":
+        darg = _cv(rng.integers(0, 6, n), rng.random(n) > 0.2, None, BIGINT)
+        args = [darg, arg]
+        specs = [AggSpec("count", distinct=True), AggSpec("percentile", param=0.9)]
+        args2 = [None, None]
+    elif case == "approx_percentile":
+        parg = _cv(rng.normal(0, 10, n), rng.random(n) > 0.1, None, DOUBLE)
+        args, specs = [parg], [AggSpec("percentile", param=0.5)]
+    elif case == "moment_aggregate_with_arg2":
+        y = _cv(rng.normal(0, 3, n), rng.random(n) > 0.1, None, DOUBLE)
+        x = _cv(rng.normal(1, 2, n), None, None, DOUBLE)
+        keys = [_cv(rng.integers(0, 20, n).astype(np.int32), None, None, INTEGER)]
+        args, args2 = [y, y], [x, x]
+        specs = [AggSpec("covar_pop"), AggSpec("corr")]
+    elif case == "host_collected_aggregate":
+        keys = [_cv(rng.integers(0, 20, n).astype(np.int32), None, None, INTEGER)]
+        args, specs = [arg, arg], [AggSpec("array_agg"), AggSpec("sum")]
+        args2 = [None, None]
+    else:
+        raise AssertionError(case)
+    if len(args2) != len(args):
+        args2 = [None] * len(args)
+    return keys, args, args2, specs, live, G
+
+
+_SORTED_CASES = [
+    "nulls_in_keys_and_args", "dead_lanes_interleaved", "all_dead_page",
+    "one_group", "every_row_its_own_group", "more_groups_than_the_frame",
+    "decimal128_key", "wide_sum_of_an_int32_past_2_31",
+    "wide_sum_of_an_int64_past_2_63", "wide_sum_of_two_limbs",
+    "min_max_over_a_dictionary", "count_distinct",
+    "two_value_sorted_aggregates", "approx_percentile",
+    "moment_aggregate_with_arg2", "host_collected_aggregate",
+]
+
+
+@pytest.mark.parametrize("case", [
+    "nulls_in_keys_and_args", "wide_sum_of_an_int64_past_2_63",
+    "decimal128_key", "count_distinct",
+])
+def test_groupby_sorted_path_under_the_kernel_ceiling(case, monkeypatch):
+    """Up to 8,192 groups the chip reduces the sorted rows with the Pallas
+    seg_reduce kernel (here interpreted) and only the keys are read at the
+    group ends: the same answers."""
+    from trino_tpu.ops.pallas import segreduce
+
+    monkeypatch.setattr(segreduce, "INTERPRET", True)
+    test_groupby_sorted_path_matches_reference(case, reducer="pallas")
+
+
+@pytest.mark.parametrize("case", _SORTED_CASES)
+def test_groupby_sorted_path_matches_reference(case, reducer=None):
+    keys, args, args2, specs, live, G = _sorted_case(case)
+    # the gate under the frame: the hash kernel declines, the sort runs
+    kernels.set_policy(kernels.KernelPolicy(
+        enabled=True, interpret=True, hash_agg_max_groups=512))
+    ev = kernels.begin_capture()
+    try:
+        out = relops.group_aggregate(
+            keys, args, specs, jnp.asarray(live), G, agg_args2=args2)
+    finally:
+        kernels.end_capture()
+    (event,) = [e for e in ev if e[0] == "group_by"]
+    assert event[1] in ("fallback", "sort") and "sort carries" in event[2], ev
+    assert {e[1] for e in ev if e[0] == "segment_reduce"} == (
+        {reducer} if reducer else set()), ev
+    got, n_groups = _decoded_groups(out, keys, args, specs)
+    want = _reference_groups(keys, args, args2, specs, live)
+    assert n_groups == len(want)  # the TRUE count, also past the frame
+    assert len(got) == min(len(want), G)
+    want = sorted(want, key=repr)
+    if len(want) > G:
+        # overflow: the executor grows the tier on n_groups; what the frame
+        # holds are whole groups of the page all the same
+        assert set(map(repr, got)) <= set(map(repr, want))
+        return
+    got = sorted(got, key=repr)
+    for g, w in zip(got, want):
+        assert len(g) == len(w) and all(_same(a, b) for a, b in zip(g, w)), (g, w)
+
+
+def _primitives(jaxpr, acc=None):
+    acc = [] if acc is None else acc
+    for e in jaxpr.eqns:
+        acc.append(e)
+        for v in e.params.values():
+            for sub in v if isinstance(v, (list, tuple)) else [v]:
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    _primitives(inner, acc)
+    return acc
+
+
+@pytest.mark.parametrize("nullable_key", [False, True], ids=["key", "nullable-key"])
+def test_groupby_sorted_path_holds_its_budget(nullable_key):
+    """One int32 key, one decimal(38,2) sum, above the gate: at most two
+    sorts (the group sort carrying the argument, the compaction of the group
+    ends carrying the key and the running sum), no scatter, and no gather
+    with n lanes on either side; a validity operand rides only when the
+    column has a mask; the dispatch event says what was carried."""
+    import jax
+
+    n, G = 10_000, 4_096
+    wide = DecimalType(38, 2)
+
+    def run(k, kvalid, q, live):
+        key = ColumnVal(k, kvalid if nullable_key else None, None, INTEGER)
+        arg = ColumnVal(q, None, None, DecimalType(12, 2))
+        return relops.group_aggregate(
+            [key], [arg], [AggSpec("sum", type=wide)], live, G)
+
+    ev = kernels.begin_capture()
+    try:
+        jaxpr = jax.make_jaxpr(run)(
+            jnp.zeros(n, jnp.int32), jnp.ones(n, bool),
+            jnp.zeros(n, jnp.int32), jnp.ones(n, bool))
+    finally:
+        kernels.end_capture()
+    eqns = _primitives(jaxpr.jaxpr)
+    names = [e.primitive.name for e in eqns]
+    sorts = [e for e in eqns if e.primitive.name == "sort"]
+    assert len(sorts) <= 2, names
+    assert not any(name.startswith("scatter") for name in names), names
+    for e in eqns:
+        if e.primitive.name == "gather":
+            assert all(v.aval.size < n for v in e.invars[:2]), e
+    group_sort, ends = sorts
+    # (dead, [key's validity,] key) are keys; the argument rides, at the
+    # width it is resident in; no iota, no validity of the argument
+    dtypes = [str(v.aval.dtype) for v in group_sort.invars]
+    assert dtypes == ["int8"] + ["bool"] * nullable_key + ["int32", "int32"]
+    assert group_sort.params["num_keys"] == 2 + nullable_key
+    # the ends' positions are the key; the key, its validity where it has
+    # one, and ONE running int64 sum ride: 3 (4) words
+    assert ends.params["num_keys"] == 1
+    assert sorted(str(v.aval.dtype) for v in ends.invars) == sorted(
+        ["int32", "int32", "int64"] + ["bool"] * nullable_key)
+    (event,) = [e for e in ev if e[0] == "group_by"]
+    assert event[1] == "fallback"
+    assert event[2] == (
+        f"cap {G} > hash_agg_limit; sort carries 1 cols, "
+        f"ends carry {3 + nullable_key} words")
+
+
+def test_explain_analyze_says_what_the_sorted_groupby_carried(kernel_engine):
+    """q18's subquery aggregation (GROUP BY l_orderkey, far above the gate)
+    under EXPLAIN ANALYZE: the `-- kernel:` line of its dispatch event names
+    the operands of the sort and the words of the compaction."""
+    from tests.tpch_queries import QUERIES
+
+    ex = [str(r[0]) for r in kernel_engine.execute(
+        "EXPLAIN ANALYZE " + QUERIES["q18"])]
+    lines = [l for l in ex if l.startswith("-- kernel:") and " group_by " in l]
+    assert any(
+        "> hash_agg_limit; sort carries 1 cols, ends carry " in l for l in lines
+    ), ex
+
+
 def _compare_join(kind, seed, C=1 << 15):
     rng = np.random.default_rng(seed)
     nl, nr = 2000, 300

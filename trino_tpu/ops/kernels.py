@@ -31,6 +31,10 @@ gate chose the legacy sort path (disabled, unencodable keys, unsupported
 shape, or a non-TPU backend without interpret); "fallback" = the shape was
 kernel-eligible but exceeded the policy's capacity limit
 (hash_agg_kernel_limit / hash_join_kernel_limit), so the sort path ran.
+A group-by that runs on the sort path records its event from there, its
+detail ending in what moved: `cap 16777216 > hash_agg_limit; sort carries 1
+cols, ends carry 3 words` — the operands that rode the group sort beside its
+keys, and the 32-bit words the compaction of the group ends carried.
 A selected kernel still carries a runtime overflow guard — hash-table
 overflow or probe exhaustion divert that execution to the sort path without
 re-counting.

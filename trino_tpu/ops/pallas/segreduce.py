@@ -44,7 +44,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["SegRed", "fused_segment_reduce", "pallas_segreduce_supported"]
+__all__ = [
+    "SegRed", "SortedRuns", "fused_segment_reduce", "pallas_segreduce_supported",
+]
 
 _CHUNK_S = 8  # sublanes per row-chunk
 _CHUNK_L = 128  # lanes per row-chunk
@@ -72,7 +74,9 @@ INTERPRET = False
 class SegRed:
     """One requested reduction over the segmented rows.
 
-    op: 'sum' | 'min' | 'max' | 'count'  ('count' == sum of valid 0/1)
+    op: 'sum' | 'min' | 'max' | 'count'  ('count' == sum of valid 0/1) |
+        'last' (the value in a group's last row: SortedRuns only — how the
+        sorted group-by reads its keys)
     values: [n] array (ignored for 'count' when valid is given)
     valid: optional [n] bool — rows where the argument is non-NULL and live.
     """
@@ -315,14 +319,16 @@ def fused_segment_reduce(
     *,
     interpret: bool = False,
     force_pallas: bool = False,
-    sorted_segments: bool = False,
-    boundaries: Optional[tuple] = None,
+    runs: Optional["SortedRuns"] = None,
 ) -> list[jnp.ndarray]:
     """Compute every requested reduction in one fused pass.
 
     seg: [n] int32 segment ids in [0, num_segments); rows with seg >=
     num_segments (the caller's dead-lane convention) fall into padding
-    groups and are sliced off.
+    groups and are sliced off.  `runs`: the rows are sorted by segment and
+    these are the groups' boundaries (the sort-based group-by) — beyond the
+    kernel's group ceiling the reductions are then read at the group ends
+    (SortedRuns.read) instead of scattered.
 
     Returns one array [num_segments] per red:
       sum of floats  -> float64 (Kahan-compensated on the Pallas path)
@@ -337,14 +343,27 @@ def fused_segment_reduce(
     interpret = interpret or INTERPRET
     use_pallas = force_pallas or interpret or pallas_segreduce_supported(G)
     if not use_pallas:
-        if sorted_segments:
-            # high-cardinality group-by over the sort-based path: rows arrive
-            # ordered by segment, so boundary gathers + cumsum diffs beat the
-            # scatter-based segment ops (XLA scatter serializes on TPU — at
-            # TPC-H SF1 Q3's ~1M groups the scatter fallback cost ~36s of
-            # device time; this path is bandwidth-bound)
-            return _sorted_fallback(seg, reds, G, boundaries)
+        if runs is not None:
+            # high-cardinality group-by over the sort-based path (XLA scatter
+            # serializes on TPU — at TPC-H SF1 Q3's ~1M groups the scatter
+            # fallback cost ~36s of device time)
+            return runs.read(reds, G)
         return _xla_fallback(seg, reds, G)
+
+    kw = dict(interpret=interpret, force_pallas=force_pallas)
+    if runs is not None and any(r.op == "last" for r in reds):
+        # the kernel reduces; what is only read is read where the groups end
+        out: list = [None] * len(reds)
+        read = [i for i, r in enumerate(reds) if r.op == "last"]
+        rest = [i for i, r in enumerate(reds) if r.op != "last"]
+        for i, o in zip(read, runs.read([reds[i] for i in read], G)):
+            out[i] = o
+        if rest:
+            for i, o in zip(
+                rest, fused_segment_reduce(seg, [reds[i] for i in rest], G, **kw)
+            ):
+                out[i] = o
+        return out
 
     if len(reds) > 1 and sum(_planes_for(r) for r in reds) > _MAX_PLANES:
         # every input plane is a double-buffered (64, 128) VMEM block per
@@ -352,7 +371,6 @@ def fused_segment_reduce(
         # 111 int limb planes, 16.1 MB of scoped VMEM against the chip's
         # 16 MB limit.  Split into passes that fit; each reads `seg` again.
         mid = len(reds) // 2
-        kw = dict(interpret=interpret, force_pallas=force_pallas)
         return (
             fused_segment_reduce(seg, reds[:mid], G, **kw)
             + fused_segment_reduce(seg, reds[mid:], G, **kw)
@@ -517,7 +535,8 @@ def fused_segment_reduce(
 
 
 # --------------------------------------------------------------------------
-# XLA fallback (CPU tests / G beyond the one-hot ceiling)
+# Beyond the one-hot ceiling: sorted runs read at their ends, or XLA's
+# segment ops (CPU tests)
 # --------------------------------------------------------------------------
 
 
@@ -536,68 +555,108 @@ def _seg_scan_extreme(vals, flag, is_min):
     return pv
 
 
-def _sorted_fallback(seg, reds, G, boundaries=None):
-    """Segment reductions for NONDECREASING seg (the sort-based group-by's
-    output order): sums/counts via diffs of one inclusive cumsum at segment
-    boundaries, min/max via a segmented associative scan read at segment
-    ends.  Everything is gathers + scans — the shape TPUs like.
-    `boundaries` = precomputed (starts, ends) searchsorted results (the
-    caller shares one boundary pass across key gathers and reductions)."""
-    n = seg.shape[0]
-    seg_c = jnp.minimum(seg.astype(jnp.int32), G)
-    if boundaries is not None:
-        starts, ends = boundaries
-    else:
-        from ..relops import searchsorted_tpu
+class SortedRuns:
+    """Where the groups of a SORTED page stand: every group is one run of
+    adjacent rows, the runs are packed from lane 0 and dead lanes come last
+    (the sort-based group-by's output order).  `start` / `end` flag a group's
+    first and last row, `live` the rows that belong to a group at all;
+    `n_groups` is the true group count.  The boundaries are these flags — no
+    searchsorted asks for them again.  `ends_words` tallies the 32-bit words
+    `read` has carried to the front of a frame (the dispatch event's
+    detail)."""
 
-        gids = jnp.arange(G, dtype=jnp.int32)
-        starts = searchsorted_tpu(seg_c, gids, side="left")
-        ends = searchsorted_tpu(seg_c, gids, side="right")
-    nonempty = ends > starts
-    ends_i = jnp.clip(ends - 1, 0, max(n - 1, 0))
-    flag = (
-        jnp.concatenate([jnp.ones((1,), jnp.bool_), seg_c[1:] != seg_c[:-1]])
-        if n > 0
-        else jnp.ones((0,), jnp.bool_)
-    )
+    def __init__(self, start: jnp.ndarray, live: jnp.ndarray):
+        n = live.shape[0]
+        self.start = start
+        self.live = live
+        self.end = live & jnp.concatenate(
+            [start[1:] | ~live[1:], jnp.ones((min(n, 1),), jnp.bool_)]
+        )
+        self.n_groups = jnp.sum(start.astype(jnp.int32))
+        self.ends_words = 0
 
-    def boundary_sum(acc):
-        ce = jnp.concatenate([jnp.zeros((1,), acc.dtype), jnp.cumsum(acc)])
-        zero = jnp.zeros((), acc.dtype)
-        return jnp.where(nonempty, jnp.take(ce, ends) - jnp.take(ce, starts), zero)
+    def read(self, reds: Sequence[SegRed], G: int) -> list[jnp.ndarray]:
+        """The one sorted-segment reducer: every reduction is a running value
+        over the sorted rows (an inclusive cumsum, exact in int64; a segmented
+        running min/max; a row's own value for 'last') READ WHERE EACH GROUP
+        ENDS, and ONE compaction — a sort on the end lanes' positions with the
+        running values as operands that are not keys — brings the G first
+        ends to the front of the frame.  Sums are then differences between
+        neighbours in the G-lane frame, a group's size the difference of its
+        end positions.  Measured on the v5e (PERF.md section 6, PR 41): the
+        running values ride that sort several times cheaper than a gather of
+        each through a permutation, and the two searchsorted passes this
+        replaces cost two sorts and two scatters of n + G lanes each."""
+        if not reds:
+            return []
+        n = self.end.shape[0]
+        words: list[jnp.ndarray] = []
+        plan: list[tuple] = []
 
-    out = []
-    for r in reds:
-        if r.op == "count":
-            v = (
-                r.valid.astype(jnp.int64)
-                if r.valid is not None
-                else jnp.ones((n,), jnp.int64)
-            )
-            out.append(boundary_sum(v))
-        elif r.op == "sum":
-            vals = r.values
-            if jnp.issubdtype(vals.dtype, jnp.integer) or vals.dtype == jnp.bool_:
-                acc = vals.astype(jnp.int64)
+        def word(arr) -> int:
+            words.append(arr)
+            return len(words) - 1
+
+        for r in reds:
+            if r.op == "last":
+                plan.append(("at", word(r.values), None))
+            elif r.op == "count":
+                if r.valid is None or r.valid is self.live:
+                    plan.append(("size",))
+                else:  # n < 2^31 rows: an int32 running count is exact
+                    c = jnp.cumsum(r.valid.astype(jnp.int32), dtype=jnp.int32)
+                    plan.append(("diff", word(c), jnp.int64))
+            elif r.op == "sum":
+                vals = r.values
+                if jnp.issubdtype(vals.dtype, jnp.integer) or vals.dtype == jnp.bool_:
+                    acc = vals.astype(jnp.int64)
+                else:
+                    acc = vals.astype(jnp.float64)
+                if r.valid is not None:
+                    acc = jnp.where(r.valid, acc, jnp.zeros_like(acc))
+                plan.append(("diff", word(jnp.cumsum(acc)), acc.dtype))
+            elif r.op in ("min", "max"):
+                sel = r.values
+                if jnp.issubdtype(sel.dtype, jnp.floating):
+                    sent = jnp.asarray(jnp.inf if r.op == "min" else -jnp.inf, sel.dtype)
+                else:
+                    info = jnp.iinfo(sel.dtype)
+                    sent = jnp.asarray(info.max if r.op == "min" else info.min, sel.dtype)
+                if r.valid is not None:
+                    sel = jnp.where(r.valid, sel, sent)
+                run = _seg_scan_extreme(sel, self.start, r.op == "min")
+                plan.append(("at", word(run), sent))
             else:
-                acc = vals.astype(jnp.float64)
-            if r.valid is not None:
-                acc = jnp.where(r.valid, acc, jnp.zeros_like(acc))
-            out.append(boundary_sum(acc))
-        elif r.op in ("min", "max"):
-            sel = r.values
-            if jnp.issubdtype(sel.dtype, jnp.floating):
-                sent = jnp.asarray(jnp.inf if r.op == "min" else -jnp.inf, sel.dtype)
+                raise NotImplementedError(r.op)
+
+        self.ends_words += sum(max(1, w.dtype.itemsize // 4) for w in words)
+        # end lanes sort first, by position: the keys among them are unique,
+        # so the sort needs no stability and no iota beside it
+        pos = jnp.arange(n, dtype=jnp.int32)
+        front = jax.lax.sort(
+            [jnp.where(self.end, pos, jnp.int32(n))] + words,
+            num_keys=1, is_stable=False,
+        )
+        front = [
+            f[:G] if n >= G else jnp.pad(f, (0, G - n)) for f in front
+        ]
+        in_frame = jnp.arange(G, dtype=jnp.int32) < jnp.minimum(self.n_groups, G)
+
+        def since_prev(c, first):
+            prev = jnp.concatenate([jnp.full((1,), first, c.dtype), c[:-1]])
+            return jnp.where(in_frame, c - prev, jnp.zeros((), c.dtype))
+
+        out = []
+        for p in plan:
+            if p[0] == "size":
+                out.append(since_prev(front[0], -1).astype(jnp.int64))
+            elif p[0] == "diff":
+                out.append(since_prev(front[1 + p[1]], 0).astype(p[2]))
+            elif p[2] is None:  # a row's own value: dead groups keep garbage
+                out.append(front[1 + p[1]])
             else:
-                info = jnp.iinfo(sel.dtype)
-                sent = jnp.asarray(info.max if r.op == "min" else info.min, sel.dtype)
-            if r.valid is not None:
-                sel = jnp.where(r.valid, sel, sent)
-            run = _seg_scan_extreme(sel, flag, r.op == "min")
-            out.append(jnp.where(nonempty, jnp.take(run, ends_i), sent))
-        else:
-            raise NotImplementedError(r.op)
-    return out
+                out.append(jnp.where(in_frame, front[1 + p[1]], p[2]))
+        return out
 
 
 def _xla_fallback(seg, reds, G):

@@ -4,8 +4,9 @@ These replace the reference's virtual-call operator chain (operator/*.java)
 with whole-page device kernels:
 
 - aggregation: the reference's FlatHash Swiss-table (operator/FlatHash.java:38)
-  becomes a SORT-BASED group-by: lax.sort on the key columns, run-boundary
-  detection, then segment_sum/min/max.  On TPU, a bitonic sort over HBM-
+  becomes a SORT-BASED group-by: lax.sort on the key columns with the
+  aggregated columns riding it, run-boundary detection, then running sums
+  and extremes read where each run ends.  On TPU, a bitonic sort over HBM-
   resident lanes beats scalar hash probing by orders of magnitude, and the
   fixed reduction tree makes float aggregation deterministic (a north-star
   requirement the Java engine itself cannot honor across runs).
@@ -83,33 +84,28 @@ def searchsorted_tpu(a: jnp.ndarray, v: jnp.ndarray, side: str = "left"):
     """jnp.searchsorted with the method picked for TPU: the default binary
     search lowers to log2(n) SEQUENTIAL gather rounds over HBM (~1.8s for
     8M probes into 8M keys — measured; it was the q03/q18 bottleneck), while
-    'sort' does one fused bitonic pass over a++v (~30ms).  Small query sets
-    keep the scan — sorting the whole haystack for a handful of lookups
-    loses."""
+    'sort' is an argsort of the concatenation a++v, a scatter of as many
+    lanes and the same again over v.  That is not cheap either: at
+    60,000,466 + 16,777,216 lanes the v5e read 0.49 s a sort and 0.45 s a
+    scatter, twice a call (ledger, PR 40) — which is why the sorted group-by
+    no longer asks (its boundaries stand as flags: SortedRuns).  Callers
+    left: equi_join's probe of the sorted build side and its expansion,
+    unnest_expand.  Small query sets keep the scan — sorting the whole
+    haystack for a handful of lookups loses."""
     method = "sort" if v.size >= _SEARCHSORTED_SORT_MIN else "scan"
     return jnp.searchsorted(a, v, side=side, method=method)
 
 
-def _segment_sum(
-    values: jnp.ndarray, seg: jnp.ndarray, num: int, sorted_segments: bool = False
-) -> jnp.ndarray:
+def _segment_sum(values: jnp.ndarray, seg: jnp.ndarray, num: int) -> jnp.ndarray:
     """Backend-aware segment sum.  On CPU, XLA's scatter-add is fine.  On
     TPU, scatter serializes — but a one-hot matmul runs on the MXU, which is
     exactly how a TPU wants to aggregate (SURVEY §7: keep the FLOPs where
     the systolic array is).  Used when the segment count is small enough
-    that the [n, G] one-hot is cheap.  For NONDECREASING seg (the sorted
-    group-by's order) large segment counts use boundary cumsum diffs —
-    gathers and scans only, never a big scatter."""
+    that the [n, G] one-hot is cheap."""
     if jax.default_backend() != "cpu" and num <= _MATMUL_SEGMENT_LIMIT:
         if jnp.issubdtype(values.dtype, jnp.integer):
             return _limb_segment_sum(values, seg, num)
         return _chunked_f32_segment_sum(values, seg, num).astype(values.dtype)
-    if sorted_segments and jax.default_backend() != "cpu":
-        from .pallas.segreduce import SegRed, _sorted_fallback
-
-        return _sorted_fallback(seg, [SegRed("sum", values, None)], num)[0].astype(
-            values.dtype
-        )
     return jax.ops.segment_sum(values, seg, num_segments=num)
 
 
@@ -260,8 +256,10 @@ def _hash_aggregate(key_vals, agg_args, specs, live, G, agg_args2):
     assigns every row a dense group id (ops/pallas/hashagg.py), the fused
     segment reductions run over those ids unsorted, and the output key
     columns are decoded from the hash table itself (<= a few thousand
-    entries) — no sort of the input anywhere.  Returns the group_aggregate
-    result tuple, or None when the static gate picks the sort path.
+    entries) — no sort of the input anywhere.  Returns (the group_aggregate
+    result tuple, None), or (None, (impl, why)) when the static gate picks
+    the sort path — which records the dispatch event itself, with what its
+    sort and its compaction carried.
 
     Overflow (more distinct groups than the capacity tier, or probe-budget
     exhaustion) reports an inflated n_groups through the normal required
@@ -274,34 +272,30 @@ def _hash_aggregate(key_vals, agg_args, specs, live, G, agg_args2):
         s.distinct or s.fn in ("percentile", "approx_distinct") or s.fn in HOST_AGGS
         for s in specs
     ):
-        return None  # value-sorted / host aggregates need the sort anyway
+        # value-sorted / host aggregates need the sort anyway
+        return None, ("sort", "value-sorted or host-collected aggregate")
     policy = get_policy()
     if not policy.enabled:
-        record_dispatch("group_by", "sort", "kernels disabled")
-        return None
+        return None, ("sort", "kernels disabled")
     from .pallas import hashagg
 
     n = live.shape[0]
     if G > policy.hash_agg_max_groups:
-        record_dispatch("group_by", "fallback", f"cap {G} > hash_agg_limit")
-        return None
+        return None, ("fallback", f"cap {G} > hash_agg_limit")
     interpret = policy.interpret or hashagg.INTERPRET
     if not interpret and jax.default_backend() != "tpu":
         # decline before encoding: the key-word encode below is real work
         # on the eager/interpreted-fallback execution path
-        record_dispatch("group_by", "sort", "cpu backend")
-        return None
+        return None, ("sort", "cpu backend")
     enc, layout = _hash_key_words(key_vals, n, for_join=False)
     if enc is None:
-        record_dispatch("group_by", "sort", "keys not word-encodable")
-        return None
+        return None, ("sort", "keys not word-encodable")
     validity = jnp.zeros((n,), jnp.int32)
     for k, kv in enumerate(key_vals):
         validity = validity | (_valid_of(kv, n).astype(jnp.int32) << k)
     words = enc + [validity]
     if not hashagg.shape_supported(n, len(words), G):
-        record_dispatch("group_by", "sort", "shape unsupported")
-        return None
+        return None, ("sort", "shape unsupported")
     record_dispatch(
         "group_by", "pallas", f"{len(words)}w cap {G} table {hashagg.table_size(G)}"
     )
@@ -310,9 +304,7 @@ def _hash_aggregate(key_vals, agg_args, specs, live, G, agg_args2):
         words, live, G, interpret=interpret
     )
     seg = jnp.where(live & (gid >= 0) & (gid < G), gid, G).astype(jnp.int32)
-    out_aggs = _fused_aggs(
-        agg_args, specs, None, seg, live, G, n, agg_args2=agg_args2
-    )
+    out_aggs = _fused_aggs(agg_args, specs, seg, live, G, n, agg_args2=agg_args2)
 
     # decode the output key columns from the table entries, ordered by gid
     T = table.shape[1]
@@ -345,7 +337,7 @@ def _hash_aggregate(key_vals, agg_args, specs, live, G, agg_args2):
 
     out_live = jnp.arange(G, dtype=jnp.int32) < jnp.minimum(n_true, G)
     n_report = jnp.where(overflow, jnp.maximum(n_true, jnp.int32(G + 1)), n_true)
-    return out_keys, out_aggs, out_live, n_report
+    return (out_keys, out_aggs, out_live, n_report), None
 
 
 # ------------------------------------------------------------ aggregation
@@ -382,103 +374,279 @@ def group_aggregate(
     if fast is not None:
         return fast
 
-    hashed = _hash_aggregate(key_vals, agg_args, specs, live, G, agg_args2)
+    hashed, declined = _hash_aggregate(key_vals, agg_args, specs, live, G, agg_args2)
     if hashed is not None:
         return hashed
+    return _sorted_aggregate(
+        key_vals, agg_args, specs, live, G, agg_args2, agg_order, declined
+    )
 
-    # ---- sort rows by (dead-last, keys..., [value-sorted agg arg]) --------
+
+class _GroupedSort:
+    """Rows sorted by (dead-last, group keys..., [one value-sorted
+    aggregate's validity and value]) WITH the columns that are aggregated
+    riding the sort as operands that are not keys: nothing is gathered
+    through a permutation afterwards (on the v5e a sort carries a column
+    several times cheaper than a gather of 60M lanes moves it: PERF.md
+    section 6, PR 41).  What rides follows what the trace can see — a
+    validity operand only for a column that has a mask, `iota` only when a
+    caller asks for the permutation itself (`want_perm`: the host-collected
+    aggregates).  `live` needs no operand of its own: the dead flag is the
+    first key, so its sorted form is the sort's own output."""
+
+    def __init__(self, key_vals, live, G, extra=None, carry=(), want_perm=False):
+        n = live.shape[0]
+        keys: list[jnp.ndarray] = [(~live).astype(jnp.int8)]
+        self._key_slots: list[tuple] = []  # per key: (validity slot, operand slots)
+        for kv in key_vals:
+            vslot = None
+            if kv.valid is not None:  # nulls group together (last)
+                vslot = len(keys)
+                keys.append(~kv.valid)
+            ops = _sortable_operands(kv)  # 2 operands for decimal128
+            self._key_slots.append((vslot, range(len(keys), len(keys) + len(ops))))
+            keys.extend(ops)
+        n_group_ops = len(keys)
+        # Validity of `extra` sorts before its value so a NULL lane whose
+        # code equals a live value cannot become the "first occurrence"
+        # (the round-1 COUNT(DISTINCT) advisory bug).
+        extra_vslot = None
+        if extra is not None:
+            if extra.valid is not None:
+                extra_vslot = len(keys)
+                keys.append(~extra.valid)
+            keys.extend(_sortable_operands(extra))
+        by_id: dict = {}  # id(array as the caller holds it) -> operand slot
+        payload: list[jnp.ndarray] = []
+        carry = list(carry)
+        if extra is not None:
+            if extra.data2 is None and extra.dict is None and (
+                extra.data.dtype != jnp.bool_
+            ):  # the key operand is the column itself
+                by_id[id(extra.data)] = len(keys) - 1
+            else:
+                carry.append(extra.data)
+        for arr in carry:
+            if id(arr) not in by_id:
+                by_id[id(arr)] = len(keys) + len(payload)
+                payload.append(arr)
+        if want_perm:
+            payload.append(jnp.arange(n, dtype=jnp.int32))
+        # a group's rows keep their page order where that order can show: in
+        # a floating sum's rounding and in what a host collection lists
+        stable = want_perm or any(
+            jnp.issubdtype(a.dtype, jnp.floating) for a in payload
+        )
+        self._ops = jax.lax.sort(
+            keys + payload, num_keys=len(keys), is_stable=stable
+        )
+        self._by_id = by_id
+        self.carried = len(payload)
+        self.perm = self._ops[-1] if want_perm else None
+        self.live = self._ops[0] == 0
+        first = jnp.arange(n, dtype=jnp.int32) == 0
+
+        def changed(slots):
+            diff = jnp.zeros((n,), jnp.bool_)
+            for i in slots:
+                op = self._ops[i]
+                diff = diff | (op != jnp.concatenate([op[:1], op[:-1]]))
+            return diff
+
+        new_group = self.live & (first | changed(range(1, n_group_ops)))
+        from .pallas.segreduce import SortedRuns
+
+        self.runs = SortedRuns(new_group, self.live)
+        seg = jnp.cumsum(new_group.astype(jnp.int32), dtype=jnp.int32) - 1
+        # dead rows and the groups past the frame -> overflow bucket
+        self.seg = jnp.minimum(jnp.where(self.live, seg, G), G)
+        if extra is not None:
+            # the value-sorted argument as it lies after the sort: valid
+            # values ascending at the front of each group's run
+            self.extra_valid = (
+                self.live if extra_vslot is None
+                else self.live & ~self._ops[extra_vslot]
+            )
+            self.extra_new = new_group | changed(range(n_group_ops, len(keys)))
+
+    def sorted(self, arr: jnp.ndarray) -> jnp.ndarray:
+        return self._ops[self._by_id[id(arr)]]
+
+    def col(self, cv: Optional[ColumnVal]) -> Optional[ColumnVal]:
+        """A carried column in the sorted order."""
+        if cv is None:
+            return None
+        return ColumnVal(
+            self.sorted(cv.data),
+            None if cv.valid is None else self.sorted(cv.valid),
+            cv.dict,
+            cv.type,
+            None if cv.data2 is None else self.sorted(cv.data2),
+        )
+
+    def key_reads(self) -> list:
+        """The group keys as 'last' reductions: a run's last row holds the
+        run's key, and the sorted key operands are the sort's own outputs."""
+        from .pallas.segreduce import SegRed
+
+        return [
+            SegRed("last", self._ops[i], None)
+            for vslot, slots in self._key_slots
+            for i in ([] if vslot is None else [vslot]) + list(slots)
+        ]
+
+    def out_keys(self, key_vals, read: list) -> list[tuple]:
+        """(data, valid, data2-or-None) per key from `key_reads`' results."""
+        it = iter(read)
+        out: list[tuple] = []
+        for kv, (vslot, slots) in zip(key_vals, self._key_slots):
+            valid = None if vslot is None else ~next(it)
+            if kv.data2 is not None:  # (hi signed, lo unsigned) operands
+                hi, lo_u = next(it), next(it)
+                lo = jax.lax.bitcast_convert_type(lo_u, jnp.int64)
+                out.append((lo.astype(kv.data.dtype), valid, hi.astype(kv.data2.dtype)))
+                continue
+            data = next(it)
+            if kv.dict is not None:  # sorted by rank: back to the code
+                inv = np.argsort(kv.dict.sorted_rank()).astype(np.int32)
+                data = jnp.take(
+                    jnp.asarray(inv), jnp.clip(data, 0, max(len(inv) - 1, 0))
+                ).astype(kv.data.dtype)
+            elif kv.data.dtype == jnp.bool_:
+                data = data != 0
+            out.append((data, valid, None))
+        return out
+
+
+def _carried_arrays(cols) -> list:
+    """The arrays of `cols` (None entries skipped) a sort has to carry."""
+    out = []
+    for cv in cols:
+        if cv is not None:
+            out.extend(a for a in (cv.data, cv.data2, cv.valid) if a is not None)
+    return out
+
+
+def _sorted_aggregate(
+    key_vals, agg_args, specs, live, G, agg_args2, agg_order, declined
+):
+    """The sort-based group-by: what group_aggregate runs when the direct-
+    code path and the hash-table kernel decline.  ONE sort that carries the
+    aggregated columns, then ONE compaction of the group ends carrying the
+    keys and the running sums (SortedRuns.read)."""
+    from .kernels import record_dispatch
+    from .pallas.segreduce import fused_segment_reduce
+
+    n = live.shape[0]
     # value-sorted aggregates (DISTINCT adjacency, percentile selection) ride
     # the group sort; the FIRST one shares the main sort, each additional one
-    # gets its own sort pass below (group order is key-determined, so segment
-    # ids align across sorts).
+    # gets its own sort pass below (group order is key-determined, so the
+    # frames align across sorts).
     vs_ix = [
         i
         for i, s in enumerate(specs)
         if (s.distinct or s.fn == "percentile") and agg_args[i] is not None
     ]
-
-    def grouped_sort(extra: Optional[ColumnVal]):
-        """Sort by (dead, keys..., extra arg) -> (perm, live_s, seg,
-        new_group, n_groups).  Validity of `extra` sorts before its value so
-        a NULL lane whose code equals a live value cannot become the "first
-        occurrence" (the round-1 COUNT(DISTINCT) advisory bug)."""
-        operands: list[jnp.ndarray] = [(~live).astype(jnp.int8)]
-        for kv in key_vals:
-            operands.append(~_valid_of(kv, n))  # nulls group together (last)
-            operands.extend(_sortable_operands(kv))  # 2 ops for decimal128
-        n_key_ops = len(operands) - 1
-        if extra is not None:
-            operands.append((~_valid_of(extra, n)).astype(jnp.int8))
-            operands.extend(_sortable_operands(extra))
-        iota = jnp.arange(n, dtype=jnp.int32)
-        sorted_ops = jax.lax.sort(operands + [iota], num_keys=len(operands))
-        perm = sorted_ops[-1]
-        live_s = jnp.take(live, perm)
-        key_ops = sorted_ops[1 : 1 + n_key_ops]
-        diff = jnp.zeros((n,), jnp.bool_)
-        for op in key_ops:
-            prev = jnp.concatenate([op[:1], op[:-1]])
-            diff = diff | (op != prev)
-        first = jnp.zeros((n,), jnp.bool_).at[0].set(True)
-        new_group = live_s & (first | diff)
-        seg = jnp.cumsum(new_group.astype(jnp.int32)) - 1
-        seg = jnp.where(live_s, seg, G)  # dead rows -> overflow bucket
-        seg = jnp.minimum(seg, G)
-        n_groups = jnp.sum(new_group.astype(jnp.int32))
-        return perm, live_s, seg, new_group, n_groups
-
-    perm, live_s, seg, new_group, n_groups = grouped_sort(
-        agg_args[vs_ix[0]] if vs_ix else None
+    host_ix = [i for i, s in enumerate(specs) if s.fn in HOST_AGGS]
+    # the value-sorted aggregate whose reductions share the main sort's pass
+    vs_main = vs_ix[0] if vs_ix and vs_ix[0] not in host_ix else None
+    # arguments that do not ride: read from the sort's keys, or on the host
+    apart = set(vs_ix) | set(host_ix)
+    riding = [None if i in apart else a for i, a in enumerate(agg_args)]
+    riding2 = [None if i in apart else a for i, a in enumerate(agg_args2)]
+    gs = _GroupedSort(
+        key_vals, live, G,
+        extra=agg_args[vs_ix[0]] if vs_ix else None,
+        carry=_carried_arrays(riding + riding2), want_perm=bool(host_ix),
     )
+    sorted_args = [gs.col(a) for a in riding]
+    sorted_args2 = [gs.col(a) for a in riding2]
 
-    # ---- output keys: first row of each segment ---------------------------
-    # seg is NONDECREASING (rows sorted by keys), so the first row of group g
-    # is a gather at searchsorted(seg, g) — no scatter (TPU scatters
-    # serialize; this was the high-cardinality group-by bottleneck).  One
-    # boundary pass is shared with the fused reductions below.
-    gids = jnp.arange(G, dtype=jnp.int32)
-    seg32 = jnp.minimum(seg.astype(jnp.int32), G)
-    starts = searchsorted_tpu(seg32, gids, side="left")
-    ends = searchsorted_tpu(seg32, gids, side="right")
-    starts_i = jnp.clip(starts, 0, max(n - 1, 0))
-    out_keys: list[tuple] = []
-    for kv in key_vals:
-        data_s = jnp.take(kv.data, perm)
-        valid_s = jnp.take(_valid_of(kv, n), perm)
-        hi = None
-        if kv.data2 is not None:  # decimal128 keys: carry the high limb
-            hi = jnp.take(jnp.take(kv.data2, perm), starts_i)
-        out_keys.append(
-            (jnp.take(data_s, starts_i), jnp.take(valid_s, starts_i), hi)
+    # ---- every reduction of the main sort in one pass over its ends --------
+    reds, finish = _agg_reductions(
+        sorted_args, specs, gs.seg, gs.live, G, n, sorted_args2, runs=gs.runs
+    )
+    key_at = len(reds)
+    reds = reds + gs.key_reads()
+    vs_at = len(reds)
+    if vs_main is not None:
+        vs_reds, vs_finish = _value_sorted_reduction(
+            gs, agg_args[vs_main], specs[vs_main]
         )
+        reds = reds + vs_reds
+    results = fused_segment_reduce(gs.seg, reds, G, runs=gs.runs)
+    out_keys = gs.out_keys(key_vals, results[key_at:vs_at])
+    out_aggs = finish(results[:key_at])
 
-    # ---- aggregates -------------------------------------------------------
-    out_aggs = _fused_aggs(
-        agg_args, specs, perm, seg, live_s, G, n,
-        sorted_segments=True, boundaries=(starts, ends), agg_args2=agg_args2,
-    )
     for i, (arg, spec) in enumerate(zip(agg_args, specs)):
-        if out_aggs[i] is None and spec.fn == "approx_distinct":
-            out_aggs[i] = _segment_hll(arg, perm, seg, live_s, G, n)
+        if out_aggs[i] is not None:
             continue
-        if out_aggs[i] is None and spec.fn in HOST_AGGS:
+        if spec.fn == "approx_distinct":
+            sa = sorted_args[i]
+            out_aggs[i] = _segment_hll(
+                sa.data, gs.live if sa.valid is None else sa.valid & gs.live,
+                gs.seg, G,
+            )
+        elif spec.fn in HOST_AGGS:
             out_aggs[i] = _host_collect_agg(
-                spec, arg, agg_args2[i], perm, seg, live_s, G, n,
+                spec, arg, agg_args2[i], gs.perm, gs.seg, gs.live, G, n,
                 order=agg_order[i],
             )
-            continue
-        if out_aggs[i] is None:  # DISTINCT/percentile: need sorted adjacency
-            if i == vs_ix[0]:
-                p, ls, sg, ng = perm, live_s, seg, new_group
-            else:  # additional value-sorted agg: its own sort pass
-                p, ls, sg, ng, _ = grouped_sort(arg)
-            if spec.fn == "percentile":
-                out_aggs[i] = _segment_percentile(arg, spec.param, p, sg, ls, G, n)
-            else:
-                out_aggs[i] = _segment_agg(arg, spec, p, sg, ls, ng, G, n)
+        elif i == vs_main:  # DISTINCT/percentile: sorted adjacency
+            out_aggs[i] = vs_finish(results[vs_at:])
+        else:  # additional value-sorted agg: its own sort pass
+            own = _GroupedSort(key_vals, live, G, extra=arg)
+            own_reds, own_finish = _value_sorted_reduction(own, arg, spec)
+            out_aggs[i] = own_finish(
+                fused_segment_reduce(own.seg, own_reds, G, runs=own.runs)
+            )
 
+    impl, why = declined
+    record_dispatch(
+        "group_by", impl,
+        f"{why}; sort carries {gs.carried} cols, "
+        f"ends carry {gs.runs.ends_words} words",
+    )
+    n_groups = gs.runs.n_groups
     out_live = jnp.arange(G, dtype=jnp.int32) < jnp.minimum(n_groups, G)
     return out_keys, out_aggs, out_live, n_groups
+
+
+def _value_sorted_reduction(gs: _GroupedSort, arg: ColumnVal, spec: AggSpec):
+    """(reductions, finish) of a DISTINCT count or a percentile over a
+    _GroupedSort whose `extra` is `arg`: rows arrive ordered by (group keys,
+    validity, value), so a value's first occurrence within its group is an
+    adjacency test and a percentile is a row at a computed offset from its
+    group's start."""
+    from .pallas.segreduce import SegRed
+
+    if spec.fn == "percentile":
+        # exact nearest-rank selection on the grouped sort.  The reference
+        # uses T-digest sketches (aggregation/
+        # TDigestAndPercentileAggregation); an exact answer over the sorted
+        # page is within any approximation contract and is the natural fit
+        # for the sort-based group-by.
+        data_s = gs.sorted(arg.data)
+        n = data_s.shape[0]
+
+        def finish(res):
+            vcnt, size = res
+            starts = jnp.cumsum(size) - size  # runs are packed from lane 0
+            off = jnp.floor(
+                spec.param * jnp.maximum(vcnt - 1, 0).astype(jnp.float64) + 0.5
+            )
+            idx = jnp.clip(starts + off.astype(jnp.int64), 0, max(n - 1, 0))
+            return jnp.take(data_s, idx), vcnt > 0
+
+        return [
+            SegRed("count", None, gs.extra_valid), SegRed("count", None, gs.live)
+        ], finish
+    if not (spec.distinct and spec.fn == "count"):
+        raise NotImplementedError(f"DISTINCT {spec.fn}")
+    return (
+        [SegRed("count", None, gs.extra_new & gs.extra_valid)],
+        lambda res: (res[0], None),
+    )
 
 
 _DIRECT_DOMAIN_LIMIT = 4096
@@ -532,14 +700,11 @@ def _direct_code_aggregate(key_vals, agg_args, specs, live, agg_args2=None):
     for kv, codes in zip(key_vals, codes_per_key):
         out_keys.append((jnp.asarray(codes.astype(np.int32)), None, None))
 
-    out_aggs = _fused_aggs(agg_args, specs, None, seg, live, G, n, agg_args2=agg_args2)
+    out_aggs = _fused_aggs(agg_args, specs, seg, live, G, n, agg_args2=agg_args2)
     return out_keys, out_aggs, out_live, n_groups
 
 
-def _fused_aggs(
-    agg_args, specs, perm, seg, live_s, G, n,
-    sorted_segments=False, boundaries=None, agg_args2=None,
-):
+def _fused_aggs(agg_args, specs, seg, live, G, n, agg_args2=None):
     """All non-DISTINCT aggregates of a GROUP BY in one fused segmented
     reduction (ops/pallas/segreduce.py): on TPU a single Pallas pass over HBM
     computes every SUM/COUNT/AVG on the MXU (exact int64 via limb
@@ -551,7 +716,22 @@ def _fused_aggs(
     Returns a list aligned with specs; DISTINCT entries are None (the caller
     computes those with the sorted-adjacency path).
     """
-    from .pallas.segreduce import SegRed, fused_segment_reduce
+    from .pallas.segreduce import fused_segment_reduce
+
+    reds, finish = _agg_reductions(agg_args, specs, seg, live, G, n, agg_args2)
+    return finish(fused_segment_reduce(seg, reds, G) if reds else [])
+
+
+def _agg_reductions(agg_args, specs, seg, live_s, G, n, agg_args2=None, runs=None):
+    """(reductions, finish) of `_fused_aggs`: the SegReds every fusable
+    aggregate asks for, and the function that turns their results into the
+    list aligned with specs.  `runs`: the rows are the sorted group-by's
+    (SortedRuns) — the caller adds its own reductions and makes the one
+    reducer call; there a count of a column without a mask is the group's
+    size, and a single-lane argument's 128-bit sum travels as the fewest
+    exact int64 partial sums (one below 64 bits, two at 64), since every
+    word read at a group's end is a word the compaction carries."""
+    from .pallas.segreduce import SegRed
 
     reds: list = []
     count_memo: dict = {}
@@ -589,16 +769,9 @@ def _fused_aggs(
         if spec.fn in MOMENT_AGGS:
             # pairwise moments (reference: CorrelationAggregation etc.):
             # sums of y, x, xy, xx, yy over rows where BOTH args are non-NULL
-            y = arg.data if perm is None else jnp.take(arg.data, perm)
-            x = arg2.data if perm is None else jnp.take(arg2.data, perm)
-            yv = _valid_of(arg, n)
-            xv = _valid_of(arg2, n)
-            if perm is not None:
-                yv = jnp.take(yv, perm)
-                xv = jnp.take(xv, perm)
-            pv = yv & xv & live_s
-            y = y.astype(jnp.float64)
-            x = x.astype(jnp.float64)
+            pv = _valid_of(arg, n) & _valid_of(arg2, n) & live_s
+            y = arg.data.astype(jnp.float64)
+            x = arg2.data.astype(jnp.float64)
             recipe.append(
                 (
                     "moment", spec.fn,
@@ -614,11 +787,11 @@ def _fused_aggs(
         if spec.fn == "count_star":
             recipe.append(("count", add_count(live_s)))
             continue
-        data = arg.data if perm is None else jnp.take(arg.data, perm)
-        valid = _valid_of(arg, n)
-        if perm is not None:
-            valid = jnp.take(valid, perm)
-        valid = valid & live_s
+        data = arg.data
+        if runs is not None and arg.valid is None:
+            valid = live_s  # one count serves every column without a mask
+        else:
+            valid = _valid_of(arg, n) & live_s
         res_t = spec.type
         wide_sum = (
             spec.fn == "sum"
@@ -638,15 +811,23 @@ def _fused_aggs(
             from ..data.dec128 import limbs32
 
             lo64 = data.astype(jnp.int64)
-            if arg.data2 is not None:
-                hi = arg.data2 if perm is None else jnp.take(arg.data2, perm)
+            if runs is not None and arg.data2 is None:
+                # a single lane: n < 2^31 rows of under 2^31 sum exactly in
+                # one int64; a 64-bit lane as its unsigned low and signed
+                # high 32 bits (limbs l0 and l1 of a sign-extended value)
+                if data.dtype.itemsize <= 4:
+                    parts = [data]
+                else:
+                    parts = [lo64 & jnp.int64(0xFFFFFFFF), lo64 >> 32]
             else:
-                hi = lo64 >> 63  # sign-extend the single lane
-            l0, l1, l2, l3 = limbs32(lo64, hi)
+                if arg.data2 is not None:
+                    hi = arg.data2
+                else:
+                    hi = lo64 >> 63  # sign-extend the single lane
+                parts = limbs32(lo64, hi)
             recipe.append(
-                ("sum128", add(SegRed("sum", l0, valid)),
-                 add(SegRed("sum", l1, valid)), add(SegRed("sum", l2, valid)),
-                 add(SegRed("sum", l3, valid)), add_count(valid))
+                ("sum128", [add(SegRed("sum", l, valid)) for l in parts],
+                 add_count(valid))
             )
         elif arg.data2 is not None and spec.fn in ("min", "max"):
             # decimal128 min/max: lexicographic two-pass — the fused pass
@@ -655,7 +836,7 @@ def _fused_aggs(
             # group winner (Int128 compare order = (hi, unsigned lo);
             # reference: spi/type/Int128Math.compare).  The lo limb is
             # XOR-biased so unsigned order matches int64 signed order.
-            hi = arg.data2 if perm is None else jnp.take(arg.data2, perm)
+            hi = arg.data2
             lo_b = jnp.bitwise_xor(
                 data.astype(jnp.int64), jnp.int64(-(2 ** 63))
             )
@@ -676,9 +857,8 @@ def _fused_aggs(
         elif spec.fn in ("min", "max"):
             if arg.dict is not None:
                 rank = jnp.take(jnp.asarray(arg.dict.sorted_rank()), arg.data)
-                rdata = rank if perm is None else jnp.take(rank, perm)
                 recipe.append(
-                    ("dictmm", spec.fn, arg, add(SegRed(spec.fn, rdata, valid)), add_count(valid))
+                    ("dictmm", spec.fn, arg, add(SegRed(spec.fn, rank, valid)), add_count(valid))
                 )
             else:
                 recipe.append(("minmax", add(SegRed(spec.fn, data, valid)), add_count(valid)))
@@ -701,13 +881,16 @@ def _fused_aggs(
         else:
             raise NotImplementedError(f"aggregate {spec.fn}")
 
-    results = (
-        fused_segment_reduce(
-            seg, reds, G, sorted_segments=sorted_segments, boundaries=boundaries
-        )
-        if reds
-        else []
-    )
+    def finish(results) -> list:
+        return _finish_aggs(recipe, results, seg, G, runs)
+
+    return reds, finish
+
+
+def _finish_aggs(recipe, results, seg, G, runs) -> list:
+    """The aggregates' outputs from their reductions' results (see
+    _agg_reductions)."""
+    from .pallas.segreduce import SegRed, fused_segment_reduce
 
     out: list = []
     for r in recipe:
@@ -720,11 +903,14 @@ def _fused_aggs(
         elif kind == "sum128":
             from ..data.dec128 import recombine32
 
-            s0, s1, s2, s3, cnt = (results[r[i]] for i in range(1, 6))
-            lo, hi = recombine32(
-                s0.astype(jnp.int64), s1.astype(jnp.int64),
-                s2.astype(jnp.int64), s3.astype(jnp.int64),
-            )
+            sums = [results[i].astype(jnp.int64) for i in r[1]]
+            cnt = results[r[2]]
+            if len(sums) == 1:  # fits one lane: sign-extend
+                lo, hi = sums[0], sums[0] >> 63
+            else:
+                if len(sums) == 2:  # limbs the argument does not have
+                    sums += [jnp.zeros_like(sums[0])] * 2
+                lo, hi = recombine32(*sums)
             out.append((lo, cnt > 0, None, hi))
         elif kind in ("sum", "avg"):
             s, cnt = results[r[1]], results[r[2]]
@@ -745,8 +931,7 @@ def _fused_aggs(
                 hi_rows == jnp.take(hi_g.astype(jnp.int64), seg)
             )
             lo_best = fused_segment_reduce(
-                seg, [SegRed(fn, lo_b, at_best)], G,
-                sorted_segments=sorted_segments, boundaries=boundaries,
+                seg, [SegRed(fn, lo_b, at_best)], G, runs=runs
             )[0]
             lo_g = jnp.bitwise_xor(
                 lo_best.astype(jnp.int64), jnp.int64(-(2 ** 63))
@@ -848,51 +1033,55 @@ def _bitlen64(v: jnp.ndarray) -> jnp.ndarray:
 
 
 def _segment_hll(
-    arg: ColumnVal,
-    perm: jnp.ndarray,
+    data_s: jnp.ndarray,
+    valid_s: jnp.ndarray,
     seg: jnp.ndarray,
-    live_s: jnp.ndarray,
     G: int,
-    n: int,
 ):
     """Grouped HyperLogLog: approx_distinct with CONSTANT sketch state per
     group (reference: ApproximateCountDistinctAggregations over
-    HyperLogLogType).  TPU shape: one extra sort by (group, bucket, rho)
-    puts every (group, bucket)'s MAX rho at its run end; per-group sums of
-    2^-rho then ride the same boundary-cumsum machinery as every other
-    sorted reduction — no G x m dense state ever materializes (empty
-    buckets enter the estimator arithmetically via m - nonempty)."""
+    HyperLogLogType).  `seg` is each row's group (G for the lanes of no
+    group; the groups that have a row are 0..k-1), `valid_s` the argument's
+    non-NULL lanes.  TPU shape: one extra sort by (group,
+    bucket, rho) puts every (group, bucket)'s MAX rho at its run end;
+    per-group sums of 2^-rho are then read at the group ends like every
+    other sorted reduction (SortedRuns.read) — no G x m dense state ever
+    materializes (empty buckets enter the estimator arithmetically via
+    m - nonempty)."""
+    from .pallas.segreduce import SegRed, SortedRuns
+
+    n = seg.shape[0]
     m = 1 << _HLL_P
     rest_bits = 63 - _HLL_P  # use the hash's low 63 bits (int64 sign-safe)
-    data_s = jnp.take(arg.data, perm)
-    valid_s = jnp.take(_valid_of(arg, n), perm) & live_s
     h = _hash64(data_s)  # int64, sign bit clear
     bucket = (h >> rest_bits).astype(jnp.int32)
     rest = h & jnp.int64((1 << rest_bits) - 1)
     # rho = leading-zero count within the rest_bits window + 1
     rho = (rest_bits + 1 - _bitlen64(rest)).astype(jnp.int32)  # [1, 52]
-    combined = seg.astype(jnp.int64) * m + bucket
+    # a NULL lane stays in its group's run as (bucket 0, rho 0) and adds
+    # nothing: every group keeps a row, so the runs stay packed in group
+    # order and the frame's lane g is group g
+    in_group = seg < G
+    valid_s = valid_s & in_group
+    combined = seg.astype(jnp.int64) * m + jnp.where(valid_s, bucket, 0)
     dead_val = jnp.int64(G) * m
-    combined = jnp.where(valid_s, combined, dead_val)
-    c_s, rho_s = jax.lax.sort([combined, rho], num_keys=2)
-    # run ends carry the bucket's max rho (rho ascends within a run)
-    is_end = jnp.concatenate(
-        [c_s[1:] != c_s[:-1], jnp.ones((1,), jnp.bool_)]
+    combined = jnp.where(in_group, combined, dead_val)
+    c_s, rho_s = jax.lax.sort(
+        [combined, jnp.where(valid_s, rho, 0)], num_keys=2
     )
-    live_end = is_end & (c_s < dead_val)
-    # keep gseg NONDECREASING (c_s is sorted): non-end rows stay in their
-    # group's run with zero contribution — masking them to G would break the
-    # boundary searchsorted's sortedness precondition
-    gseg = jnp.minimum((c_s // m).astype(jnp.int32), G)
-    contrib_z = jnp.where(live_end, 2.0 ** (-rho_s.astype(jnp.float64)), 0.0)
-    contrib_e = live_end.astype(jnp.int64)
-    # boundary-cumsum reductions apply over the sorted gseg
-    from .pallas.segreduce import SegRed, _sorted_fallback
-
-    z_part, e_cnt = _sorted_fallback(
-        gseg,
-        [SegRed("sum", contrib_z, None), SegRed("sum", contrib_e, None)],
-        G,
+    # run ends carry the bucket's max rho (rho ascends within a run)
+    bucket_end = jnp.concatenate(
+        [c_s[1:] != c_s[:-1], jnp.ones((min(n, 1),), jnp.bool_)]
+    ) & (rho_s > 0)
+    gid = c_s // m
+    live2 = c_s < dead_val
+    start = live2 & (
+        (jnp.arange(n, dtype=jnp.int32) == 0)
+        | (gid != jnp.concatenate([gid[:1], gid[:-1]]))
+    )
+    contrib_z = jnp.where(bucket_end, 2.0 ** (-rho_s.astype(jnp.float64)), 0.0)
+    z_part, e_cnt = SortedRuns(start, live2).read(
+        [SegRed("sum", contrib_z, None), SegRed("count", None, bucket_end)], G
     )
     e_cnt = e_cnt.astype(jnp.float64)
     z = (m - e_cnt) + z_part  # empty buckets contribute 2^0 each
@@ -1035,61 +1224,6 @@ def _host_collect_agg(
     return jnp.asarray(codes), jnp.asarray(valid), Dictionary(uniq)
 
 
-def _segment_agg(
-    arg: Optional[ColumnVal],
-    spec: AggSpec,
-    perm: jnp.ndarray,
-    seg: jnp.ndarray,
-    live_s: jnp.ndarray,
-    new_group: jnp.ndarray,
-    G: int,
-    n: int,
-):
-    """DISTINCT aggregates only — everything else is fused (_fused_aggs).
-
-    Requires the sort-based grouping: rows arrive ordered by (group keys,
-    distinct argument), so the first occurrence of each value within its
-    group is an adjacency test.
-    """
-    num = G + 1  # +1 overflow bucket for dead lanes
-    assert spec.distinct, "non-DISTINCT aggregates run through _fused_aggs"
-    data_s = jnp.take(arg.data, perm)
-    valid_s = jnp.take(_valid_of(arg, n), perm) & live_s
-    prev = jnp.concatenate([data_s[:1], data_s[:-1]])
-    new_val = new_group | (data_s != prev)
-    contrib = (new_val & valid_s).astype(jnp.int64)
-    if spec.fn != "count":
-        raise NotImplementedError(f"DISTINCT {spec.fn}")
-    out = _segment_sum(contrib, seg, num, sorted_segments=True)[:G]
-    return out, None
-
-
-def _segment_percentile(
-    arg: ColumnVal,
-    p: float,
-    perm: jnp.ndarray,
-    seg: jnp.ndarray,
-    live_s: jnp.ndarray,
-    G: int,
-    n: int,
-):
-    """approx_percentile via exact nearest-rank selection on the grouped sort
-    (the sort operands append (validity, value) for this arg, so each group's
-    valid values are contiguous ascending runs).  The reference uses T-digest
-    sketches (aggregation/TDigestAndPercentileAggregation); an exact answer
-    over the sorted page is within any approximation contract and is the
-    natural fit for the sort-based group-by."""
-    data_s = jnp.take(arg.data, perm)
-    valid_s = jnp.take(_valid_of(arg, n), perm) & live_s
-    vcnt = _segment_sum(valid_s.astype(jnp.int64), seg, G + 1, sorted_segments=True)[:G]
-    # group start among sorted rows (seg ascends over live rows, dead == G)
-    starts = searchsorted_tpu(seg, jnp.arange(G, dtype=seg.dtype), side="left")
-    off = jnp.floor(p * jnp.maximum(vcnt - 1, 0).astype(jnp.float64) + 0.5)
-    idx = jnp.clip(starts + off.astype(jnp.int64), 0, max(n - 1, 0))
-    vals = jnp.take(data_s, idx)
-    return vals, vcnt > 0
-
-
 def _global_aggregate(agg_args, specs, live, agg_args2=None, agg_order=None):
     """No GROUP BY: one output row even over empty input (SQL semantics).
 
@@ -1103,7 +1237,7 @@ def _global_aggregate(agg_args, specs, live, agg_args2=None, agg_order=None):
     if agg_order is None:
         agg_order = [()] * len(specs)
     seg = jnp.zeros((n,), jnp.int32)
-    fused = _fused_aggs(agg_args, specs, None, seg, live, 1, n, agg_args2=agg_args2)
+    fused = _fused_aggs(agg_args, specs, seg, live, 1, n, agg_args2=agg_args2)
     out_aggs = []
     for i, ((arg, spec), pre) in enumerate(zip(zip(agg_args, specs), fused)):
         if pre is not None:
@@ -1120,9 +1254,10 @@ def _global_aggregate(agg_args, specs, live, agg_args2=None, agg_order=None):
             continue
         valid = _valid_of(arg, n) & live
         if spec.fn == "approx_distinct":
-            seg1 = jnp.zeros((n,), jnp.int32)
-            perm1 = jnp.arange(n, dtype=jnp.int32)
-            cnts, _ = _segment_hll(arg, perm1, seg1, live, 1, n)
+            # dead lanes to the back: the sketch wants its rows grouped
+            cnts, _ = _segment_hll(
+                arg.data, valid, jnp.where(live, 0, 1).astype(jnp.int32), 1
+            )
             out_aggs.append((cnts, None))
             continue
         if spec.distinct:
