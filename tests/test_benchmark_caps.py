@@ -27,6 +27,7 @@ machine (whoever learns the tiers anew has them) the recording is held to
 them; elsewhere that one case skips.
 """
 
+import contextlib
 import glob
 import json
 import os
@@ -46,8 +47,14 @@ def _load(*parts):
         return json.load(f)
 
 
-# the configurations whose cells send `joins_text_1stream` through Engine()
-EMBEDDED = ["tpch_sf1_embedded", "tpch_sf10_embedded"]
+# the configurations whose cells go through Engine(), and the mix each is sent
+EMBEDDED = {
+    "tpch_sf1_embedded": "joins_text_1stream",
+    "tpch_sf10_embedded": "joins_text_1stream",
+    "tpch_sf10_embedded_multiway": "multiway_text_1stream",
+}
+CASES = [(config, name) for config, mix in EMBEDDED.items()
+         for name in _load("traffic", f"{mix}.json")["pass"]]
 # from this scale on a test does not generate columns: it plans from a recording
 _BIG = 2.0
 _RECORDED = os.path.join(os.path.dirname(__file__), "data", "tpch_sf10_planned_stats.json")
@@ -84,13 +91,13 @@ def _columns_on_this_machine(scale: float) -> bool:
         for table, t in _load(_RECORDED)["tables"].items() for c in t["columns"])
 
 
-@pytest.fixture(scope="module", params=EMBEDDED)
-def embedded_engine(request):
+@contextlib.contextmanager
+def planned_engine(config_name: str):
     """`benchmarks/entries/embedded.py` `Entry.__init__`, less the device."""
     from trino_tpu.connectors import tpch
     from trino_tpu.runtime.engine import Engine
 
-    config = _load("configs", f"{request.param}.json")
+    config = _load("configs", f"{config_name}.json")
     scale = float(config["scale_factor"])
     mp = pytest.MonkeyPatch()
     if scale >= _BIG:
@@ -107,8 +114,16 @@ def embedded_engine(request):
     engine.register_catalog("tpch", tpch.TpchConnector(scale))
     for prop, value in config["session"].items():
         engine.session.set(prop, str(value))
-    yield engine
-    mp.undo()
+    try:
+        yield engine
+    finally:
+        mp.undo()
+
+
+@pytest.fixture(scope="module")
+def embedded_engine(request):
+    with planned_engine(request.param) as engine:
+        yield engine
 
 
 def _shipped_keys() -> dict:
@@ -133,7 +148,7 @@ def _scan_stand_ins(engine, plan) -> dict:
     }
 
 
-@pytest.mark.parametrize("name", _load("traffic", "joins_text_1stream.json")["pass"])
+@pytest.mark.parametrize("embedded_engine,name", CASES, indirect=["embedded_engine"])
 def test_embedded_statement_finds_its_shipped_capacities(embedded_engine, name):
     text = "\n".join(_load("templates", f"{name}.json")["text"])  # loader.sql_text
     plan = embedded_engine.plan(text)

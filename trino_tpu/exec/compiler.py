@@ -30,6 +30,7 @@ from ..connectors.spi import CatalogManager
 from ..data.page import CodedStrings, Column, Page
 from ..data.types import Type
 from ..ops.expr import ColumnVal, column_val, eval_expr, eval_predicate, param_context
+from ..ops.kernels import JOIN_ROWS
 from ..ops.relops import (
     AggSpec, SortSpec, broadcast_single_row, compact_rows, equi_join,
     group_aggregate, limit_mask, sort_rows, top_n, unnest_expand,
@@ -40,6 +41,7 @@ from ..plan.nodes import (
     Join, Limit, MatchRecognize, PlanNode, Project, RemoteSource, Sort,
     TableScan, TopN, Unnest, Values, Window,
 )
+from ..plan.reorder import is_ordered_join
 
 __all__ = ["LocalExecutor", "MemoryBudgetExceeded", "FragmentCompileError"]
 
@@ -546,6 +548,8 @@ class LocalExecutor:
                         op = type(nodes[nid]).__name__
                         FRAME_LANES.labels(op).inc(cap)
                         FRAME_LIVE_ROWS.labels(op).inc(required[nid])
+                        if is_ordered_join(nodes[nid]):
+                            JOIN_ROWS.labels("actual").inc(required[nid])
                 self._settle(plan, nodes, inputs, caps, known, required,
                              grown, tighten)
                 # execute wall = everything this call that wasn't compile

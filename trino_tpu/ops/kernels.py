@@ -75,6 +75,16 @@ _DISPATCH = _metrics.GLOBAL.counter(
     ("op", "impl"),
 )
 
+JOIN_ROWS = _metrics.GLOBAL.counter(
+    "trino_tpu_join_rows_total",
+    "Output rows of inner equi-joins: estimated = what the join-order cost "
+    "model gave each join of a statement Engine() planned (plan/reorder.py "
+    "join_estimates, the planner span's join_estimates), actual = what the "
+    "compiled program reported for each such join in a converged execution "
+    "(the device_wait span's frames); far apart, the order was chosen blind",
+    ("what",),
+)
+
 FUSED_SCATTER = _metrics.GLOBAL.counter(
     "trino_tpu_fused_scatter_total",
     "Fused scan programs traced, by the form the kernel scatters its "

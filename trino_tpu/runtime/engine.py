@@ -22,8 +22,10 @@ from ..connectors.spi import CatalogManager, ColumnSchema, Connector
 from ..data.page import Page
 from ..exec.compiler import LocalExecutor, page_rows
 from ..exec.resident import ResidentStore
+from ..ops.kernels import JOIN_ROWS
 from ..plan.nodes import PlanNode, TableScan, format_plan
 from ..plan.planner import Planner
+from ..plan.reorder import join_estimates
 from .session import SessionProperties
 from .txn import run_write  # imported eagerly: registers the txn metrics
 
@@ -179,10 +181,25 @@ class Engine:
         return format_plan(self.plan(sql))
 
     def execute_page(self, sql) -> Page:
-        with self.tracer.span("planner"):
+        with self.tracer.span("planner") as span:
             plan = self.plan(sql)
+            self._note_join_estimates(span, plan)
         with self.tracer.span("execute"):
             return self._execute_planned(plan)
+
+    def _note_join_estimates(self, span, plan: PlanNode) -> None:
+        """On the `planner` span, what the join order was chosen by:
+        `join_order` (each region's leaves as they are joined) and
+        `join_estimates` (node id -> estimated output rows; the `device_wait`
+        span's `frames` hold the rows the same nodes made)."""
+        orders, estimates = join_estimates(plan, self.catalogs)
+        if not estimates:
+            return
+        if orders:
+            span.attributes["join_order"] = orders
+        span.attributes["join_estimates"] = {
+            f"Join#{nid}": rows for nid, rows in estimates.items()}
+        JOIN_ROWS.labels("estimated").inc(sum(estimates.values()))
 
     def _device_memory_budget(self) -> int:
         """Per-query device-memory budget: the session property when set,
