@@ -38,6 +38,9 @@ keys, and the 32-bit words the compaction of the group ends carried.
 A selected kernel still carries a runtime overflow guard — hash-table
 overflow or probe exhaustion divert that execution to the sort path without
 re-counting.
+op "compact" (relops.compact_rows; no Pallas kernel) has impls of its own:
+"carry" = the columns rode the compaction's sort, "gather" = they were fetched
+through its permutation; the detail is `60000466 -> 33554432 lanes, 5 words`.
 """
 
 from __future__ import annotations
@@ -68,10 +71,12 @@ _POLICY = _DEFAULT
 _DISPATCH = _metrics.GLOBAL.counter(
     "trino_tpu_kernel_dispatch_total",
     "Data-plane kernel selections at plan-trace time, by relational op "
-    "(group_by | join | fused_pipeline | segment_reduce | top_n) and "
+    "(group_by | join | fused_pipeline | segment_reduce | top_n | compact) and "
     "implementation (pallas = Pallas TPU kernel, sort = legacy sort path, "
     "fallback = kernel-eligible shape past the policy capacity limit, sort "
-    "path ran; segment_reduce and top_n count their Pallas selections only)",
+    "path ran; segment_reduce and top_n count their Pallas selections only; "
+    "compact: carry = the columns rode the compaction's sort, gather = they "
+    "were fetched through its permutation)",
     ("op", "impl"),
 )
 

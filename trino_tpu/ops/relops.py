@@ -1687,30 +1687,96 @@ def broadcast_single_row(
 # ------------------------------------------------------------- sort / topn
 
 
+# compact_rows: the columns ride the sort where the frame holds at least one
+# lane in 24 of the input.  Chip microbenchmark (PERF.md section 6, PR 43),
+# five words a row: carried 448 ms at 60M lanes whatever the frame, gathered
+# 424 into 2^21 lanes (a 29th) and 703 into 2^22 (a 14th); 27 ms at 6M lanes
+# against 20 into 2^17 (a 46th) and 44 into 2^18 (a 23rd)
+_COMPACT_CARRY_RATIO = 24
+
+
+def compact_form(n: int, cap: int, words: int) -> str:
+    """How `compact_rows` moves `words` 32-bit words a row from `n` lanes
+    into a frame of `cap`: "carry" (operands of the sort) or "gather"
+    (fetched through the sorted permutation).  A word costs the sort about
+    a nanosecond a lane of INPUT and a gather 19-27 a lane of OUTPUT, so
+    the gather wins only into a frame that is small against the input; one
+    word rides in the permutation's place at no cost at all.  A function of
+    the traced shapes alone: the CPU traces the form the chip runs."""
+    if words <= 1 or cap * _COMPACT_CARRY_RATIO >= n:
+        return "carry"
+    return "gather"
+
+
 def compact_rows(cols, live, cap: int):
-    """Gather live rows into `cap` lanes (dead lanes drop).  Sort-based:
-    one 2-operand bitonic pass moves live rows to the front in original
-    order (stable), then every column gathers the first `cap` positions —
-    no scatter (TPU scatters serialize).  Returns (cols, live, required)
-    with required = true live count for the capacity-retry protocol."""
+    """Move live rows into `cap` lanes (dead lanes drop).  One sort on
+    (dead flag, lane number) brings live rows to the front in their
+    original order (dead ones follow in theirs); no scatter (TPU scatters
+    serialize).  How the columns follow is `compact_form`'s choice by
+    shape: "carry" — every array of every column (`data`; `valid` and
+    `data2` where there) is an operand of that sort, sliced to `cap`
+    afterwards: no permutation, no gather (q12's compactions, frames of a
+    quarter to a half of their inputs); "gather" — the sort yields the
+    permutation and every array takes `cap` lanes through it (q18's: 4,096
+    lanes out of 15M).  Both leave the same bits in every lane, dead ones
+    included.  Returns (cols, live, required) with required = true live
+    count for the capacity-retry protocol, whatever `cap` is."""
+    from .kernels import record_dispatch
+
     n = live.shape[0]
-    iota = jnp.arange(n, dtype=jnp.int32)
-    perm = jax.lax.sort([(~live).astype(jnp.int8), iota], num_keys=2,
-                        is_stable=True)[-1]
-    take = perm[:cap]
-    required = jnp.sum(live.astype(jnp.int64))
-    out = [
+    arrays: dict = {}  # id -> array: what a row holds, each once
+    for cv in cols:
+        for a in (cv.data, cv.valid, cv.data2):
+            if a is not None:
+                arrays.setdefault(id(a), a)
+    words = sum(
+        max(1, a.dtype.itemsize // 4) * int(np.prod(a.shape[1:]))
+        for a in arrays.values()
+    )
+    form = compact_form(n, cap, words)
+    record_dispatch("compact", form, f"{n} -> {cap} lanes, {words} words")
+    if form == "gather":
+        iota = jnp.arange(n, dtype=jnp.int32)
+        perm = jax.lax.sort([(~live).astype(jnp.int8), iota], num_keys=2,
+                            is_stable=True)[-1]
+        take = perm[:cap]
+        required = jnp.sum(live.astype(jnp.int64))
+        at = {k: jnp.take(a, take, axis=0) for k, a in arrays.items()}
+    else:
+        # the lane's number under the dead flag, ONE u32 key (n < 2^31): a
+        # strict total order, so the sort need not be stable — a stable one
+        # on the flag alone drags a hidden index operand (PERF.md section 6,
+        # PR 43: 491 ms for 448 at 60M lanes, compiled in 98 s for 60)
+        key = jnp.arange(n, dtype=jnp.uint32) | (
+            (~live).astype(jnp.uint32) << 31)
+        # a mask rides as s8.  An array that is not one lane a row (none
+        # reaches a Compact today) cannot be an operand of the sort: it is
+        # gathered by row through the sorted key's lane numbers
+        ride = {k: a for k, a in arrays.items() if a.ndim == 1}
+        out = jax.lax.sort(
+            [key] + [a.astype(jnp.int8) if a.dtype == jnp.bool_ else a
+                     for a in ride.values()],
+            num_keys=1, is_stable=False,
+        )
+        required = jnp.sum(live.astype(jnp.int64))
+        at = {k: o[:cap].astype(a.dtype)
+              for (k, a), o in zip(ride.items(), out[1:])}
+        if len(ride) < len(arrays):
+            take = (out[0][:cap] & jnp.uint32(0x7FFFFFFF)).astype(jnp.int32)
+            at.update((k, jnp.take(a, take, axis=0))
+                      for k, a in arrays.items() if k not in ride)
+    out_cols = [
         ColumnVal(
-            jnp.take(cv.data, take),
-            None if cv.valid is None else jnp.take(cv.valid, take),
+            at[id(cv.data)],
+            None if cv.valid is None else at[id(cv.valid)],
             cv.dict,
             cv.type,
-            None if cv.data2 is None else jnp.take(cv.data2, take),
+            None if cv.data2 is None else at[id(cv.data2)],
         )
         for cv in cols
     ]
     out_live = jnp.arange(cap, dtype=jnp.int64) < jnp.minimum(required, cap)
-    return out, out_live, required
+    return out_cols, out_live, required
 
 
 def sort_rows(
