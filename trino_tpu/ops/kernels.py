@@ -41,6 +41,12 @@ re-counting.
 op "compact" (relops.compact_rows; no Pallas kernel) has impls of its own:
 "carry" = the columns rode the compaction's sort, "gather" = they were fetched
 through its permutation; the detail is `60000466 -> 33554432 lanes, 5 words`.
+op "join_rank" (relops.equi_join; one event a traced join, beside its "join"
+event) says how the probe's bounds over the sorted build side were found:
+"pallas" = by the hash kernel's probe, "merged" = a running count over ONE
+sort of build ++ probe hashes, "scan" = a binary search (few probes against
+many keys: relops.rank_form); the detail is `60000466 ++ 4096 lanes -> C
+16384` (build lanes, probe lanes, the expansion frame).
 """
 
 from __future__ import annotations

@@ -11,7 +11,9 @@ with whole-page device kernels:
   fixed reduction tree makes float aggregation deterministic (a north-star
   requirement the Java engine itself cannot honor across runs).
 - equi-join: the reference's PagesHash + JoinProbe (operator/join/) becomes
-  sort + vectorized binary search (searchsorted) + prefix-sum expansion.
+  a sort of the build side's hashes, the probe's bounds over it (a running
+  count over one merged sort, or a binary search for few probes: rank_form)
+  and a prefix-sum expansion.
   Output capacity is static; the kernel reports the true match count so the
   host can retry at a bigger tier (exec/executor.py), mirroring how the
   reference's planner-fed stats size hash tables.
@@ -77,23 +79,96 @@ def _valid_of(v: ColumnVal, n: int) -> jnp.ndarray:
 
 _MATMUL_SEGMENT_LIMIT = 1024
 
-_SEARCHSORTED_SORT_MIN = 4096
+# rank_form: what a lane gathered by one round of a binary search costs in
+# lanes of the sorted forms.  The chip (PERF.md section 6, PR 44): a round
+# 22-30 ns a query lane, twice for two bounds; the merged bounds 7-12 ns a
+# lane of keys + queries, the expansion's scatter 6-10.  At 60M keys the
+# bounds cross near 375k queries, at 15M near 90k, the expansion of 15M rows
+# near 125k output lanes; 6 puts the turn at 387k, 105k and 105k
+_RANK_SCAN_RATIO = 6
+
+
+def rank_form(keys: int, queries: int) -> str:
+    """How `queries` values are ranked among `keys` sorted ones: "scan" (a
+    binary search, `searchsorted_tpu`) or "merged" (a running count over one
+    sorted order: `_merged_bounds`, `expand_rows`).  The search gathers
+    `queries` lanes in each of its ceil(log2(keys + 1)) rounds; the sorted
+    form sorts or scatters `keys + queries` lanes once, whatever the
+    queries' count — so 4,096 probes never sort a 60M-lane haystack and 15M
+    probes never search one.  A function of the traced shapes alone: the
+    CPU traces the form the chip runs."""
+    rounds = int(keys).bit_length()
+    if queries * rounds * _RANK_SCAN_RATIO <= keys + queries:
+        return "scan"
+    return "merged"
 
 
 def searchsorted_tpu(a: jnp.ndarray, v: jnp.ndarray, side: str = "left"):
-    """jnp.searchsorted with the method picked for TPU: the default binary
-    search lowers to log2(n) SEQUENTIAL gather rounds over HBM (~1.8s for
-    8M probes into 8M keys — measured; it was the q03/q18 bottleneck), while
-    'sort' is an argsort of the concatenation a++v, a scatter of as many
-    lanes and the same again over v.  That is not cheap either: at
-    60,000,466 + 16,777,216 lanes the v5e read 0.49 s a sort and 0.45 s a
-    scatter, twice a call (ledger, PR 40) — which is why the sorted group-by
-    no longer asks (its boundaries stand as flags: SortedRuns).  Callers
-    left: equi_join's probe of the sorted build side and its expansion,
-    unnest_expand.  Small query sets keep the scan — sorting the whole
-    haystack for a handful of lookups loses."""
-    method = "sort" if v.size >= _SEARCHSORTED_SORT_MIN else "scan"
-    return jnp.searchsorted(a, v, side=side, method=method)
+    """jnp.searchsorted as a binary search: log2(n) SEQUENTIAL gather rounds
+    over HBM, each of `v.size` lanes (~1.8 s for 8M probes into 8M keys —
+    measured; it was the q03/q18 bottleneck).  Right only for few queries
+    against many keys, which `rank_form` decides from BOTH sizes; callers —
+    equi_join's bounds and `expand_rows` — ask it first and otherwise rank
+    by one sorted order of their own.  JAX's method="sort" (an argsort of
+    a ++ v, a scatter of as many lanes and the same again over v: at
+    60,000,466 + 16,777,216 lanes 0.49 s a sort and 0.45 s a scatter, twice
+    a call; ledger, PR 40) is reached from nowhere since PR 44."""
+    return jnp.searchsorted(a, v, side=side, method="scan")
+
+
+def _merged_bounds(bh: jnp.ndarray, ph: jnp.ndarray):
+    """searchsorted(sort(bh), ph, "left" / "right"), value for value, from
+    ONE sort of bh ++ ph: the lane number is the sort's last key, so the
+    order is total (no stable sort's hidden index operand) and among equal
+    hashes the build's lanes come first.  There `cb`, the running count of
+    build lanes, IS the right bound at a probe's lane, and the count before
+    the first lane of its run of equal hashes the left one — monotone, so a
+    running maximum carries it through the run.  A second sort, on the lane
+    number with both bounds riding, takes them home to the probe's order
+    (the probe's lanes first): the chip reads a scatter of as many lanes at
+    8 ns a lane and bound, this sort at ~3.5 for both (PERF.md section 6,
+    PR 44)."""
+    nr, nl = bh.shape[0], ph.shape[0]
+    lane = jnp.arange(nr + nl, dtype=jnp.int32)
+    h_s, lane_s = jax.lax.sort(
+        [jnp.concatenate([bh, ph]), lane], num_keys=2, is_stable=False)
+    is_b = lane_s < nr
+    cb = jnp.cumsum(is_b.astype(jnp.int32))
+    first = jnp.concatenate([jnp.ones((1,), jnp.bool_), h_s[1:] != h_s[:-1]])
+    lo_s = jax.lax.cummax(jnp.where(first, cb - is_b, 0))
+    home = jnp.where(is_b, lane_s + nl, lane_s - nr)
+    _, lo, hi = jax.lax.sort([home, lo_s, cb], num_keys=1, is_stable=False)
+    return lo[:nl].astype(jnp.int64), hi[:nl].astype(jnp.int64)
+
+
+def expand_rows(ends: jnp.ndarray, C: int):
+    """Row i yields ends[i] - ends[i-1] output lanes (`ends` an inclusive
+    running sum): (row, offset in row) of each of `C` output lanes, the row
+    clipped to the last one past the total.  The row of lane j is
+    searchsorted(ends, j, "right") — a question about a sorted iota, so no
+    sort answers it: it is the LAST row that starts at or before j.  Each
+    row's number is scattered to its start (of the rows that share a start,
+    empty ones and the row after them, the last) and a running maximum
+    carries it over the row's lanes; the running maximum of the marked
+    positions is the row's start.  Few output lanes against many rows take
+    the binary search (`rank_form`).  Shared by equi_join's expansion and
+    unnest_expand."""
+    n = ends.shape[0]
+    j = jnp.arange(C, dtype=jnp.int64)
+    if rank_form(n, C) == "scan":
+        row = jnp.minimum(searchsorted_tpu(ends, j, side="right"), n - 1)
+        row = row.astype(jnp.int32)
+        start = jnp.where(row > 0, jnp.take(ends, jnp.maximum(row - 1, 0)), 0)
+        return row, j - start
+    i = jnp.arange(n, dtype=jnp.int32)
+    starts = jnp.concatenate([jnp.zeros((1,), ends.dtype), ends[:-1]])
+    last = (ends != starts) | (i == n - 1)
+    at = jnp.where(last & (starts < C), starts, C).astype(jnp.int32)
+    mark = jnp.full((C,), -1, jnp.int32).at[at].set(i, mode="drop")
+    row = jax.lax.cummax(mark)
+    start = jax.lax.cummax(
+        jnp.where(mark >= 0, jnp.arange(C, dtype=jnp.int32), 0))
+    return row, j - start.astype(jnp.int64)
 
 
 def _segment_sum(values: jnp.ndarray, seg: jnp.ndarray, num: int) -> jnp.ndarray:
@@ -1446,8 +1521,8 @@ def equi_join(
     residual: Optional[Callable[[list[ColumnVal], int], jnp.ndarray]],
     out_capacity: int,
 ):
-    """Sort + searchsorted equi-join.  kind: inner | left | semi | anti |
-    null_anti.
+    """Sort equi-join.  kind: inner | left | full | semi | anti | null_anti |
+    mark | mark_in.
 
     inner/left -> (out_cols, out_live, required) with capacity
       out_capacity (+ n_left extra lanes for left-join unmatched rows).
@@ -1470,18 +1545,28 @@ def equi_join(
     nr = right_live.shape[0]
     C = out_capacity
 
+    rank = rank_form(nr, nl)
+
     def _sort_lohi():
         bh = _combined_hash(right_keys, right_live, nr, _SENT_BUILD)
         ph = _combined_hash(left_keys, left_live, nl, _SENT_PROBE)
         iota_r = jnp.arange(nr, dtype=jnp.int32)
         bh_sorted, pb = jax.lax.sort([bh, iota_r], num_keys=1)
-        l = searchsorted_tpu(bh_sorted, ph, side="left").astype(jnp.int64)
-        h = searchsorted_tpu(bh_sorted, ph, side="right").astype(jnp.int64)
+        if rank == "scan":
+            l = searchsorted_tpu(bh_sorted, ph, side="left").astype(jnp.int64)
+            h = searchsorted_tpu(bh_sorted, ph, side="right").astype(jnp.int64)
+        else:
+            l, h = _merged_bounds(bh, ph)
         return l, h, pb
+
+    from .kernels import record_dispatch
 
     hashed = _hash_join_gids(
         left_keys, right_keys, left_live, right_live, nl, nr
     )
+    record_dispatch(
+        "join_rank", "pallas" if hashed is not None else rank,
+        f"{nr} ++ {nl} lanes -> C {C}")
     if hashed is not None:
         h_ok, h_lo, h_hi, h_perm = hashed
         lo, hi, perm_b = jax.lax.cond(
@@ -1494,10 +1579,7 @@ def equi_join(
     total = cum[-1]
 
     j = jnp.arange(C, dtype=jnp.int64)
-    pidx = searchsorted_tpu(cum, j, side="right").astype(jnp.int32)
-    pidx_c = jnp.minimum(pidx, nl - 1)
-    start = jnp.take(cum, pidx_c) - jnp.take(counts, pidx_c)
-    k = j - start
+    pidx_c, k = expand_rows(cum, C)
     bpos = jnp.take(lo, pidx_c).astype(jnp.int64) + k
     bpos_c = jnp.clip(bpos, 0, nr - 1).astype(jnp.int32)
     bidx = jnp.take(perm_b, bpos_c)
@@ -1960,11 +2042,8 @@ def unnest_expand(
 
     ends = jnp.cumsum(row_lens)  # inclusive scan
     total = ends[-1] if n else jnp.int64(0)
-    starts = ends - row_lens
     j = jnp.arange(C, dtype=jnp.int64)
-    src = searchsorted_tpu(ends, j, side="right")
-    src_c = jnp.clip(src, 0, max(n - 1, 0)).astype(jnp.int32)
-    pos = j - jnp.take(starts, src_c)
+    src_c, pos = expand_rows(ends, C)
     out_live = j < total
 
     out_cols: list[ColumnVal] = []
