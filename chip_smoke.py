@@ -52,12 +52,10 @@ REPO = os.path.dirname(os.path.abspath(__file__))
 # q18 for ~20, each again on the served path whose fragments are different
 # programs.  Inside 1200 s, compilation included, fit: q06 and q01 (the
 # fused scan kernel, no big sort) and q12 (a 6M-row sort join, a group-by,
-# the segment-reduce kernel).  The queries that select the hash-join,
-# hash-aggregation and radix top-k kernels through SQL (q05/q10/q11: nation
-# and region build sides; q07/q08: small non-dictionary group-bys; q18:
-# TopN over 8M lanes) run behind --queries, with a longer time limit; the
-# default run drives those kernels on the chip directly, at SF1 shapes,
-# against numpy (kernels_phase).
+# the segment-reduce kernel).  The query that selects the radix top-k kernel
+# through SQL (q18: TopN over 8M lanes) runs behind --queries, with a longer
+# time limit; the default run drives that kernel and the segment reduction on
+# the chip directly, at SF1 shapes, against numpy (kernels_phase).
 QUERY_NAMES = ("q06", "q01", "q12")
 HEAVY_QUERY_NAMES = ("q05", "q08", "q03", "q18")
 DIST_QUERY_NAMES = ("q01", "q12")
@@ -65,8 +63,6 @@ DIST_QUERY_NAMES = ("q01", "q12")
 # dispatch op (ops/kernels.py) -> the kernel module it selects
 KERNEL_FILES = {
     "fused_pipeline": "fused.py",
-    "group_by": "hashagg.py",
-    "join": "hashjoin.py",
     "segment_reduce": "segreduce.py",
     "top_n": "topk.py",
 }
@@ -227,15 +223,14 @@ def kernels_phase(scale: float, ran: dict) -> None:
     import numpy as np
 
     from trino_tpu.connectors.tpch import tpch_data
-    from trino_tpu.ops.pallas import hashagg, hashjoin, topk
+    from trino_tpu.ops.pallas import topk
     from trino_tpu.ops.pallas.segreduce import SegRed, fused_segment_reduce
 
     li = tpch_data("lineitem", scale)
-    n = len(li["l_quantity"])
-    qty = (np.asarray(li["l_quantity"]) // 100).astype(np.int32)  # 1..50
     lnum = np.asarray(li["l_linenumber"]).astype(np.int32)  # 1..7
     price = np.asarray(li["l_extendedprice"]).astype(np.int64)
     ship = np.asarray(li["l_shipdate"]).astype(np.int32)
+    n = len(lnum)
     live = np.ones((n,), np.bool_)
     live[::97] = False
 
@@ -249,42 +244,7 @@ def kernels_phase(scale: float, ran: dict) -> None:
         say(f"[kernels] {name}: n {n} cold {cold:.2f}s warm {warm:.4f}s")
         return jax.device_get(out)
 
-    d_qty, d_lnum, d_live = jnp.asarray(qty), jnp.asarray(lnum), jnp.asarray(live)
-
-    # hash build (group-by and join build): 350 distinct (linenumber, qty)
-    gid, _table, n_groups, overflow = timed(
-        "hashagg.build_hash_table 2 words cap 2048",
-        jax.jit(lambda a, b, lv: hashagg.build_hash_table([a, b], lv, 2048)),
-        d_lnum, d_qty, d_live,
-    )
-    key = lnum.astype(np.int64) * 64 + qty
-    pairs = np.unique(np.stack([gid[live].astype(np.int64), key[live]]), axis=1)
-    want_groups = len(np.unique(key[live]))
-    if (bool(overflow) or int(n_groups) != want_groups
-            or pairs.shape[1] != want_groups or (gid[~live] != -1).any()
-            or gid[live].min() != 0 or gid[live].max() != want_groups - 1):
-        raise RuntimeError("hashagg.build_hash_table disagrees with numpy")
-    ran.setdefault("group_by", "kernels phase")
-
-    # hash probe: 25 even keys built, quantities 1..50 probed (half miss)
-    bkeys = np.arange(0, 50, 2, dtype=np.int32)
-
-    def build_and_probe(bk, pk, lv):
-        bgid, table, _n, ovb = hashagg.build_hash_table(
-            [bk], jnp.ones(bk.shape, jnp.bool_), 32
-        )
-        pgid, unres = hashjoin.probe_hash_table([pk], lv, table)
-        return bgid, pgid, ovb | unres
-
-    bgid, pgid, bad = timed(
-        "hashjoin.probe_hash_table 1 word table 512",
-        jax.jit(build_and_probe), jnp.asarray(bkeys), d_qty, d_live,
-    )
-    lookup = np.full((64,), -1, np.int64)
-    lookup[bkeys] = bgid
-    if bool(bad) or (pgid != np.where(live, lookup[qty], -1)).any():
-        raise RuntimeError("hashjoin.probe_hash_table disagrees with numpy")
-    ran.setdefault("join", "kernels phase")
+    d_live = jnp.asarray(live)
 
     # segment reduce: exact int64 sum, count, i32 min/max over 7 segments
     seg = np.where(live, lnum - 1, 7).astype(np.int32)

@@ -216,27 +216,27 @@ try:
     assert any(q["query_id"] == qid for q in listing2), "history not listed"
     print(f"/v1/query/{qid} after expiry: served from history ok")
 
-    # data-plane kernel dispatch: a GROUP BY over a non-dictionary key run
-    # in interpret mode must select the Pallas hash kernel — visible both
-    # as an EXPLAIN ANALYZE `-- kernel:` footer line and as a
-    # trino_tpu_kernel_dispatch_total{op="group_by",impl="pallas"} count
+    # data-plane kernel dispatch: a GROUP BY over a non-dictionary key
+    # takes the sorted group-by — visible both as an EXPLAIN ANALYZE
+    # `-- kernel:` footer line and as a
+    # trino_tpu_kernel_dispatch_total{op="group_by",impl="sort"} count
     from trino_tpu.ops import kernels as _kernels
     from trino_tpu.runtime.engine import Engine
 
     eng = Engine()
     eng.register_catalog("tpch", TpchConnector(0.01))
     eng.session.set("pallas_interpret", "true")
-    before = _kernels._DISPATCH.value("group_by", "pallas")
+    before = _kernels._DISPATCH.value("group_by", "sort")
     krows = eng.execute(
         "EXPLAIN ANALYZE select l_suppkey, sum(l_extendedprice) "
         "from lineitem group by l_suppkey"
     )
     ktext = "\n".join(str(r[0]) for r in krows)
     klines = [ln for ln in ktext.splitlines() if ln.startswith("-- kernel:")]
-    assert any("pallas group_by" in ln for ln in klines), (
-        f"expected a Pallas group_by dispatch line: {klines}"
+    assert any("sort group_by" in ln for ln in klines), (
+        f"expected a sort group_by dispatch line: {klines}"
     )
-    after = _kernels._DISPATCH.value("group_by", "pallas")
+    after = _kernels._DISPATCH.value("group_by", "sort")
     assert after > before, "kernel dispatch counter did not move"
     print(f"kernel dispatch: {klines[0]} (counter {before:.0f} -> {after:.0f})")
 

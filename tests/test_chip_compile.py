@@ -1,11 +1,10 @@
 """Chipless TPU compiles of the main-path Pallas kernels at TPC-H SF1 shapes.
 
 Interpret mode (tests/test_pallas*.py) proves what the kernels compute; it
-cannot show what the chip's compiler refuses.  Before PR 22 both hash kernels
-were refused by Mosaic (an f64 scalar out of a bool reduction, an i1 reshape,
-an i1 while-loop carry), the hash build took 230 s to compile, the fused scan
+cannot show what the chip's compiler refuses.  Before PR 22 the fused scan
 kernel aborted the compiler on a keyless recipe and a wide segment reduce
-outgrew VMEM — all invisible to every interpreted test.  The TPU compiler is
+outgrew VMEM (and two hash kernels, gone since PR 45, were refused by Mosaic
+outright) — all invisible to every interpreted test.  The TPU compiler is
 installed here and compiles for a chip that is described, not attached, so
 these tests guard every later PR at no chip time.  Nothing runs: results are
 the interpreted tests' business.
@@ -55,7 +54,7 @@ def _compile(fn, *shapes, kernel):
     """AOT-compile for the described chip -> (seconds, the compiled program);
     the kernel must be in the program as a Mosaic custom call that carries
     its name (`name=` on the pallas_call: a profiler trace then says
-    `%hash_agg.1`, not `%call.130`)."""
+    `%seg_reduce.1`, not `%call.130`)."""
     t0 = time.perf_counter()
     compiled = jax.jit(fn).lower(*shapes).compile()
     seconds = time.perf_counter() - t0
@@ -67,35 +66,6 @@ def _compile(fn, *shapes, kernel):
 
 def _col(one_chip, dtype, n=N_SF1):
     return jax.ShapeDtypeStruct((n,), dtype, sharding=one_chip)
-
-
-@pytest.mark.parametrize("n_words,cap", [(2, 2048), (6, 4096)])
-def test_hash_build_compiles_for_v5e(one_chip, n_words, cap):
-    from trino_tpu.ops.pallas import hashagg
-
-    seconds, _ = _compile(
-        lambda live, *w: hashagg.build_hash_table(list(w), live, cap),
-        _col(one_chip, jnp.bool_),
-        *[_col(one_chip, jnp.int32)] * n_words,
-        kernel="hash_agg",
-    )
-    # the python-unrolled kernel compiled, in 230 s; the loops keep it small
-    assert seconds < 60, f"hash build compile took {seconds:.0f}s"
-
-
-@pytest.mark.parametrize("n_words,cap", [(2, 2048), (6, 4096)])
-def test_hash_probe_compiles_for_v5e(one_chip, n_words, cap):
-    from trino_tpu.ops.pallas import hashagg, hashjoin
-
-    table = jax.ShapeDtypeStruct(
-        (16, hashagg.table_size(cap)), jnp.float32, sharding=one_chip
-    )
-    _compile(
-        lambda live, tbl, *w: hashjoin.probe_hash_table(list(w), live, tbl),
-        _col(one_chip, jnp.bool_), table,
-        *[_col(one_chip, jnp.int32)] * n_words,
-        kernel="hash_join_probe",
-    )
 
 
 def test_segment_reduce_compiles_for_v5e(one_chip):

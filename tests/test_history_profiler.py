@@ -1,12 +1,13 @@
 """Profiling & query-history plane: the compile profiler's per-signature
 ledger (utils/profiler.py), the phase ledger on the query state machine,
 the bounded persistent history store (runtime/history.py) with its
-/v1/query surface and post-expiry fallback, and the perf-regression /
-metrics-lint gates (scripts/perf_gate.py, scripts/metrics_lint.py)."""
+/v1/query surface and post-expiry fallback, and the scripts an operator
+reads them with (scripts/trace_dump.py, scripts/metrics_lint.py)."""
 
 import importlib.util
 import json
 import os
+import sys
 import urllib.error
 import urllib.request
 
@@ -192,55 +193,62 @@ def test_local_explain_analyze_profile_footer():
     assert "-- compile: " in text  # named jit signature attribution
 
 
-# ------------------------------------------------------------- perf gate
+# ------------------------------------------------------------ trace dump
 
 
-def test_perf_gate_new_regression_fails():
-    gate = _load_script("perf_gate")
-    old = {"queries": {"q1": {"wall_s": 1.0}}, "warm_regressions": []}
-    new = {
-        "queries": {"q1": {"wall_s": 1.1}},
-        "warm_regressions": [{"query": "q1", "warm_s": 300.0, "bound": 240.0}],
-    }
-    failures = gate.compare(old, new)
-    assert len(failures) == 1 and "q1" in failures[0]
-    # already-known regressions don't re-fail; missing old field == empty
-    assert gate.compare(new, new) == []
-    assert gate.compare({"queries": {}}, new)  # old predates the field
+def _spans():
+    """A coordinator's `query` root with a `schedule` child, a worker's
+    `task` root whose parent is that child, and a root of another trace."""
+    query = {"trace_id": "t1", "span_id": "q", "parent_id": None, "name": "query",
+             "duration_ms": 100.0, "attributes": {"query_id": "q_1"},
+             "children": [{"trace_id": "t1", "span_id": "s", "parent_id": "q",
+                           "name": "schedule", "duration_ms": 40.0}]}
+    task = {"trace_id": "t1", "span_id": "k", "parent_id": "s", "name": "task",
+            "duration_ms": 30.0, "attributes": {"task_id": "q_1.0.0", "worker": "w1"}}
+    other = {"trace_id": "t2", "span_id": "o", "parent_id": "gone", "name": "query",
+             "duration_ms": 5.0}
+    return query, task, other
 
 
-def test_perf_gate_wall_ratio():
-    gate = _load_script("perf_gate")
-    old = {"queries": {"q1": {"wall_s": 1.0}, "q2": {"wall_s": 0.001}}}
-    new = {"queries": {"q1": {"wall_s": 2.0}, "q2": {"wall_s": 0.01}}}
-    failures = gate.compare(old, new)
-    # q1 doubled (gated); q2 is sub-50ms jitter (ignored)
-    assert len(failures) == 1 and "q1" in failures[0]
-    assert gate.compare(old, old) == []
+def test_trace_dump_nests_a_remote_span_under_its_parent():
+    dump = _load_script("trace_dump")
+    query, task, other = _spans()
+    traces = dump.stitch([task, query, other])  # the worker's line came first
+    assert set(traces) == {"t1", "t2"}
+    (root,) = traces["t1"]["spans"]
+    assert root is query and traces["t1"]["total_ms"] == 100.0
+    (schedule,) = root["children"]
+    assert schedule["children"] == [task]  # under the nested child, by parent_id
+    # a parent no exported line holds: the span stays a root of its trace
+    assert traces["t2"]["spans"] == [other]
 
 
-def test_perf_gate_on_recorded_bench_runs(tmp_path):
-    """The gate's file surface (main + the driver's {parsed: ...} wrapper)
-    over two small records: a q03 wall regression and a clean pair."""
-    import json
+def test_trace_dump_skips_torn_lines(tmp_path):
+    dump = _load_script("trace_dump")
+    query, task, _other = _spans()
+    path = tmp_path / "trace.jsonl"
+    path.write_text(json.dumps(query) + "\n\n" + json.dumps(task)[:25] + "\n"
+                    + json.dumps(task) + "\n")
+    assert dump.load_roots(str(path)) == [query, task]
 
-    gate = _load_script("perf_gate")
 
-    def record(name, q03_wall):
-        path = tmp_path / name
-        path.write_text(json.dumps({"n": 1, "rc": 0, "parsed": {
-            "sf": 1.0, "device": "tpu",
-            "queries": {"q01": {"wall_s": 0.116},
-                        "q03": {"wall_s": q03_wall},
-                        "q18": {"skipped": "deadline"}},
-        }}))
-        return str(path)
-
-    old = record("old.json", 1.38)
-    new = record("new.json", 2.9)  # q03 more than 1.5x slower
-    assert gate.main([old, new]) == 2
-    assert gate.main([old, old]) == 0
-    assert gate.main([new, new]) == 0
+def test_trace_dump_prints_one_flame_a_trace(tmp_path, capsys, monkeypatch):
+    dump = _load_script("trace_dump")
+    path = tmp_path / "trace.jsonl"
+    path.write_text("".join(json.dumps(s) + "\n" for s in _spans()))
+    monkeypatch.setattr(sys, "argv", ["trace_dump.py", str(path), "--trace", "t1"])
+    assert dump.main() == 0
+    out = capsys.readouterr().out
+    assert "=== trace t1  (1 root span(s), 100.0 ms wall)" in out and "t2" not in out
+    lines = [l for l in out.splitlines() if " ms " in l and "===" not in l]
+    # query, then schedule under it, then the worker's task under schedule
+    assert [l.split("# ")[-1].strip() for l in lines] == [
+        "query q_1", "schedule", "task q_1.0.0 w1"]
+    assert [l.index(" ms ") for l in lines] == [10, 12, 14]  # two columns a level
+    assert " 30.0%" in lines[2]  # of the trace's wall, not of its parent
+    monkeypatch.setattr(sys, "argv", ["trace_dump.py", str(path), "--trace", "none"])
+    assert dump.main() == 1
+    assert "no traces found" in capsys.readouterr().err
 
 
 # ----------------------------------------------------------- metrics lint
@@ -269,6 +277,26 @@ def test_metrics_lint_brace_expansion_and_help(tmp_path):
     failures = mlint.lint([str(bad)], str(readme))
     assert any("no HELP" in f for f in failures)
     assert any("trino_tpu_c_total" in f for f in failures)
+
+
+def test_metrics_lint_exit_codes_and_undocumented_family(tmp_path, capsys):
+    """`main`'s contract: 0 and an ok line over a clean scrape, 2 and a FAIL
+    line a problem — here the third class, a family the README omits."""
+    mlint = _load_script("metrics_lint")
+    readme = tmp_path / "README.md"
+    readme.write_text('counts `trino_tpu_a_total{state="x"}`')
+    a = "# HELP trino_tpu_a_total a\n# TYPE trino_tpu_a_total counter\n"
+    good = tmp_path / "good.prom"
+    good.write_text(a + "# HELP process_cpu_seconds_total cpu\n")
+    assert mlint.main(["--readme", str(readme), str(good)]) == 0
+    assert "metrics_lint: ok (1 target(s))" in capsys.readouterr().out
+    extra = tmp_path / "extra.prom"
+    extra.write_text(a + "# HELP trino_tpu_z_total z\n# TYPE trino_tpu_z_total counter\n")
+    assert mlint.main(["--readme", str(readme), str(good), str(extra)]) == 2
+    out = capsys.readouterr().out
+    assert "FAIL trino_tpu_z_total is exposed but the README does not document it" in out
+    assert "metrics_lint: 1 problem(s)" in out
+    assert mlint.main(["--readme", str(readme), str(tmp_path / "missing.prom")]) == 2
 
 
 # ------------------------------------------------- cluster integration

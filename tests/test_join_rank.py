@@ -145,6 +145,13 @@ def _join_page(case: str, rng):
         rl = np.zeros(nr, bool)
     elif case == "collisions":  # eight hashes for forty keys: the verification sorts them out
         C = 8192
+    elif case == "small_build":
+        # the shape the hash kernel took until PR 45 (a build side of a few
+        # dozen rows), on the path that takes it now: nullable keys, dead
+        # lanes on both sides
+        nr, keys = 40, 30
+        valid_l, valid_r = rng.random(nl) < 0.85, rng.random(nr) < 0.85
+        ll, rl = rng.random(nl) < 0.8, rng.random(nr) < 0.8
     else:
         assert case == "plain"
     lk, rk = ColumnVal(kv(nl, keys), valid_l, None, BIGINT), ColumnVal(kv(nr, keys), valid_r, None, BIGINT)
@@ -159,13 +166,13 @@ def _join_page(case: str, rng):
 KINDS = ("inner", "left", "full", "semi", "anti", "null_anti", "mark", "mark_in")
 
 
-@pytest.mark.parametrize("case", ["plain", "null_keys", "overflow", "empty_build", "collisions"])
+@pytest.mark.parametrize("case", [
+    "plain", "null_keys", "overflow", "empty_build", "collisions", "small_build"])
 @pytest.mark.parametrize("kind", KINDS)
 def test_every_kind_is_the_parents_join(kind, case, monkeypatch):
     lc, ll, rc, rl, lk, rk, C = _join_page(case, np.random.default_rng(46))
     if case == "collisions":
         monkeypatch.setattr(relops, "_mix64", lambda x: x.astype(jnp.uint64) & jnp.uint64(7))
-    kernels.set_policy(kernels.KernelPolicy(enabled=False))  # the sort path
     residual = None
     if kind in ("inner", "left", "full"):  # a non-equi conjunct over the expansion frame
         residual = lambda cols, n: cols[1].data % 3 != 0  # noqa: E731
@@ -246,7 +253,6 @@ def _traced_join(nr, nl, C):
         return [c.data for c in cols], live, required
 
     s = lambda n, dt: jax.ShapeDtypeStruct((n,), dt)  # noqa: E731
-    kernels.set_policy(kernels.KernelPolicy(enabled=False))
     events = kernels.begin_capture()
     try:
         jaxpr = jax.make_jaxpr(call)(
@@ -254,16 +260,26 @@ def _traced_join(nr, nl, C):
             s(nr, jnp.int64), s(nr, jnp.int32), s(nr, jnp.bool_))
     finally:
         kernels.end_capture()
+    assert [e for e in events if e[0] == "join"] == [("join", "sort", f"build {nr}")]
     return jaxpr.jaxpr, [e for e in events if e[0] == "join_rank"]
 
 
-# (nr, nl, C): SF10 q18's Join#5 and Join#8, SF10 q12's Join#3, Q9's Join#10, SF1 q12's Join#3
+# (nr, nl, C): SF10 q18's Join#5 and Join#8, SF10 q12's Join#3, Q9's Join#10, SF1 q12's Join#3;
+# then the three shapes the hash kernel took until PR 45 (frames as the cells' plans trace at
+# benchmarks/caps/'s tiers) — SF1 q18's Join#8 (orders against the 2,048-lane frame of the 63
+# orderkeys that pass), Q9's Join#7 (an 8.4M-lane frame x nation), Q5's Join#9 (nation x
+# region) — and SF1 q18's Join#5 (x lineitem) and Join#6 (x customer)
 @pytest.mark.parametrize("nr,nl,C,bounds,expansion", [
     (60_000_466, 4_096, 16_384, "scan", "merged"),
     (4_096, 15_000_000, 4_096, "merged", "scan"),
     (1_048_576, 15_000_000, 1_048_576, "merged", "merged"),
     (8_000_000, 60_000_466, 67_108_864, "merged", "merged"),
     (65_536, 1_500_000, 65_536, "merged", "merged"),
+    (2_048, 1_500_000, 2_048, "merged", "scan"),
+    (25, 8_388_608, 8_388_608, "merged", "merged"),
+    (5, 25, 32, "merged", "merged"),
+    (6_002_367, 2_048, 2_048, "scan", "merged"),
+    (150_000, 2_048, 2_048, "merged", "merged"),
 ])
 def test_what_the_sort_path_traces_to(nr, nl, C, bounds, expansion):
     jaxpr, events = _traced_join(nr, nl, C)
@@ -289,20 +305,21 @@ def test_the_parent_traced_to_seven_sorts(monkeypatch):
     assert _count(jaxpr, "sort") == 7 and _count(jaxpr, "scatter") == 6
 
 
-def test_a_kernel_join_says_pallas_and_shares_the_expansion():
-    rng = np.random.default_rng(48)
-    nl, nr, C = 600, 64, 1024
-    lk = [ColumnVal(jnp.asarray(rng.integers(0, 50, nl)), None, None, BIGINT)]
-    rk = [ColumnVal(jnp.asarray(rng.integers(0, 50, nr)), None, None, BIGINT)]
-    kernels.set_policy(kernels.KernelPolicy(enabled=True, interpret=True))
-    events = kernels.begin_capture()
-    try:
-        relops.equi_join("semi", lk, jnp.ones(nl, bool), rk, jnp.ones(nr, bool), lk, rk, None, C)
-    finally:
-        kernels.end_capture()
-        kernels.set_policy(kernels.KernelPolicy(enabled=False))
-    assert [e[:2] for e in events if e[0] in ("join", "join_rank")] == [
-        ("join", "pallas"), ("join_rank", "pallas")]
+def test_a_small_build_traces_one_branch_and_no_kernel():
+    """A build side under the former gate (2,048 lanes) with every kernel
+    switched to interpret: no `cond` (the hash kernel's runtime guard compiled
+    the sort path beside it), no `pallas_call`, one `join` event."""
+    jaxprs = {}
+    for policy in (kernels.KernelPolicy(), kernels.KernelPolicy(enabled=True, interpret=True)):
+        kernels.set_policy(policy)
+        try:
+            jaxprs[policy.interpret], events = _traced_join(64, 600, 1024)
+        finally:
+            kernels.set_policy(kernels.KernelPolicy())
+        assert events == [("join_rank", "merged", "64 ++ 600 lanes -> C 1024")]
+        assert _count(jaxprs[policy.interpret], "cond") == 0
+        assert _count(jaxprs[policy.interpret], "pallas_call") == 0
+    assert str(jaxprs[True]) == str(jaxprs[False])  # the policy moves nothing in a join
 
 
 # ------------------------------------------------------------------ (d)

@@ -207,98 +207,3 @@ def test_engine_q1_through_pallas_interpreter(tpch_tiny, oracle):
         assert_rows_equal(got, want, ordered=True, rtol=1e-6)
     finally:
         segreduce.INTERPRET = False
-
-
-# ---------------------------------------------------- hash-table kernels
-
-
-def _np_partition(keys_tuples, gid, live):
-    """key tuple -> set of row indices, built from a gid assignment."""
-    by_gid = {}
-    for i, g in enumerate(gid):
-        if not live[i]:
-            assert g == -1
-            continue
-        by_gid.setdefault(int(g), set()).add(i)
-    return {frozenset(v) for v in by_gid.values()}
-
-
-def test_hash_build_partitions_like_unique(rng):
-    from trino_tpu.ops.pallas import hashagg
-
-    n = 20000
-    live = rng.rand(n) > 0.2
-    w0 = (rng.randint(0, 1 << 31, size=n) % 53).astype(np.int32)
-    w1 = ((w0 * 7 + 11) % 97).astype(np.int32)  # correlated second word
-    gid, table, n_groups, overflow = hashagg.build_hash_table(
-        [jnp.asarray(w0), jnp.asarray(w1)], jnp.asarray(live), 256,
-        interpret=True,
-    )
-    gid = np.asarray(gid)
-    assert not bool(overflow)
-    keys = list(zip(w0.tolist(), w1.tolist()))
-    want = {
-        frozenset(i for i in range(n) if live[i] and keys[i] == k)
-        for k in {keys[i] for i in range(n) if live[i]}
-    }
-    assert _np_partition(keys, gid, live) == want
-    assert int(n_groups) == len(want)
-    lg = gid[live]
-    assert lg.min() >= 0 and lg.max() == len(want) - 1  # dense claim ids
-
-
-def test_hash_build_overflow_sets_flag(rng):
-    from trino_tpu.ops.pallas import hashagg
-
-    n = 4096
-    w = np.arange(n, dtype=np.int32)  # every row distinct
-    gid, table, n_groups, overflow = hashagg.build_hash_table(
-        [jnp.asarray(w)], jnp.ones(n, bool), 512, interpret=True
-    )
-    assert bool(overflow)
-    assert int(n_groups) > 512  # inflated count drives the caller's retry
-
-
-def test_hash_probe_hits_and_misses(rng):
-    from trino_tpu.ops.pallas import hashagg, hashjoin
-
-    nb, npr = 500, 6000
-    bw = rng.randint(0, 300, size=nb).astype(np.int32)
-    b_live = rng.rand(nb) > 0.1
-    gid_b, table, n_groups, overflow = hashagg.build_hash_table(
-        [jnp.asarray(bw)], jnp.asarray(b_live), 1024, interpret=True
-    )
-    assert not bool(overflow)
-    gid_b = np.asarray(gid_b)
-    key_gid = {int(bw[i]): int(gid_b[i]) for i in range(nb) if b_live[i]}
-
-    pw = rng.randint(0, 600, size=npr).astype(np.int32)  # half miss
-    p_live = rng.rand(npr) > 0.1
-    gid_p, unresolved = hashjoin.probe_hash_table(
-        [jnp.asarray(pw)], jnp.asarray(p_live), table, interpret=True
-    )
-    assert not bool(unresolved)
-    gid_p = np.asarray(gid_p)
-    for i in range(npr):
-        want = key_gid.get(int(pw[i]), -1) if p_live[i] else -1
-        assert int(gid_p[i]) == want, (i, int(pw[i]))
-
-
-def test_hash_build_full_load_collision_stress(rng):
-    # cap == distinct count: the table runs at its max load factor, so long
-    # probe chains and cross-chunk slot races all occur
-    from trino_tpu.ops.pallas import hashagg
-
-    n = 16384
-    uniq = rng.randint(-(1 << 31), 1 << 31, size=2048).astype(np.int32)
-    w = uniq[rng.randint(0, 2048, size=n)]
-    gid, table, n_groups, overflow = hashagg.build_hash_table(
-        [jnp.asarray(w)], jnp.ones(n, bool), 2048, interpret=True
-    )
-    assert not bool(overflow)
-    assert int(n_groups) == len(set(w.tolist()))
-    gid = np.asarray(gid)
-    seen = {}
-    for i in range(n):
-        k = int(w[i])
-        assert seen.setdefault(k, int(gid[i])) == int(gid[i])

@@ -1,16 +1,14 @@
-"""Differential tests: Pallas hash data-plane kernels vs the sort oracle.
+"""`ops/relops.py` above the Pallas kernels that are left (PR 45: the hash
+group-by and hash join kernels went; joins and keyed group-bys sort).
 
-Every case runs the SAME relops entry point twice — once with the kernel
-policy disabled (legacy sort path, the oracle) and once with kernels enabled
-in interpret mode — and asserts identical results.  Group output order is a
-deliberate non-guarantee (the engine's Aggregate output is unordered until a
-Sort), so group-by comparisons align rows by key; join comparisons align by
-full output row.
-
-Covers the satellite checklist: nulls in keys and arguments, dictionary-
-coded keys, decimal128 limb aggregation, empty/all-filtered inputs, hash-
-collision stress near table capacity, the overflow-to-sort fallback
-boundary, and the session kill-switch restoring the legacy path.
+The sorted group-by against a plain Python reference over the live rows —
+nulls in keys and arguments, dictionary-coded keys, decimal128 limb
+aggregation, empty and all-filtered inputs, a frame nearly full and one
+overflowed — what it traces to (sorts, operands, no scatter, no hash
+kernel) and what EXPLAIN ANALYZE says of it; then the fused scan
+(`ops/pallas/fused.py`) against the operator-at-a-time path the session's
+`data_plane_kernels` switch restores: plans, scatter forms, resident operands,
+parameters.
 """
 
 import decimal
@@ -41,172 +39,12 @@ def _cv(data, valid=None, dict_=None, typ=None, data2=None):
     )
 
 
-def _norm_groups(out):
-    """Group rows keyed/sorted by key tuple: (keys..., aggs...) per live
-    group, order-independent."""
-    out_keys, out_aggs, out_live, n_groups = out
-    live = np.asarray(out_live)
-    rows = []
-    for g in range(live.shape[0]):
-        if not live[g]:
-            continue
-        row = []
-        for k in out_keys:
-            d, v = np.asarray(k[0])[g], k[1]
-            ok = True if v is None else bool(np.asarray(v)[g])
-            khi = k[2] if len(k) > 2 else None
-            if khi is not None:
-                full = int(np.asarray(khi)[g]) * (1 << 64) + int(np.uint64(d))
-                row.append((ok, full if ok else None))
-            else:
-                row.append((ok, d.item() if ok else None))
-        for a in out_aggs:
-            d = np.asarray(a[0])[g]
-            ok = True if a[1] is None else bool(np.asarray(a[1])[g])
-            if len(a) == 4:  # decimal128: (lo, valid, None, hi)
-                full = int(np.asarray(a[3])[g]) * (1 << 64) + int(
-                    np.uint64(d)
-                )
-                row.append((ok, full if ok else None))
-            else:
-                row.append((ok, round(float(d), 6) if ok else None))
-        rows.append(tuple(row))
-    return sorted(rows, key=repr), int(np.asarray(n_groups))
-
-
-def _compare_groupby(keys, args, specs, live, G, expect_impl="pallas"):
-    kernels.set_policy(kernels.KernelPolicy(enabled=False))
-    legacy = _norm_groups(
-        relops.group_aggregate(keys, args, specs, jnp.asarray(live), G)
-    )
-    kernels.set_policy(kernels.KernelPolicy(enabled=True, interpret=True))
-    ev = kernels.begin_capture()
-    try:
-        hashed = _norm_groups(
-            relops.group_aggregate(keys, args, specs, jnp.asarray(live), G)
-        )
-    finally:
-        kernels.end_capture()
-    impls = {e[1] for e in ev if e[0] == "group_by"}
-    assert hashed == legacy
-    if expect_impl is not None:
-        assert expect_impl in impls, (impls, ev)
-    return legacy
-
-
-def test_groupby_nulls_in_keys_and_args():
-    rng = np.random.default_rng(7)
-    n = 3000
-    keys = [
-        _cv(rng.integers(0, 40, n), None, None, BIGINT),
-        _cv(rng.integers(-5, 5, n).astype(np.int32),
-            rng.random(n) > 0.1, None, INTEGER),
-    ]
-    arg = _cv(rng.integers(-1000, 1000, n), rng.random(n) > 0.15, None, BIGINT)
-    specs = [AggSpec("sum"), AggSpec("count"), AggSpec("min"),
-             AggSpec("max"), AggSpec("avg"), AggSpec("count_star")]
-    live = rng.random(n) > 0.2
-    _compare_groupby(keys, [arg] * 5 + [None], specs, live, 1024)
-
-
-def test_groupby_dict_coded_keys():
-    from trino_tpu.data.page import Dictionary
-    from trino_tpu.data.types import VARCHAR
-
-    rng = np.random.default_rng(11)
-    n = 2000
-    d = Dictionary(np.asarray([f"v{i}" for i in range(30)], object))
-    keys = [
-        _cv(rng.integers(0, 30, n).astype(np.int32), None, d, VARCHAR),
-        # second key forces the general (non-direct-code) path; wide-
-        # magnitude values exercise both 16-bit word halves
-        _cv(rng.integers(0, 8, n) * ((1 << 37) + 12345), None, None, BIGINT),
-    ]
-    arg = _cv(rng.normal(0, 10, n), None, None, DOUBLE)
-    live = rng.random(n) > 0.3
-    _compare_groupby(keys, [arg, arg], [AggSpec("sum"), AggSpec("avg")],
-                     live, 1024)
-
-
-def test_groupby_decimal128_limb_sum():
-    rng = np.random.default_rng(13)
-    n = 1500
-    t = DecimalType(38, 2)
-    lo = rng.integers(-(1 << 62), 1 << 62, n)
-    hi = rng.integers(-4, 4, n)
-    keys = [_cv(rng.integers(0, 20, n), None, None, BIGINT)]
-    arg = _cv(lo, rng.random(n) > 0.1, None, t, data2=hi)
-    live = rng.random(n) > 0.2
-    _compare_groupby(keys, [arg], [AggSpec("sum", type=t)], live, 1024)
-
-
-def test_groupby_decimal128_keys():
-    rng = np.random.default_rng(17)
-    n = 1200
-    t = DecimalType(38, 0)
-    keys = [_cv(rng.integers(0, 25, n), None, None, t,
-                data2=rng.integers(-2, 2, n))]
-    arg = _cv(rng.integers(0, 100, n), None, None, BIGINT)
-    _compare_groupby(keys, [arg], [AggSpec("sum")], np.ones(n, bool), 1024)
-
-
-def test_groupby_empty_and_all_filtered():
-    rng = np.random.default_rng(19)
-    n = 1000
-    keys = [_cv(rng.integers(0, 10, n), None, None, BIGINT)]
-    arg = _cv(rng.integers(0, 100, n), None, None, BIGINT)
-    legacy = _compare_groupby(keys, [arg], [AggSpec("sum")],
-                              np.zeros(n, bool), 512)
-    assert legacy == ([], 0)
-
-
-def test_groupby_collision_stress_near_capacity():
-    # cap 512 -> table 1024 slots at 0.5 load: every slot's probe chain is
-    # exercised, duplicate keys race to claim the same slot across rounds
-    rng = np.random.default_rng(23)
-    n = 8192
-    uniq = rng.integers(-(1 << 60), 1 << 60, 500)
-    data = uniq[rng.integers(0, 500, n)]
-    keys = [_cv(data, None, None, BIGINT)]
-    arg = _cv(rng.integers(-50, 50, n), None, None, BIGINT)
-    _compare_groupby(keys, [arg, arg, None],
-                     [AggSpec("sum"), AggSpec("min"), AggSpec("count_star")],
-                     np.ones(n, bool), 512)
-
-
-def test_groupby_overflow_inflates_then_sorts():
-    """More distinct groups than the capacity tier: the kernel reports an
-    inflated n_groups (the executor's retry signal); the doubled tier then
-    succeeds and matches the oracle; a tier past the policy limit dispatches
-    the sort fallback."""
-    rng = np.random.default_rng(29)
-    n = 4000
-    data = rng.integers(0, 700, n)  # ~700 distinct > 512 cap
-    keys = [_cv(data, None, None, BIGINT)]
-    arg = _cv(rng.integers(0, 9, n), None, None, BIGINT)
-    kernels.set_policy(kernels.KernelPolicy(enabled=True, interpret=True))
-    out = relops.group_aggregate(keys, [arg], [AggSpec("sum")],
-                                 jnp.ones(n, bool), 512)
-    assert int(np.asarray(out[3])) > 512  # overflow -> retry signal
-    _compare_groupby(keys, [arg], [AggSpec("sum")], np.ones(n, bool), 1024)
-    # past the policy limit the gate must dispatch "fallback" (sort runs)
-    kernels.set_policy(kernels.KernelPolicy(
-        enabled=True, interpret=True, hash_agg_max_groups=512))
-    ev = kernels.begin_capture()
-    try:
-        relops.group_aggregate(keys, [arg], [AggSpec("sum")],
-                               jnp.ones(n, bool), 1024)
-    finally:
-        kernels.end_capture()
-    assert ("group_by", "fallback") in {(e[0], e[1]) for e in ev}
-
-
 # ------------------------------------------- the sorted group-by by itself
 #
-# What group_aggregate runs above the hash kernel's gate (and for every
-# value-sorted or host-collected aggregate): one sort that carries the
-# aggregated columns, one compaction of the group ends.  Each case forces
-# that path and is held to a plain Python reference over the live rows.
+# What group_aggregate runs for every keyed group-by the direct-code path
+# declines: one sort that carries the aggregated columns, one compaction of
+# the group ends.  Each case is held to a plain Python reference over the
+# live rows.
 
 
 def _u128(lo, hi):
@@ -253,6 +91,8 @@ def _reference_agg(spec, arg, arg2, rows):
         return None
     if spec.fn == "sum":
         return sum(vals)
+    if spec.fn == "avg":
+        return float(sum(vals)) / len(vals)
     if spec.fn == "percentile":
         return _nearest_rank(vals, spec.param)
     return {"min": min, "max": max}[spec.fn](vals)
@@ -375,6 +215,40 @@ def _sorted_case(case):
         keys = [_cv(rng.integers(0, 20, n).astype(np.int32), None, None, INTEGER)]
         args, specs = [arg, arg], [AggSpec("array_agg"), AggSpec("sum")]
         args2 = [None, None]
+    elif case == "dictionary_key_beside_a_wide_int64_key":
+        # the second key keeps the direct-code path out; its values pass 2^37
+        n = 2000
+        d = Dictionary(np.asarray([f"v{i}" for i in range(30)], object))
+        keys = [_cv(rng.integers(0, 30, n).astype(np.int32), None, d, VARCHAR),
+                _cv(rng.integers(0, 8, n) * ((1 << 37) + 12345), None, None, BIGINT)]
+        darg = _cv(rng.normal(0, 10, n), None, None, DOUBLE)
+        args, specs = [darg, darg], [AggSpec("sum"), AggSpec("avg")]
+        live = rng.random(n) > 0.3
+    elif case == "decimal128_limb_sum_with_null_arguments":
+        n = 1500
+        keys = [_cv(rng.integers(0, 20, n), None, None, BIGINT)]
+        args = [_cv(rng.integers(-(1 << 62), 1 << 62, n), rng.random(n) > 0.1,
+                    None, d38, data2=rng.integers(-4, 4, n))]
+        specs = [AggSpec("sum", type=d38)]
+        live = rng.random(n) > 0.2
+    elif case == "500_keys_in_a_512_frame":
+        n, G = 8192, 512
+        uniq = rng.integers(-(1 << 60), 1 << 60, 500)
+        keys = [_cv(uniq[rng.integers(0, 500, n)], None, None, BIGINT)]
+        small = _cv(rng.integers(-50, 50, n), None, None, BIGINT)
+        args = [small, small, None]
+        specs = [AggSpec("sum"), AggSpec("min"), AggSpec("count_star")]
+        live = np.ones(n, bool)
+    elif case == "six_aggregates_over_two_keys":
+        k2valid = rng.random(n) > 0.1
+        keys = [_cv(rng.integers(0, 40, n), None, None, BIGINT),
+                _cv(np.where(k2valid, rng.integers(-5, 5, n), 0).astype(np.int32),
+                    k2valid, None, INTEGER)]
+        narg = _cv(rng.integers(-1000, 1000, n), rng.random(n) > 0.15, None, BIGINT)
+        args = [narg] * 5 + [None]
+        specs = [AggSpec("sum"), AggSpec("count"), AggSpec("min"),
+                 AggSpec("max"), AggSpec("avg"), AggSpec("count_star")]
+        live = rng.random(n) > 0.2
     else:
         raise AssertionError(case)
     if len(args2) != len(args):
@@ -390,6 +264,9 @@ _SORTED_CASES = [
     "min_max_over_a_dictionary", "count_distinct",
     "two_value_sorted_aggregates", "approx_percentile",
     "moment_aggregate_with_arg2", "host_collected_aggregate",
+    "dictionary_key_beside_a_wide_int64_key",
+    "decimal128_limb_sum_with_null_arguments", "500_keys_in_a_512_frame",
+    "six_aggregates_over_two_keys",
 ]
 
 
@@ -410,9 +287,7 @@ def test_groupby_sorted_path_under_the_kernel_ceiling(case, monkeypatch):
 @pytest.mark.parametrize("case", _SORTED_CASES)
 def test_groupby_sorted_path_matches_reference(case, reducer=None):
     keys, args, args2, specs, live, G = _sorted_case(case)
-    # the gate under the frame: the hash kernel declines, the sort runs
-    kernels.set_policy(kernels.KernelPolicy(
-        enabled=True, interpret=True, hash_agg_max_groups=512))
+    kernels.set_policy(kernels.KernelPolicy(enabled=True, interpret=True))
     ev = kernels.begin_capture()
     try:
         out = relops.group_aggregate(
@@ -420,7 +295,7 @@ def test_groupby_sorted_path_matches_reference(case, reducer=None):
     finally:
         kernels.end_capture()
     (event,) = [e for e in ev if e[0] == "group_by"]
-    assert event[1] in ("fallback", "sort") and "sort carries" in event[2], ev
+    assert event[1] == "sort" and "sort carries" in event[2], ev
     assert {e[1] for e in ev if e[0] == "segment_reduce"} == (
         {reducer} if reducer else set()), ev
     got, n_groups = _decoded_groups(out, keys, args, specs)
@@ -452,7 +327,7 @@ def _primitives(jaxpr, acc=None):
 
 @pytest.mark.parametrize("nullable_key", [False, True], ids=["key", "nullable-key"])
 def test_groupby_sorted_path_holds_its_budget(nullable_key):
-    """One int32 key, one decimal(38,2) sum, above the gate: at most two
+    """One int32 key, one decimal(38,2) sum: at most two
     sorts (the group sort carrying the argument, the compaction of the group
     ends carrying the key and the running sum), no scatter, and no gather
     with n lanes on either side; a validity operand rides only when the
@@ -495,15 +370,39 @@ def test_groupby_sorted_path_holds_its_budget(nullable_key):
     assert sorted(str(v.aval.dtype) for v in ends.invars) == sorted(
         ["int32", "int32", "int64"] + ["bool"] * nullable_key)
     (event,) = [e for e in ev if e[0] == "group_by"]
-    assert event[1] == "fallback"
+    assert event[1] == "sort"
     assert event[2] == (
-        f"cap {G} > hash_agg_limit; sort carries 1 cols, "
+        f"cap {G}; sort carries 1 cols, "
         f"ends carry {3 + nullable_key} words")
 
 
+def test_groupby_under_interpret_traces_no_hash_kernel():
+    """A keyed group-by of the shape the hash kernel took (two word-encodable
+    keys, a frame of 2,048) with every kernel switched to interpret: the
+    program holds no `hash_agg` call, and its one group_by event says sort."""
+    import jax
+
+    n, G = 8_192, 2_048
+
+    def run(k1, k2, v, live):
+        keys = [ColumnVal(k1, None, None, INTEGER), ColumnVal(k2, None, None, BIGINT)]
+        return relops.group_aggregate(
+            keys, [ColumnVal(v, None, None, BIGINT)], [AggSpec("sum")], live, G)
+
+    kernels.set_policy(kernels.KernelPolicy(enabled=True, interpret=True))
+    ev = kernels.begin_capture()
+    try:
+        jaxpr = jax.make_jaxpr(run)(
+            jnp.zeros(n, jnp.int32), jnp.zeros(n, jnp.int64),
+            jnp.zeros(n, jnp.int64), jnp.ones(n, bool))
+    finally:
+        kernels.end_capture()
+    assert "hash_agg" not in str(jaxpr)
+    assert [e[:2] for e in ev if e[0] == "group_by"] == [("group_by", "sort")]
+
+
 def test_explain_analyze_says_what_the_sorted_groupby_carried(kernel_engine):
-    """q18's subquery aggregation (GROUP BY l_orderkey, far above the gate)
-    under EXPLAIN ANALYZE: the `-- kernel:` line of its dispatch event names
+    """q18's subquery aggregation (GROUP BY l_orderkey) under EXPLAIN ANALYZE: the `-- kernel:` line of its dispatch event names
     the operands of the sort and the words of the compaction."""
     from tests.tpch_queries import QUERIES
 
@@ -511,75 +410,31 @@ def test_explain_analyze_says_what_the_sorted_groupby_carried(kernel_engine):
         "EXPLAIN ANALYZE " + QUERIES["q18"])]
     lines = [l for l in ex if l.startswith("-- kernel:") and " group_by " in l]
     assert any(
-        "> hash_agg_limit; sort carries 1 cols, ends carry " in l for l in lines
+        "sort group_by (cap " in l and "; sort carries 1 cols, ends carry " in l
+        for l in lines
     ), ex
 
 
-def _compare_join(kind, seed, C=1 << 15):
-    rng = np.random.default_rng(seed)
-    nl, nr = 2000, 300
-    lc = [_cv(rng.integers(0, 100, nl), None, None, BIGINT)]
-    lk = [_cv(rng.integers(0, 50, nl), rng.random(nl) > 0.05, None, BIGINT)]
-    rc = [_cv(rng.integers(0, 100, nr), None, None, BIGINT)]
-    rk = [_cv(rng.integers(0, 60, nr), rng.random(nr) > 0.05, None, BIGINT)]
-    ll = jnp.asarray(rng.random(nl) > 0.1)
-    rl = jnp.asarray(rng.random(nr) > 0.1)
+def test_explain_analyze_of_q18_names_one_join_and_one_keyed_groupby(kernel_engine):
+    """Every join of q18 prints `sort join` and a `merged` or `scan`
+    join_rank beside it, every keyed group-by `sort group_by`; the dispatch
+    counter carries no `fallback` and no Pallas join or group-by."""
+    from tests.tpch_queries import QUERIES
 
-    def rows(cols, lv):
-        lv = np.asarray(lv)
-        mats = [
-            (np.asarray(c.data),
-             None if c.valid is None else np.asarray(c.valid))
-            for c in cols
-        ]
-        return sorted(
-            (
-                tuple(
-                    d[i].item() if v is None or v[i] else None
-                    for d, v in mats
-                )
-                for i in range(lv.shape[0])
-                if lv[i]
-            ),
-            key=repr,
-        )
-
-    kernels.set_policy(kernels.KernelPolicy(enabled=False))
-    cols0, live0, req0 = relops.equi_join(kind, lc, ll, rc, rl, lk, rk, None, C)
-    kernels.set_policy(kernels.KernelPolicy(enabled=True, interpret=True))
-    ev = kernels.begin_capture()
-    try:
-        cols1, live1, req1 = relops.equi_join(
-            kind, lc, ll, rc, rl, lk, rk, None, C
-        )
-    finally:
-        kernels.end_capture()
-    assert int(req0) == int(req1)
-    assert rows(cols0, live0) == rows(cols1, live1)
-    assert ("join", "pallas") in {(e[0], e[1]) for e in ev}
-
-
-@pytest.mark.parametrize("kind", ["inner", "semi", "anti", "left", "null_anti"])
-def test_join_kinds_match_sort(kind):
-    _compare_join(kind, seed=11)
-
-
-def test_join_build_over_limit_dispatches_fallback():
-    rng = np.random.default_rng(31)
-    nl, nr = 500, 4000  # build side past the policy limit
-    lk = [_cv(rng.integers(0, 50, nl), None, None, BIGINT)]
-    rk = [_cv(rng.integers(0, 50, nr), None, None, BIGINT)]
-    lc = [_cv(rng.integers(0, 9, nl), None, None, BIGINT)]
-    rc = [_cv(rng.integers(0, 9, nr), None, None, BIGINT)]
-    kernels.set_policy(kernels.KernelPolicy(
-        enabled=True, interpret=True, hash_join_max_build=1024))
-    ev = kernels.begin_capture()
-    try:
-        relops.equi_join("inner", lc, jnp.ones(nl, bool), rc,
-                         jnp.ones(nr, bool), lk, rk, None, 1 << 16)
-    finally:
-        kernels.end_capture()
-    assert ("join", "fallback") in {(e[0], e[1]) for e in ev}
+    ex = [str(r[0]) for r in kernel_engine.execute(
+        "EXPLAIN ANALYZE " + QUERIES["q18"])]
+    lines = [l[len("-- kernel: "):] for l in ex if l.startswith("-- kernel:")]
+    joins = [l for l in lines if l.split()[1] == "join"]
+    ranks = [l for l in lines if l.split()[1] == "join_rank"]
+    groupbys = [l for l in lines if l.split()[1] == "group_by"]
+    assert len(joins) == len(ranks) == 3 and len(groupbys) == 2, lines
+    assert all(l.startswith("sort join (build ") for l in joins), joins
+    assert all(l.split()[0] in ("merged", "scan") for l in ranks), ranks
+    assert all(l.startswith("sort group_by (cap ") for l in groupbys), groupbys
+    for op in ("group_by", "join", "join_rank", "fused_pipeline", "compact"):
+        assert kernels._DISPATCH.value(op, "fallback") == 0
+    for op in ("group_by", "join", "join_rank"):
+        assert kernels._DISPATCH.value(op, "pallas") == 0
 
 
 def test_kill_switch_restores_legacy_dispatch():
@@ -596,6 +451,20 @@ def test_kill_switch_restores_legacy_dispatch():
         kernels.end_capture()
     impls = {e[1] for e in ev if e[0] == "group_by"}
     assert impls == {"sort"}
+
+
+@pytest.mark.parametrize("side", ["agg", "join"])
+def test_a_hash_kernels_limit_is_an_unknown_property(kernel_engine, side):
+    """The two knobs went with the kernels (PR 45): setting one fails as any
+    unknown name does, through SQL and on the session itself."""
+    from trino_tpu.runtime.session import PROPERTIES
+
+    name = f"hash_{side}_kernel_limit"
+    assert name not in PROPERTIES
+    with pytest.raises(KeyError, match=f"unknown session property: {name}"):
+        kernel_engine.session.set(name, "512")
+    with pytest.raises(Exception, match="(?i)unknown session property"):
+        kernel_engine.execute(f"SET SESSION {name} = 512")
 
 
 # ------------------------------------------------------- engine-level fused

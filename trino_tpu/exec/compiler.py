@@ -603,9 +603,8 @@ class LocalExecutor:
         one rule and one headroom serve them all.  Two exceptions: a TopN
         keeps its floor (its need is the radix threshold's ties, which a
         float key can multiply; `_initial_caps` says what a wrong guess
-        costs), and a node the retry loop grew in this call stays grown (the
-        hash aggregate reports an inflated count when it gives up below its
-        tier).  -> whether any tier changed."""
+        costs), and a node the retry loop grew in this call stays grown.
+        -> whether any tier changed."""
         changed = False
         for nid, cap in caps.items():
             need = required.get(nid)
@@ -620,56 +619,6 @@ class LocalExecutor:
 
     def execute_to_rows(self, plan: PlanNode) -> list[tuple]:
         return self.execute(plan).to_pylist()
-
-    def steady_state_time(self, plan: PlanNode, iters: int = 8) -> float:
-        """Device-side seconds per execution of the cached jitted program,
-        amortized over `iters` back-to-back dispatches with ONE final block.
-
-        execute() pays a host<->device synchronisation per call (it fetches
-        the packed overflow vector before it returns).  Pipelining the
-        dispatches amortizes that away, so wall_per_query -
-        steady_state_time ~= the fixed sync floor; bench.py reports both
-        sides (the roofline accounting VERDICT r2 asked for)."""
-        self.execute(plan)  # ensure caps learned + program cached + inputs hot
-        nodes = _node_ids(plan)
-        inputs = {}
-        for i, n in nodes.items():
-            if isinstance(n, TableScan):
-                inputs[str(i)] = self.table_page(
-                    n.catalog, n.table, n.column_names, n.output_types, scan_id=i
-                )
-        caps = self._learned_caps[plan]
-        cache_key, _treedef, _avals = self._cache_key(plan, inputs, caps)
-        entry = self._jit_cache.get(cache_key)
-        if entry is None:
-            # every prior execution fell back (compile never swapped in):
-            # force a synchronous compile — steady-state measures the
-            # compiled program, not the eager path
-            saved = self.compile_wait_budget_ms
-            self.compile_wait_budget_ms = 0
-            try:
-                self._run(plan, inputs, caps)
-            finally:
-                self.compile_wait_budget_ms = saved
-            entry = self._jit_cache[cache_key]
-        fn, _holder, _sig = entry
-        out, packed = fn(inputs, ())
-        jax.block_until_ready(packed)  # drain any pending work
-        # keeping many dispatches in flight also keeps every run's OUTPUT
-        # buffers alive at once; for queries whose working set is a big
-        # fraction of HBM that forces allocator thrash (measured: q18 SF1
-        # "pipelined" 23s vs 9.4s single-shot).  Cap in-flight runs by the
-        # estimated footprint so the measurement never self-sabotages.
-        est = self._estimate_bytes(inputs, self._learned_caps.get(plan, {}))
-        if est > 2_000_000_000:
-            iters = min(iters, 2)
-        import time as _time
-
-        t0 = _time.perf_counter()
-        for _ in range(iters):
-            _, packed = fn(inputs, ())
-        jax.block_until_ready(packed)
-        return (_time.perf_counter() - t0) / iters
 
     def _estimate_bytes(self, inputs, caps) -> int:
         """Planned device-memory footprint: every stateful node's capacity

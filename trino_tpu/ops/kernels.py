@@ -1,52 +1,48 @@
 """Data-plane kernel policy and dispatch accounting.
 
-The Pallas data-plane kernels (ops/pallas/hashagg.py, hashjoin.py, fused.py)
-replace the sort-based relational hot paths (ops/relops.py) when a static
-gate says the shape fits — group/build cardinality inside the VMEM hash
-table, key types encodable as i32 words, aggregate set fully fusable.  This
-module is the one place that decision is configured and observed:
+One Pallas kernel replaces a relational hot path when a static gate says
+the shape fits: the fused scan (ops/pallas/fused.py) stands in for a
+Filter/Project chain under an Aggregate whose keys are small dictionary
+columns.  Two more serve the paths of ops/relops.py from below: the
+segmented reduction (segreduce.py) and the radix top-n (topk.py).  Joins and
+keyed group-bys sort (PERF.md section 6, PR 45: the hash-table kernels that
+stood beside the sort paths lost on the chip and went).  This module is the
+one place the choice is configured and observed:
 
-  * KernelPolicy — per-statement knobs (runtime/session.py properties
-    `data_plane_kernels`, `hash_agg_kernel_limit`, `hash_join_kernel_limit`,
-    `pallas_interpret`), re-applied by the engine before each statement the
-    same way compile props are.
+  * KernelPolicy — per-statement switches (runtime/session.py properties
+    `data_plane_kernels`, `pallas_interpret`), re-applied by the engine
+    before each statement the same way compile props are.
   * record_dispatch() — increments
-    trino_tpu_kernel_dispatch_total{op,impl=pallas|sort|fallback} and, while
-    a plan trace is active, appends the event to that trace's capture so
-    EXPLAIN ANALYZE can print `-- kernel:` footer lines.  Dispatch is
-    recorded at TRACE time (kernel selection), once per compiled program —
-    a jit-cache hit re-runs the selected kernel without re-counting.
+    trino_tpu_kernel_dispatch_total{op,impl} and, while a plan trace is
+    active, appends the event to that trace's capture so EXPLAIN ANALYZE
+    can print `-- kernel:` footer lines.  Dispatch is recorded at TRACE
+    time (kernel selection), once per compiled program — a jit-cache hit
+    re-runs the selected kernel without re-counting.
   * events_for(plan) — the captured events of the last trace of `plan`
     (plans are frozen dataclasses, so they key a bounded dict directly);
     describe(plan) — the same as text, one line an event: EXPLAIN ANALYZE's
     `-- kernel:` lines and the `dispatch` span's `kernels`.
 
-ops: group_by (ops/pallas/hashagg.py), join (hashagg build + hashjoin
-probe), fused_pipeline (fused.py), segment_reduce (segreduce.py — the
-accumulator under every group-by path and the radix histograms) and top_n
-(topk.py radix select); the last two are counted when selected only.
-
-impl values: "pallas" = the Pallas kernel was selected; "sort" = the static
-gate chose the legacy sort path (disabled, unencodable keys, unsupported
-shape, or a non-TPU backend without interpret); "fallback" = the shape was
-kernel-eligible but exceeded the policy's capacity limit
-(hash_agg_kernel_limit / hash_join_kernel_limit), so the sort path ran.
-A group-by that runs on the sort path records its event from there, its
-detail ending in what moved: `cap 16777216 > hash_agg_limit; sort carries 1
-cols, ends carry 3 words` — the operands that rode the group sort beside its
-keys, and the 32-bit words the compaction of the group ends carried.
-A selected kernel still carries a runtime overflow guard — hash-table
-overflow or probe exhaustion divert that execution to the sort path without
-re-counting.
-op "compact" (relops.compact_rows; no Pallas kernel) has impls of its own:
-"carry" = the columns rode the compaction's sort, "gather" = they were fetched
-through its permutation; the detail is `60000466 -> 33554432 lanes, 5 words`.
-op "join_rank" (relops.equi_join; one event a traced join, beside its "join"
-event) says how the probe's bounds over the sorted build side were found:
-"pallas" = by the hash kernel's probe, "merged" = a running count over ONE
-sort of build ++ probe hashes, "scan" = a binary search (few probes against
-many keys: relops.rank_form); the detail is `60000466 ++ 4096 lanes -> C
-16384` (build lanes, probe lanes, the expansion frame).
+ops and their impls:
+  fused_pipeline (fused.py): "pallas" = the fused scan was selected.
+  segment_reduce (segreduce.py — the accumulator under every group-by path
+    and the radix histograms) and top_n (topk.py radix select): "pallas",
+    counted when selected only.
+  group_by (relops.group_aggregate): "sort" = the sorted group-by, its
+    detail what moved: `cap 16777216; sort carries 1 cols, ends carry 3
+    words` — the operands that rode the group sort beside its keys, and the
+    32-bit words the compaction of the group ends carried.  (The global and
+    the direct-code forms record their segment_reduce only.)
+  join (relops.equi_join): "sort", detail `build 4096` (build lanes).
+  join_rank (one event a traced join, beside its join event): how the
+    probe's bounds over the sorted build side were found — "merged" = a
+    running count over ONE sort of build ++ probe hashes, "scan" = a binary
+    search (few probes against many keys: relops.rank_form); the detail is
+    `60000466 ++ 4096 lanes -> C 16384` (build lanes, probe lanes, the
+    expansion frame).
+  compact (relops.compact_rows): "carry" = the columns rode the
+    compaction's sort, "gather" = they were fetched through its
+    permutation; the detail is `60000466 -> 33554432 lanes, 5 words`.
 """
 
 from __future__ import annotations
@@ -65,10 +61,8 @@ __all__ = [
 
 @dataclass(frozen=True)
 class KernelPolicy:
-    enabled: bool = True            # master kill switch (data_plane_kernels)
-    hash_agg_max_groups: int = 2048  # group cap above which group-by sorts
-    hash_join_max_build: int = 2048  # build rows above which joins sort
-    interpret: bool = False         # run kernels interpreted (CPU CI path)
+    enabled: bool = True     # the fused scan may be selected (data_plane_kernels)
+    interpret: bool = False  # run kernels interpreted (CPU CI path)
 
 
 _DEFAULT = KernelPolicy()
@@ -77,12 +71,12 @@ _POLICY = _DEFAULT
 _DISPATCH = _metrics.GLOBAL.counter(
     "trino_tpu_kernel_dispatch_total",
     "Data-plane kernel selections at plan-trace time, by relational op "
-    "(group_by | join | fused_pipeline | segment_reduce | top_n | compact) and "
-    "implementation (pallas = Pallas TPU kernel, sort = legacy sort path, "
-    "fallback = kernel-eligible shape past the policy capacity limit, sort "
-    "path ran; segment_reduce and top_n count their Pallas selections only; "
-    "compact: carry = the columns rode the compaction's sort, gather = they "
-    "were fetched through its permutation)",
+    "(group_by | join | join_rank | fused_pipeline | segment_reduce | top_n "
+    "| compact) and implementation (pallas = Pallas TPU kernel; group_by and "
+    "join: sort; join_rank: merged = one sort of build ++ probe hashes, "
+    "scan = a binary search; segment_reduce and top_n count their Pallas "
+    "selections only; compact: carry = the columns rode the compaction's "
+    "sort, gather = they were fetched through its permutation)",
     ("op", "impl"),
 )
 
@@ -135,8 +129,8 @@ def policy_key() -> tuple:
     from .pallas import hashagg, segreduce, topk
 
     p = _POLICY
-    return (p.enabled, p.hash_agg_max_groups, p.hash_join_max_build,
-            p.interpret, segreduce.INTERPRET, hashagg.INTERPRET, topk.FORCE)
+    return (p.enabled, p.interpret,
+            segreduce.INTERPRET, hashagg.INTERPRET, topk.FORCE)
 
 
 # --------------------------------------------------------- event capture

@@ -95,8 +95,8 @@ every answer as it was, to the last digit (2.220e-11 and 5.022e-9, PR 36).
 Exactness-critical cases (BIGINT sum's mod-2^64 semantics) are rejected at
 plan time and take the sort path.
 
-Like the hash kernels, everything here runs under pallas interpret mode on
-CPU so tier-1 exercises the same code path as the TPU build.
+Everything here runs under pallas interpret mode on CPU so tier-1
+exercises the same code path as the TPU build.
 """
 
 from __future__ import annotations
@@ -109,14 +109,14 @@ import jax
 import jax.numpy as jnp
 
 from ...plan.ir import Call, Const, FieldRef, IrExpr, Param
-from .hashagg import (
-    _CHUNK_L,
-    _CHUNK_S,
-    _STEP_CHUNKS,
-    _STEP_ROWS,
-    _SUB_ROWS,
-)
 from . import hashagg as _hashagg
+
+# rows stream in 8192-row grid steps of eight (8, 128) sub-chunks
+_CHUNK_S = 8
+_CHUNK_L = 128
+_SUB_ROWS = _CHUNK_S * _CHUNK_L
+_STEP_CHUNKS = 8
+_STEP_ROWS = _SUB_ROWS * _STEP_CHUNKS
 
 # the widest mixed-radix key-code domain the planner accepts: four lane
 # tiles of one-hot, no table walk

@@ -252,169 +252,6 @@ def _sortable_key(v: ColumnVal, descending: bool = False) -> jnp.ndarray:
     return data
 
 
-# ----------------------------------------------- hash-kernel key encoding
-#
-# The Pallas hash kernels (ops/pallas/hashagg.py, hashjoin.py) compare keys
-# as fixed lists of i32 words.  Equality over the words must coincide with
-# the sort path's grouping / the join's verified match semantics:
-#
-#   group-by: the sort path's run boundary fires on (~valid, raw operand)
-#     per key, so NULL rows group by validity AND payload — encoding the raw
-#     words plus one packed validity word reproduces that exactly.  DOUBLE
-#     keys are rejected: the sort path gives every NaN row its own group
-#     (raw-compare diff), which no bitwise word equality can express.
-#   join: matches are re-verified exactly downstream, so extra candidates
-#     are harmless but MISSED ones are not — -0.0 is canonicalized to +0.0
-#     (they compare equal), NULL keys are simply excluded from the build
-#     and probe live sets (they never match).
-#
-# Dictionary-coded columns encode their CODES (the sort path's sorted_rank
-# is a bijection of codes, and the join verifies by code equality), other
-# integers sign-extend to two words, decimal128 to four.
-
-
-def _words64(bits: jnp.ndarray) -> list:
-    u = bits.astype(jnp.uint64)
-    lo = jax.lax.bitcast_convert_type(
-        (u & jnp.uint64(0xFFFFFFFF)).astype(jnp.uint32), jnp.int32
-    )
-    hi = jax.lax.bitcast_convert_type(
-        (u >> jnp.uint64(32)).astype(jnp.uint32), jnp.int32
-    )
-    return [lo, hi]
-
-
-def _combine64(lo32: jnp.ndarray, hi32: jnp.ndarray) -> jnp.ndarray:
-    u = lo32.astype(jnp.uint32).astype(jnp.uint64) | (
-        hi32.astype(jnp.uint32).astype(jnp.uint64) << jnp.uint64(32)
-    )
-    return jax.lax.bitcast_convert_type(u, jnp.int64)
-
-
-def _hash_key_words(keys: Sequence[ColumnVal], n: int, for_join: bool):
-    """Encode key columns as i32 word lists for the hash kernels, or None
-    when a column's equality semantics cannot be carried by words (see
-    above).  Returns (words, layout) where layout[k] is the per-key word
-    kind: 'dict' | 'i32' | 'i64' | 'f64' | 'dec128'."""
-    words: list = []
-    layout: list[str] = []
-    for kv in keys:
-        if kv.dict is not None:
-            words.append(kv.data.astype(jnp.int32))
-            layout.append("dict")
-        elif kv.data2 is not None:
-            words.extend(_words64(kv.data.astype(jnp.int64)))
-            words.extend(_words64(kv.data2.astype(jnp.int64)))
-            layout.append("dec128")
-        elif jnp.issubdtype(kv.data.dtype, jnp.floating):
-            if not for_join:
-                return None, None  # per-NaN-row groups are not word-equatable
-            d = kv.data.astype(jnp.float64)
-            d = jnp.where(d == 0.0, jnp.float64(0.0), d)  # -0.0 matches +0.0
-            words.extend(_words64(jax.lax.bitcast_convert_type(d, jnp.uint64)))
-            layout.append("f64")
-        elif kv.data.dtype.itemsize > 4:
-            words.extend(_words64(kv.data.astype(jnp.int64)))
-            layout.append("i64")
-        elif kv.data.dtype == jnp.bool_ or jnp.issubdtype(
-            kv.data.dtype, jnp.integer
-        ):
-            words.append(kv.data.astype(jnp.int32))
-            layout.append("i32")
-        else:
-            return None, None
-    return words, layout
-
-
-def _hash_aggregate(key_vals, agg_args, specs, live, G, agg_args2):
-    """Pallas hash-table grouped aggregation: one streaming build pass
-    assigns every row a dense group id (ops/pallas/hashagg.py), the fused
-    segment reductions run over those ids unsorted, and the output key
-    columns are decoded from the hash table itself (<= a few thousand
-    entries) — no sort of the input anywhere.  Returns (the group_aggregate
-    result tuple, None), or (None, (impl, why)) when the static gate picks
-    the sort path — which records the dispatch event itself, with what its
-    sort and its compaction carried.
-
-    Overflow (more distinct groups than the capacity tier, or probe-budget
-    exhaustion) reports an inflated n_groups through the normal required
-    channel: the executor retries at a doubled tier, and once the tier
-    exceeds hash_agg_max_groups this gate flips to the sort path — the
-    deterministic overflow-to-sort fallback."""
-    from .kernels import get_policy, record_dispatch
-
-    if any(
-        s.distinct or s.fn in ("percentile", "approx_distinct") or s.fn in HOST_AGGS
-        for s in specs
-    ):
-        # value-sorted / host aggregates need the sort anyway
-        return None, ("sort", "value-sorted or host-collected aggregate")
-    policy = get_policy()
-    if not policy.enabled:
-        return None, ("sort", "kernels disabled")
-    from .pallas import hashagg
-
-    n = live.shape[0]
-    if G > policy.hash_agg_max_groups:
-        return None, ("fallback", f"cap {G} > hash_agg_limit")
-    interpret = policy.interpret or hashagg.INTERPRET
-    if not interpret and jax.default_backend() != "tpu":
-        # decline before encoding: the key-word encode below is real work
-        # on the eager/interpreted-fallback execution path
-        return None, ("sort", "cpu backend")
-    enc, layout = _hash_key_words(key_vals, n, for_join=False)
-    if enc is None:
-        return None, ("sort", "keys not word-encodable")
-    validity = jnp.zeros((n,), jnp.int32)
-    for k, kv in enumerate(key_vals):
-        validity = validity | (_valid_of(kv, n).astype(jnp.int32) << k)
-    words = enc + [validity]
-    if not hashagg.shape_supported(n, len(words), G):
-        return None, ("sort", "shape unsupported")
-    record_dispatch(
-        "group_by", "pallas", f"{len(words)}w cap {G} table {hashagg.table_size(G)}"
-    )
-
-    gid, table, n_true, overflow = hashagg.build_hash_table(
-        words, live, G, interpret=interpret
-    )
-    seg = jnp.where(live & (gid >= 0) & (gid < G), gid, G).astype(jnp.int32)
-    out_aggs = _fused_aggs(agg_args, specs, seg, live, G, n, agg_args2=agg_args2)
-
-    # decode the output key columns from the table entries, ordered by gid
-    T = table.shape[1]
-    entry_gid = jnp.where(table[0] > 0.5, table[1].astype(jnp.int32), T)
-    order = jnp.argsort(entry_gid)[:G]
-
-    def word_at(i):
-        lo = jnp.take(table[2 + 2 * i], order).astype(jnp.uint32)
-        hi = jnp.take(table[3 + 2 * i], order).astype(jnp.uint32)
-        return jax.lax.bitcast_convert_type(lo | (hi << jnp.uint32(16)), jnp.int32)
-
-    vword = word_at(len(enc))
-    out_keys = []
-    wpos = 0
-    for k, (kv, kind) in enumerate(zip(key_vals, layout)):
-        valid = ((vword >> k) & 1) != 0
-        if kind in ("dict", "i32"):
-            data = word_at(wpos).astype(kv.data.dtype)
-            wpos += 1
-            out_keys.append((data, valid, None))
-        elif kind == "i64":
-            data = _combine64(word_at(wpos), word_at(wpos + 1)).astype(kv.data.dtype)
-            wpos += 2
-            out_keys.append((data, valid, None))
-        else:  # dec128
-            lo = _combine64(word_at(wpos), word_at(wpos + 1))
-            hi = _combine64(word_at(wpos + 2), word_at(wpos + 3))
-            wpos += 4
-            out_keys.append((lo, valid, hi))
-
-    out_live = jnp.arange(G, dtype=jnp.int32) < jnp.minimum(n_true, G)
-    n_report = jnp.where(overflow, jnp.maximum(n_true, jnp.int32(G + 1)), n_true)
-    return (out_keys, out_aggs, out_live, n_report), None
-
-
 # ------------------------------------------------------------ aggregation
 
 
@@ -427,7 +264,8 @@ def group_aggregate(
     agg_args2: Optional[Sequence[Optional[ColumnVal]]] = None,
     agg_order: Optional[Sequence[tuple]] = None,
 ):
-    """Sort-based grouped aggregation.
+    """Grouped aggregation: global (no keys), direct-code (dictionary keys
+    of a small domain index their groups) or sorted.
 
     Returns (out_keys: list[(data, valid, data2-or-None)], out_aggs:
     list[(data, valid) or (data, valid, Dictionary) for host-collected
@@ -435,7 +273,6 @@ def group_aggregate(
     `num_groups_cap` and n_groups is the true group count (> cap ==
     overflow, host retries).
     """
-    n = live.shape[0]
     G = num_groups_cap
     if agg_args2 is None:
         agg_args2 = [None] * len(specs)
@@ -449,12 +286,7 @@ def group_aggregate(
     if fast is not None:
         return fast
 
-    hashed, declined = _hash_aggregate(key_vals, agg_args, specs, live, G, agg_args2)
-    if hashed is not None:
-        return hashed
-    return _sorted_aggregate(
-        key_vals, agg_args, specs, live, G, agg_args2, agg_order, declined
-    )
+    return _sorted_aggregate(key_vals, agg_args, specs, live, G, agg_args2, agg_order)
 
 
 class _GroupedSort:
@@ -602,13 +434,11 @@ def _carried_arrays(cols) -> list:
     return out
 
 
-def _sorted_aggregate(
-    key_vals, agg_args, specs, live, G, agg_args2, agg_order, declined
-):
+def _sorted_aggregate(key_vals, agg_args, specs, live, G, agg_args2, agg_order):
     """The sort-based group-by: what group_aggregate runs when the direct-
-    code path and the hash-table kernel decline.  ONE sort that carries the
-    aggregated columns, then ONE compaction of the group ends carrying the
-    keys and the running sums (SortedRuns.read)."""
+    code path declines.  ONE sort that carries the aggregated columns, then
+    ONE compaction of the group ends carrying the keys and the running sums
+    (SortedRuns.read)."""
     from .kernels import record_dispatch
     from .pallas.segreduce import fused_segment_reduce
 
@@ -676,10 +506,9 @@ def _sorted_aggregate(
                 fused_segment_reduce(own.seg, own_reds, G, runs=own.runs)
             )
 
-    impl, why = declined
     record_dispatch(
-        "group_by", impl,
-        f"{why}; sort carries {gs.carried} cols, "
+        "group_by", "sort",
+        f"cap {G}; sort carries {gs.carried} cols, "
         f"ends carry {gs.runs.ends_words} words",
     )
     n_groups = gs.runs.n_groups
@@ -1434,82 +1263,6 @@ def _in_null_facts(left_keys, right_keys, left_live, right_live, nl, nr):
     return build_any, build_has_null, probe_ok
 
 
-def _hash_join_gids(left_keys, right_keys, left_live, right_live, nl, nr):
-    """Pallas hash-join front end: build a VMEM hash table over the (small)
-    build side, probe the left side streamingly, and convert each probe
-    row's dense build-group id into the (lo, hi) row-range-over-perm_b form
-    the sort path's expansion tail consumes — so inner/semi/anti/left/mark
-    all share the verified-match machinery below unchanged.
-
-    Returns None when the static gate picks the sort path, else
-    (ok, lo, hi, perm_b): `ok` is the runtime guard (table overflow or
-    probe-budget exhaustion flips the join back to the sort path via
-    lax.cond — deterministic overflow-to-sort).  Only the build side is
-    ever sorted (<= hash_join_max_build rows); the probe side is one
-    streaming kernel pass plus gathers."""
-    from .kernels import get_policy, record_dispatch
-
-    policy = get_policy()
-    if not policy.enabled:
-        record_dispatch("join", "sort", "kernels disabled")
-        return None
-    from .pallas import hashagg, hashjoin
-
-    interpret = policy.interpret or hashagg.INTERPRET
-    if not interpret and jax.default_backend() != "tpu":
-        # decline before encoding: the key-word encode below is real work
-        # on the eager/interpreted-fallback execution path
-        record_dispatch("join", "sort", "cpu backend")
-        return None
-    wl, llay = _hash_key_words(left_keys, nl, for_join=True)
-    wr, rlay = _hash_key_words(right_keys, nr, for_join=True)
-    if wl is None or wr is None or llay != rlay or len(wl) != len(wr):
-        record_dispatch("join", "sort", "keys not word-encodable")
-        return None
-    if nr > policy.hash_join_max_build:
-        record_dispatch("join", "fallback", f"build {nr} > hash_join_limit")
-        return None
-    if not hashagg.shape_supported(max(nl, nr, 1), len(wr), nr):
-        record_dispatch("join", "sort", "shape unsupported")
-        return None
-    record_dispatch(
-        "join", "pallas", f"build {nr} table {hashagg.table_size(nr)}"
-    )
-
-    blive = right_live
-    for rk in right_keys:
-        blive = blive & _valid_of(rk, nr)  # NULL keys never match
-    plive = left_live
-    for lk in left_keys:
-        plive = plive & _valid_of(lk, nl)
-
-    gid_b, table, _n_true, ovb = hashagg.build_hash_table(
-        wr, blive, nr, interpret=interpret
-    )
-    gid_p, unres = hashjoin.probe_hash_table(
-        wl, plive, table, interpret=interpret
-    )
-    ok = ~ovb & ~unres
-
-    # build rows sorted by group id (dead/null rows last) give contiguous
-    # per-group ranges; group starts come from a tiny searchsorted over the
-    # build side only
-    segb = jnp.where(
-        blive & (gid_b >= 0), jnp.minimum(gid_b, nr - 1), nr
-    ).astype(jnp.int32)
-    iota_r = jnp.arange(nr, dtype=jnp.int32)
-    segb_sorted, perm_b = jax.lax.sort([segb, iota_r], num_keys=1)
-    gids = jnp.arange(nr, dtype=jnp.int32)
-    gstart = jnp.searchsorted(segb_sorted, gids, side="left")
-    gend = jnp.searchsorted(segb_sorted, gids, side="right")
-    matched = gid_p >= 0
-    gidx = jnp.clip(gid_p, 0, nr - 1)
-    lo = jnp.where(matched, jnp.take(gstart, gidx), 0).astype(jnp.int64)
-    cnt = jnp.where(matched, jnp.take(gend, gidx) - jnp.take(gstart, gidx), 0)
-    hi = lo + cnt.astype(jnp.int64)
-    return ok, lo, hi, perm_b
-
-
 def equi_join(
     kind: str,
     left_cols: Sequence[ColumnVal],
@@ -1545,35 +1298,20 @@ def equi_join(
     nr = right_live.shape[0]
     C = out_capacity
 
-    rank = rank_form(nr, nl)
-
-    def _sort_lohi():
-        bh = _combined_hash(right_keys, right_live, nr, _SENT_BUILD)
-        ph = _combined_hash(left_keys, left_live, nl, _SENT_PROBE)
-        iota_r = jnp.arange(nr, dtype=jnp.int32)
-        bh_sorted, pb = jax.lax.sort([bh, iota_r], num_keys=1)
-        if rank == "scan":
-            l = searchsorted_tpu(bh_sorted, ph, side="left").astype(jnp.int64)
-            h = searchsorted_tpu(bh_sorted, ph, side="right").astype(jnp.int64)
-        else:
-            l, h = _merged_bounds(bh, ph)
-        return l, h, pb
-
     from .kernels import record_dispatch
 
-    hashed = _hash_join_gids(
-        left_keys, right_keys, left_live, right_live, nl, nr
-    )
-    record_dispatch(
-        "join_rank", "pallas" if hashed is not None else rank,
-        f"{nr} ++ {nl} lanes -> C {C}")
-    if hashed is not None:
-        h_ok, h_lo, h_hi, h_perm = hashed
-        lo, hi, perm_b = jax.lax.cond(
-            h_ok, lambda: (h_lo, h_hi, h_perm), _sort_lohi
-        )
+    rank = rank_form(nr, nl)
+    record_dispatch("join", "sort", f"build {nr}")
+    record_dispatch("join_rank", rank, f"{nr} ++ {nl} lanes -> C {C}")
+    bh = _combined_hash(right_keys, right_live, nr, _SENT_BUILD)
+    ph = _combined_hash(left_keys, left_live, nl, _SENT_PROBE)
+    iota_r = jnp.arange(nr, dtype=jnp.int32)
+    bh_sorted, perm_b = jax.lax.sort([bh, iota_r], num_keys=1)
+    if rank == "scan":
+        lo = searchsorted_tpu(bh_sorted, ph, side="left").astype(jnp.int64)
+        hi = searchsorted_tpu(bh_sorted, ph, side="right").astype(jnp.int64)
     else:
-        lo, hi, perm_b = _sort_lohi()
+        lo, hi = _merged_bounds(bh, ph)
     counts = (hi - lo).astype(jnp.int64)
     cum = jnp.cumsum(counts)
     total = cum[-1]

@@ -262,8 +262,6 @@ class Engine:
 
         _kernels.set_policy(_kernels.KernelPolicy(
             enabled=bool(self.session.get("data_plane_kernels")),
-            hash_agg_max_groups=int(self.session.get("hash_agg_kernel_limit")),
-            hash_join_max_build=int(self.session.get("hash_join_kernel_limit")),
             interpret=bool(self.session.get("pallas_interpret")),
         ))
 

@@ -277,22 +277,10 @@ PROPERTIES: dict[str, _Prop] = {
         ),
         _Prop(
             "data_plane_kernels", bool, True,
-            "master switch for the Pallas data-plane kernels (hash "
-            "group-by, hash join, fused scan pipelines; ops/pallas/). "
-            "false restores the legacy sort-based paths bit-for-bit",
+            "whether a scan-filter-project-aggregate fragment may run as "
+            "the fused Pallas scan (ops/pallas/fused.py); false runs it "
+            "operator at a time",
             None,
-        ),
-        _Prop(
-            "hash_agg_kernel_limit", int, 2048,
-            "group-count capacity above which group-by takes the sort "
-            "path instead of the Pallas VMEM hash table",
-            lambda v: v >= 1,
-        ),
-        _Prop(
-            "hash_join_kernel_limit", int, 2048,
-            "build-side rows above which equi-joins take the sort path "
-            "instead of the Pallas VMEM hash table",
-            lambda v: v >= 1,
         ),
         _Prop(
             "pallas_interpret", bool, False,
