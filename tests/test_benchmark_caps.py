@@ -19,7 +19,8 @@ The SF10 configuration (`tpch_sf10_embedded`, PR 40) is planned from
 statistics too, but no test may generate SF10's columns to have them (three
 tables' first passes: ~90 s and 6 GB in the sandbox).  So the case plans from
 the statistics the connector computed from those columns once, recorded in
-`tests/data/tpch_sf10_planned_stats.json` (eight columns and three row counts):
+`tests/data/tpch_sf10_planned_stats.json` (the columns the cells' statements
+read and the tables' row counts):
 milliseconds, on any machine.  A statistic planning asks for that is not
 recorded fails the case — on a fresh machine that column would be generated,
 which is the other thing PR 40 mended.  Where SF10's column files are on the
@@ -52,6 +53,7 @@ EMBEDDED = {
     "tpch_sf1_embedded": "joins_text_1stream",
     "tpch_sf10_embedded": "joins_text_1stream",
     "tpch_sf10_embedded_multiway": "multiway_text_1stream",
+    "tpch_sf10_embedded_subquery": "subquery_text_1stream",
 }
 CASES = [(config, name) for config, mix in EMBEDDED.items()
          for name in _load("traffic", f"{mix}.json")["pass"]]

@@ -200,6 +200,16 @@ POINTS = {
         (16_777_216, 4_096, 6, "gather"), (15_000_000, 4_096, 4, "gather")],
     ("tpch_sf10_embedded_multiway", "q05"): [(15_000_000, 8_388_608, 3, "carry")],
     ("tpch_sf10_embedded_multiway", "q09"): [(2_000_000, 262_144, 2, "carry")],
+    # PR 47: lineitem's 60M-lane filters stay pass-throughs (38M and 26M rows keep their tier)
+    ("tpch_sf10_embedded_subquery", "q16"): [
+        (2_000_000, 1_048_576, 4, "carry"), (100_000, 2_048, 2, "gather")],
+    ("tpch_sf10_embedded_subquery", "q20"): [
+        (2_000_000, 65_536, 2, "gather"), (8_000_000, 262_144, 3, "gather"),
+        (60_000_466, 33_554_432, 4, "carry")],
+    ("tpch_sf10_embedded_subquery", "q21"): [(2_097_152, 262_144, 3, "carry")],
+    ("tpch_sf10_embedded_subquery", "q22"): [
+        (1_500_000, 1_048_576, 3, "carry"), (1_500_000, 1_048_576, 2, "carry"),
+        (1_048_576, 524_288, 6, "carry"), (524_288, 131_072, 3, "carry")],
 }
 
 

@@ -260,7 +260,8 @@ def _traced_join(nr, nl, C):
             s(nr, jnp.int64), s(nr, jnp.int32), s(nr, jnp.bool_))
     finally:
         kernels.end_capture()
-    assert [e for e in events if e[0] == "join"] == [("join", "sort", f"build {nr}")]
+    assert [e for e in events if e[0] == "join"] == [
+        ("join", "sort", f"inner build {nr} probe {nl} -> C {C}")]
     return jaxpr.jaxpr, [e for e in events if e[0] == "join_rank"]
 
 

@@ -428,7 +428,8 @@ def test_explain_analyze_of_q18_names_one_join_and_one_keyed_groupby(kernel_engi
     ranks = [l for l in lines if l.split()[1] == "join_rank"]
     groupbys = [l for l in lines if l.split()[1] == "group_by"]
     assert len(joins) == len(ranks) == 3 and len(groupbys) == 2, lines
-    assert all(l.startswith("sort join (build ") for l in joins), joins
+    assert [l.split(" build ")[0] for l in joins] == [
+        "sort join (semi", "sort join (inner", "sort join (inner"], joins
     assert all(l.split()[0] in ("merged", "scan") for l in ranks), ranks
     assert all(l.startswith("sort group_by (cap ") for l in groupbys), groupbys
     for op in ("group_by", "join", "join_rank", "fused_pipeline", "compact"):

@@ -580,18 +580,27 @@ def test_cpu_clock_tells_a_busy_thread_from_a_waiting_one():
 
 
 def test_record_takes_the_recording_threads_cpu_start():
+    """The upper bound holds every time.  The lower one (half of a 20 ms spin
+    on the thread's CPU clock) is asked up to five times: under six xdist
+    workers that clock ticks every 10 ms and the thread may be held off its
+    core, so one spin can read a single tick."""
     tracer = Tracer()
     tracer._cpu = time.thread_time
-    with tracer.span("query") as query:
-        t0, cpu0 = time.perf_counter(), tracer.cpu_now()
-        while time.perf_counter() < t0 + 0.02:
-            pass
-        worked = tracer.record("http.get", t0, cpu_start_s=cpu0, served=True)
-        plain = tracer.record("queued", t0)
-    assert query.children == [worked, plain]
-    assert worked.attributes["served"] is True
-    assert 0.5 * worked.duration_ms <= worked.attributes["cpu_ms"] <= worked.duration_ms + 1.0
-    assert "cpu_ms" not in plain.attributes  # no start, no clock
+    for _attempt in range(5):
+        with tracer.span("query") as query:
+            t0, cpu0 = time.perf_counter(), tracer.cpu_now()
+            while time.perf_counter() < t0 + 0.02:
+                pass
+            worked = tracer.record("http.get", t0, cpu_start_s=cpu0, served=True)
+            plain = tracer.record("queued", t0)
+        assert query.children == [worked, plain]
+        assert worked.attributes["served"] is True
+        assert worked.attributes["cpu_ms"] <= worked.duration_ms + 1.0
+        assert "cpu_ms" not in plain.attributes  # no start, no clock
+        if 0.5 * worked.duration_ms <= worked.attributes["cpu_ms"]:
+            break
+    else:
+        raise AssertionError(f"five spins, none read half its time on the CPU clock: {worked}")
 
 
 def test_a_host_whose_cpu_clock_is_unfit_gets_no_cpu_ms(monkeypatch):

@@ -33,7 +33,10 @@ ops and their impls:
     words` — the operands that rode the group sort beside its keys, and the
     32-bit words the compaction of the group ends carried.  (The global and
     the direct-code forms record their segment_reduce only.)
-  join (relops.equi_join): "sort", detail `build 4096` (build lanes).
+  join (relops.equi_join): "sort", detail `semi+residual build 4096 probe
+    60000466 -> C 16384`: the kind (inner | left | full | semi | anti |
+    null_anti | mark | mark_in, `+residual` where non-equality conjuncts
+    ride the join), build lanes, probe lanes, the expansion frame.
   join_rank (one event a traced join, beside its join event): how the
     probe's bounds over the sorted build side were found — "merged" = a
     running count over ONE sort of build ++ probe hashes, "scan" = a binary

@@ -1301,7 +1301,8 @@ def equi_join(
     from .kernels import record_dispatch
 
     rank = rank_form(nr, nl)
-    record_dispatch("join", "sort", f"build {nr}")
+    said = kind + ("+residual" if residual is not None else "")
+    record_dispatch("join", "sort", f"{said} build {nr} probe {nl} -> C {C}")
     record_dispatch("join_rank", rank, f"{nr} ++ {nl} lanes -> C {C}")
     bh = _combined_hash(right_keys, right_live, nr, _SENT_BUILD)
     ph = _combined_hash(left_keys, left_live, nl, _SENT_PROBE)

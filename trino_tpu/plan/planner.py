@@ -40,13 +40,22 @@ from ..data.types import (
 )
 from ..sql import ast as A
 from ..sql.parser import parse
+from ..utils.metrics import GLOBAL as _METRICS
 from .ir import Call, CaseWhen, Const, FieldRef, InListIr, IrExpr, LikeIr, Param
 from .nodes import (
     AggCall, Aggregate, Distinct, Filter, Join, Limit, PlanNode, Project,
     Sort, SortKey, TableScan, TopN, Unnest,
 )
 
-__all__ = ["Planner", "PlanningError", "param_bindings"]
+__all__ = ["Planner", "PlanningError", "param_bindings", "join_kinds", "note_subqueries"]
+
+SUBQUERIES = _METRICS.counter(
+    "trino_tpu_plan_subqueries_total",
+    "Subqueries the planner decorrelated into joins, by the form the"
+    " statement wrote (exists | not_exists | in | not_in | scalar_correlated"
+    " | scalar_uncorrelated)",
+    ("form",),
+)
 
 
 class _ParamBindings(threading.local):
@@ -157,11 +166,28 @@ class Planner:
         # expanded plan's scans.
         self.views: dict[tuple[str, str], A.Query] = {}
         self._view_stack: list[tuple[str, str]] = []  # cycle detection
+        self._noted = threading.local()  # .forms: this thread's last plan()
 
     def plan(self, query) -> PlanNode:
         if isinstance(query, str):
             query = parse(query)
-        return self._plan_query(query, outer=None, ctes={})
+        self._noted.forms = []
+        node = self._plan_query(query, outer=None, ctes={})
+        for form in self._noted.forms:
+            SUBQUERIES.labels(form).inc()
+        return node
+
+    def subqueries(self) -> tuple[str, ...]:
+        """The subquery forms this thread's last `plan()` decorrelated, in
+        the order the statement writes them (an outer one before those
+        inside it)."""
+        return tuple(getattr(self._noted, "forms", ()))
+
+    def _note_subquery(self, form: str) -> int:
+        """-> where the form stands (a scalar subquery learns whether it is
+        correlated only once its FROM is planned, and says so there)."""
+        self._noted.forms.append(form)
+        return len(self._noted.forms) - 1
 
     # ------------------------------------------------------------------ query
     def _plan_query(
@@ -1585,6 +1611,7 @@ class Planner:
                 agg_map=merged or None, grouped=grouped,
             )
             if isinstance(node_ast, A.ScalarSubquery):
+                self._note_subquery("scalar_uncorrelated")
                 sub = self._plan_subquery_relation(node_ast.query, outer_scope, ctes)
                 if len(sub.fields) != 1:
                     raise PlanningError("scalar subquery must select one expression")
@@ -1598,6 +1625,7 @@ class Planner:
                 sub_map[node_ast] = ref
                 continue
             if isinstance(node_ast, A.InSubquery):
+                self._note_subquery("not_in" if node_ast.negated else "in")
                 sub = self._plan_subquery_relation(node_ast.query, outer_scope, ctes)
                 if len(sub.fields) != 1:
                     raise PlanningError("IN subquery must produce one column")
@@ -1614,6 +1642,7 @@ class Planner:
                     raise PlanningError("EXISTS over a set operation not supported")
                 if q.select.group_by or self._collect_aggs(q.select, ()):
                     raise PlanningError("EXISTS with aggregation not supported")
+                self._note_subquery("not_exists" if node_ast.negated else "exists")
                 inner, correlated = self._split_correlated(q, outer_scope, ctes)
                 lkeys, rkeys, res_ir = self._correlation_parts(
                     rel, inner, correlated, outer, outer_t=t
@@ -1645,6 +1674,7 @@ class Planner:
             for name, cq in q.ctes:
                 ctes[name] = cq
         # plan FROM without where first to get the inner scope
+        noted = len(self._noted.forms)
         inner = self._plan_from(sel.relations, None, outer_scope, ctes)
         local: list[A.Expr] = []
         correlated: list[A.Expr] = []
@@ -1664,6 +1694,7 @@ class Planner:
         if local:
             # re-plan FROM with the local predicates so pushdown/join-keying happens
             where = _and_all(local)
+            del self._noted.forms[noted:]  # FROM is planned again: noted once
             inner = self._plan_from(sel.relations, where, outer_scope, ctes)
         return inner, correlated
 
@@ -1679,6 +1710,7 @@ class Planner:
             raise PlanningError("EXISTS over a set operation not supported")
         if q.select.group_by or self._collect_aggs(q.select, ()):
             raise PlanningError("EXISTS with aggregation not supported")
+        self._note_subquery("not_exists" if negated else "exists")
         outer_scope = Scope(rel.fields, outer)
         inner, correlated = self._split_correlated(q, outer_scope, ctes)
         return self._semi_join(rel, inner, correlated, negated, outer, extra_pairs=[])
@@ -1693,6 +1725,7 @@ class Planner:
         translator: "_Translator",
     ) -> RelationPlan:
         q = e.query
+        self._note_subquery("not_in" if negated else "in")
         outer_scope = Scope(rel.fields, outer)
         sub = self._plan_subquery_relation(q, outer_scope, ctes)
         if len(sub.fields) != 1:
@@ -1788,7 +1821,10 @@ class Planner:
             raise PlanningError("scalar subquery over a set operation not supported")
         sel = q.select
         outer_scope = Scope(rel.fields, outer)
+        at = self._note_subquery("scalar_uncorrelated")
         inner, correlated = self._split_correlated(q, outer_scope, ctes)
+        if correlated:
+            self._noted.forms[at] = "scalar_correlated"
         agg_calls = self._collect_aggs(sel, ())
         if not agg_calls or sel.group_by:
             if correlated:
@@ -2844,3 +2880,34 @@ def _nulls_first(si: A.SortItem) -> bool:
     if si.nulls_first is not None:
         return si.nulls_first
     return not si.ascending  # Trino default: NULLS LAST for ASC, FIRST for DESC
+
+
+def join_kinds(plan: PlanNode) -> dict[str, str]:
+    """Of a finished plan: `Join#<id>` (preorder ids, exec/compiler.py
+    `_node_ids`) -> the join's kind as the executor runs it — inner | left |
+    full | semi | anti | null_anti | mark | mark_in | cross, `single` for the
+    cross join of an arbitrary scalar subquery's one enforced row — with
+    `+residual` where non-equality conjuncts ride the join."""
+    from .nodes import EnforceSingleRow, walk
+
+    out = {}
+    for i, n in enumerate(walk(plan)):
+        if isinstance(n, Join):
+            kind = n.kind
+            if kind == "cross" and isinstance(n.right, EnforceSingleRow):
+                kind = "single"
+            out[f"Join#{i}"] = kind + ("+residual" if n.residual is not None else "")
+    return out
+
+
+def note_subqueries(span, planner: Planner, plan: PlanNode) -> None:
+    """On a `planner` span whose statement `planner` has just planned, what
+    its subqueries became: `subqueries` (the forms, `Planner.subqueries`) and
+    `join_kinds` (`join_kinds` of the finished plan); a statement with
+    neither says nothing."""
+    forms = planner.subqueries()
+    if forms:
+        span.attributes["subqueries"] = list(forms)
+    kinds = join_kinds(plan)
+    if kinds:
+        span.attributes["join_kinds"] = kinds

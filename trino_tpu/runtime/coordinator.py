@@ -42,7 +42,7 @@ from ..exec.resident import ResidentStore
 from ..plan.distribute import distribute
 from ..plan.fragmenter import Fragment, fragment_plan
 from ..plan.optimizer import optimize
-from ..plan.planner import Planner
+from ..plan.planner import Planner, note_subqueries
 from ..plan.serde import _encode, plan_to_json
 from ..utils import flightrecorder as _fr
 from ..utils import metrics as _metrics
@@ -2188,10 +2188,11 @@ class Coordinator:
             }
             return None
         try:
-            with self.tracer.span("planner", preplanned=False):
+            with self.tracer.span("planner", preplanned=False) as span:
                 plan = optimize(
                     self.planner.plan(record["sql"]), self.catalogs, self.session
                 )
+                note_subqueries(span, self.planner, plan)
         except Exception:
             return None  # let the execution path raise the real error
         # _run_once reuses this plan for attempt 0 (pop: retries re-plan)
@@ -2358,6 +2359,8 @@ class Coordinator:
                 plan = optimize(
                     self.planner.plan(record["sql"]), self.catalogs, self.session
                 )
+                # ids of the plan before `distribute` cuts it into fragments
+                note_subqueries(span, self.planner, plan)
             dplan = distribute(plan, self.catalogs, nw, self.session,
                                connector_buckets=True)
             fragments = fragment_plan(dplan)

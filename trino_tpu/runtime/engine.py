@@ -24,7 +24,7 @@ from ..exec.compiler import LocalExecutor, page_rows
 from ..exec.resident import ResidentStore
 from ..ops.kernels import JOIN_ROWS
 from ..plan.nodes import PlanNode, TableScan, format_plan
-from ..plan.planner import Planner
+from ..plan.planner import Planner, note_subqueries
 from ..plan.reorder import join_estimates
 from .session import SessionProperties
 from .txn import run_write  # imported eagerly: registers the txn metrics
@@ -184,6 +184,7 @@ class Engine:
         with self.tracer.span("planner") as span:
             plan = self.plan(sql)
             self._note_join_estimates(span, plan)
+            note_subqueries(span, self.planner, plan)
         with self.tracer.span("execute"):
             return self._execute_planned(plan)
 

@@ -59,6 +59,7 @@ from typing import Optional
 import numpy as np
 
 from ..exec.compiler import page_rows
+from ..plan.planner import note_subqueries
 from ..utils.metrics import GLOBAL as _METRICS
 
 __all__ = ["FastPath", "NotFastpath", "PLAN_CACHE_EVENTS", "EXECUTE_BATCH"]
@@ -393,6 +394,8 @@ class FastPath:
             span.attributes.update(
                 preplanned=info.cache == "hit", plan_cache=info.cache
             )
+            if info.cache != "hit":  # planned just now, on this thread
+                note_subqueries(span, eng.planner, entry.plan)
         # `bind`: this request's bindings as the cached plan's typed scalars
         with eng.tracer.span("bind", params=len(slots)):
             self.last_columns = list(entry.output_names)
