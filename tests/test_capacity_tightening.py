@@ -61,6 +61,12 @@ def _counted() -> dict:
     return {kind: capcache.TIGHTENED.value(kind) for kind in KINDS}
 
 
+def _flat(spans):
+    for s in spans:
+        yield s
+        yield from _flat(s.children)
+
+
 def _instrument(ex):
     """A service of the executor's own, its `compile` spans, and what every
     call of `_run` was given and reported."""
@@ -78,11 +84,7 @@ def _instrument(ex):
     ex._run = spied
 
     def causes():
-        def flat(spans):
-            for s in spans:
-                yield s
-                yield from flat(s.children)
-        return [s.attributes["cause"] for s in flat(exporter.snapshot())
+        return [s.attributes["cause"] for s in _flat(exporter.snapshot())
                 if s.name == "compile"]
 
     return runs, causes
@@ -95,9 +97,12 @@ CASES = {
         {"Aggregate": 1}),
     "distinct": (
         _memory_engine, "select distinct k from a where v + k < 100", {"Distinct": 1}),
+    # a filtering join that still builds a frame: a residual of two conjuncts
+    # (one comparison, or none, is answered off the rank: no frame, no tier)
     "semi_join": (
         _memory_engine,
-        "select count(*) from a where v < 1 and k in (select k from b)",
+        "select count(*) from a where v < 1 and exists"
+        " (select 1 from b where b.k = a.k and b.v <> a.v and b.v < a.v + 50)",
         {"Join": 1}),
     "inner_join_loose_stats_frame": (
         _memory_engine,
@@ -151,6 +156,39 @@ def test_every_sized_node_tightens_once(case, own_caps_file):
     assert _counted() == after
     assert capcache.load_caps(plan, ex._load_inputs(nodes, None), ex._caps_scope) == (
         learned, True)
+
+
+@pytest.mark.parametrize("sql,form", [
+    ("select count(*) from a where v < 1 and k in (select k from b)", "rank"),
+    ("select count(*) from a where v < 1 and k not in (select k from b)", "rank"),
+    ("select count(*) from a where v < 1 and exists"
+     " (select 1 from b where b.k = a.k and b.v <> a.v)", "minmax"),
+])
+def test_a_join_that_builds_no_frame_reports_no_need(sql, form, own_caps_file):
+    """A filtering join answered off its rank (ops/relops.py `filter_form`)
+    is sized like any join but reads no tier: it reports nothing, so nothing
+    tightens it, no frame of it is counted, and its one program stays."""
+    from trino_tpu.ops import kernels
+
+    eng = _memory_engine()
+    ex = eng.executor
+    runs, causes = _instrument(ex)
+    ex.tracer.add_exporter(exporter := InMemorySpanExporter())
+    plan = eng.plan(sql)
+    (nid,) = [i for i, n in _node_ids(plan).items() if type(n).__name__ == "Join"]
+    before = _counted()["Join"]
+    rows = eng.execute_page(sql).to_pylist()
+    assert f"{form} join_filter (" in "; ".join(kernels.describe(plan))
+    loose, need = runs[0]
+    assert nid in loose and nid not in need
+    assert ex._learned_caps[plan][nid] == loose[nid] and _counted()["Join"] == before
+    (wait,) = [s for s in _flat(exporter.snapshot()) if s.name == "device_wait"]
+    assert f"Join#{nid}" not in wait.attributes["frames"]
+    # whatever else tightened, the join's tier is in every later program's key, unchanged
+    for _ in range(2):
+        assert eng.execute_page(sql).to_pylist() == rows
+    assert all(caps[nid] == loose[nid] for caps, _ in runs)
+    assert ex.compile_service.builds <= 2 and "caps_tier" not in causes()
 
 
 def test_top_n_keeps_its_floor(own_caps_file):

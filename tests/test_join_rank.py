@@ -163,6 +163,21 @@ def _join_page(case: str, rng):
             [dev(lk)], [dev(rk)], C)
 
 
+def _same_page(got, want, msg=""):
+    """(cols, live, ...) against (cols, live, ...): every lane of every
+    array, dead ones too, its dtype, and the column's dictionary and type."""
+    (cols, live), (want_cols, want_live) = got[:2], want[:2]
+    np.testing.assert_array_equal(np.asarray(live), np.asarray(want_live), err_msg=msg)
+    assert len(cols) == len(want_cols)
+    for g, w in zip(cols, want_cols):
+        assert g.dict is w.dict and g.type is w.type
+        for have, other in ((g.data, w.data), (g.valid, w.valid), (g.data2, w.data2)):
+            assert (have is None) == (other is None), msg
+            if other is not None:
+                assert have.dtype == other.dtype
+                np.testing.assert_array_equal(np.asarray(have), np.asarray(other), err_msg=msg)
+
+
 KINDS = ("inner", "left", "full", "semi", "anti", "null_anti", "mark", "mark_in")
 
 
@@ -184,18 +199,171 @@ def test_every_kind_is_the_parents_join(kind, case, monkeypatch):
             got[form] = relops.equi_join(*args)
     with monkeypatch.context() as m:
         _parent_equi_join(m)
-        want_cols, want_live, want_required = relops.equi_join(*args)
+        tight = relops.equi_join(*args)
+        # a frame too small for the matches answers for the lanes it holds (the
+        # caller retries at `required`); the rank needs no frame and answers
+        # at once what the parent answers in a frame that holds them all
+        whole = relops.equi_join(*args[:-1], 16384)
+    assert int(whole[2]) == int(tight[2]) <= 16384
     for form, (cols, live, required) in got.items():
-        assert int(required) == int(want_required), form
-        np.testing.assert_array_equal(np.asarray(live), np.asarray(want_live), err_msg=form)
-        assert len(cols) == len(want_cols)
-        for g, w in zip(cols, want_cols):  # every lane, dead ones too
-            assert g.dict is w.dict and g.type is w.type
-            for have, want in ((g.data, w.data), (g.valid, w.valid), (g.data2, w.data2)):
-                assert (have is None) == (want is None), form
-                if want is not None:
-                    assert have.dtype == want.dtype
-                    np.testing.assert_array_equal(np.asarray(have), np.asarray(want), err_msg=form)
+        # a filtering kind without a residual reads its answer off the merged
+        # rank and builds no frame: it has no need to report
+        no_frame = kind in relops._FILTERING and form == "merged"
+        want_cols, want_live, want_required = whole if no_frame else tight
+        if no_frame:
+            assert required is None
+        else:
+            assert int(required) == int(want_required), form
+        _same_page((cols, live), (want_cols, want_live), form)
+
+
+# --- a filtering kind with a residual: the run's min and max against the frame
+
+def _ir(op, x, y):
+    from trino_tpu.data.types import BOOLEAN
+    from trino_tpu.plan.ir import Call, FieldRef
+
+    ref = lambda i: FieldRef(i, BIGINT)  # noqa: E731
+    return Call(op, (ref(x), ref(y)), BOOLEAN)
+
+
+def _residual_page(case: str, rng):
+    """Two columns a side (the key, the compared value): fields 0, 1 | 2, 3."""
+    nl, nr, C = 300, 260, 4096
+    kv = lambda n, hi: rng.integers(0, hi, n, dtype=np.int64)  # noqa: E731
+    ll, rl = rng.random(nl) < 0.9, rng.random(nr) < 0.9
+    lkey, rkey = kv(nl, 40), kv(nr, 40)
+    a, b = kv(nl, 6), kv(nr, 6)  # few values: runs whose every b equals a exist
+    rkey[:20], b[:20] = 41, 3  # a run of one b: `ne` turns on min == max == a
+    lkey[:30] = 41
+    va = vb = vlk = vrk = None
+    extra_l, extra_r, hi_l, hi_r = [], [], None, None
+    if case == "nulls":
+        va, vb = rng.random(nl) < 0.8, rng.random(nr) < 0.7
+        vlk, vrk = rng.random(nl) < 0.9, rng.random(nr) < 0.9
+    elif case == "two_keys":
+        extra_l, extra_r = [kv(nl, 3)], [kv(nr, 3)]
+    elif case == "limbed_key":  # the build side carries a high limb, the probe side none
+        hi_r = np.where(rng.random(nr) < 0.2, 1, rkey >> 63)
+    elif case == "narrow":  # int32 against int64, as narrowed lanes meet computed ones
+        a = a.astype(np.int32)
+    elif case == "extremes":  # b at its dtype's largest value sorts among the probes' slots
+        b = np.where(rng.random(nr) < 0.3, np.iinfo(np.int64).max, b)
+        a = np.where(rng.random(nl) < 0.3, np.iinfo(np.int64).max, a)
+    else:
+        assert case == "plain"
+    col = lambda d, v=None, d2=None: ColumnVal(  # noqa: E731
+        jnp.asarray(d), None if v is None else jnp.asarray(v), None, BIGINT,
+        None if d2 is None else jnp.asarray(d2))
+    lk, rk = col(lkey, vlk, hi_l), col(rkey, vrk, hi_r)
+    lc, rc = [lk, col(a, va)], [rk, col(b, vb)]
+    lkeys, rkeys = [lk] + [col(e) for e in extra_l], [rk] + [col(e) for e in extra_r]
+    return lc, jnp.asarray(ll), rc, jnp.asarray(rl), lkeys, rkeys, C
+
+
+def _filter_events(call):
+    events = kernels.begin_capture()
+    try:
+        out = call()
+    finally:
+        kernels.end_capture()
+    return out, [e[1] for e in events if e[0] == "join_filter"]
+
+
+RESIDUALS = {  # name -> (IR over fields 0, 1 | 2, 3, the form it takes)
+    "ne": (_ir("ne", 1, 3), "minmax"), "lt": (_ir("lt", 1, 3), "minmax"),
+    "ge": (_ir("ge", 1, 3), "minmax"), "le": (_ir("le", 1, 3), "minmax"),
+    "gt": (_ir("gt", 1, 3), "minmax"),
+    # the build side's expression first: `b < a` is `a > b`
+    "ne_mirrored": (_ir("ne", 3, 1), "minmax"), "lt_mirrored": (_ir("lt", 3, 1), "minmax"),
+    "ge_mirrored": (_ir("ge", 3, 1), "minmax"),
+    "eq": (_ir("eq", 1, 3), "frame"),           # no question about a run's ends
+    "one_side": (_ir("ne", 2, 3), "frame"),     # both arguments read the build side
+}
+
+
+def _with_residual(kind, page, ir, hand_over=True):
+    from trino_tpu.exec.compiler import _one_comparison
+    from trino_tpu.ops.expr import eval_expr, eval_predicate
+
+    lc, ll, rc, rl, lkeys, rkeys, C = page
+    residual = lambda cols, n: eval_predicate(ir, cols, n)  # noqa: E731
+    compare = None
+    sides = _one_comparison(ir, len(lc)) if hand_over else None
+    if sides is not None:
+        op, x, y = sides
+        compare = (op, eval_expr(x, lc, ll.shape[0]), eval_expr(y, [*lc, *rc], rl.shape[0]))
+    return _filter_events(lambda: relops.equi_join(
+        kind, lc, ll, rc, rl, lkeys, rkeys, residual, C, compare))
+
+
+@pytest.mark.parametrize("residual", sorted(RESIDUALS))
+@pytest.mark.parametrize("kind", ["semi", "anti", "mark"])
+def test_one_comparison_is_asked_of_the_runs_min_and_max(kind, residual):
+    """Every lane of the answer against the frame path's (the same call with
+    the comparison not handed over), and the `join_filter` event's form."""
+    ir, form = RESIDUALS[residual]
+    page = _residual_page("plain", np.random.default_rng(48))
+    got, said = _with_residual(kind, page, ir)
+    want, frame = _with_residual(kind, page, ir, hand_over=False)
+    assert said == [form] and frame == ["frame"]
+    assert (got[2] is None) == (form == "minmax") and want[2] is not None
+    _same_page(got, want)
+    kept = int(np.sum(np.asarray(got[1] if kind != "mark" else got[0][-1].data)))
+    assert form == "frame" or 0 < kept < 300  # the page tells the forms apart
+
+
+@pytest.mark.parametrize("case", ["nulls", "two_keys", "limbed_key", "narrow", "extremes"])
+@pytest.mark.parametrize("op", ["ne", "lt", "ge"])
+@pytest.mark.parametrize("kind", ["semi", "anti", "mark"])
+def test_min_and_max_under_nulls_wide_keys_and_limbs(kind, op, case):
+    """NULLs in the compared column on both sides and in the keys, a
+    two-column key, a key with a `data2` limb on one side, lanes of two
+    widths, values at the dtype's end: the frame path's answer."""
+    page = _residual_page(case, np.random.default_rng(49))
+    got, said = _with_residual(kind, page, _ir(op, 1, 3))
+    want, _ = _with_residual(kind, page, _ir(op, 1, 3), hand_over=False)
+    assert said == ["minmax"] and got[2] is None
+    _same_page(got, want)
+
+
+@pytest.mark.parametrize("kind,why", [
+    ("null_anti", "NOT IN's three-valued answer keeps the frame under a residual"),
+    ("mark_in", "and IN's"), ("inner", "an expansion is the output"), ("left", "the same")])
+def test_what_keeps_the_frame_under_one_comparison(kind, why):
+    page = _residual_page("plain", np.random.default_rng(50))
+    got, said = _with_residual(kind, page, _ir("ne", 1, 3))
+    assert said == (["frame"] if kind in relops._FILTERING else []), why
+    assert got[2] is not None
+
+
+def test_a_residual_of_two_conjuncts_takes_the_frame():
+    from trino_tpu.data.types import BOOLEAN
+    from trino_tpu.exec.compiler import _one_comparison
+    from trino_tpu.plan.ir import Call
+
+    both = Call("and", (_ir("ne", 1, 3), _ir("lt", 1, 3)), BOOLEAN)
+    assert _one_comparison(both, 2) is None
+    assert _one_comparison(_ir("ne", 1, 3), 2)[0] == "ne"
+    assert _one_comparison(_ir("lt", 3, 1), 2)[0] == "gt"  # mirrored: probe op build
+    page = _residual_page("plain", np.random.default_rng(51))
+    got, said = _with_residual("semi", page, both)
+    assert said == ["frame"] and got[2] is not None
+
+
+@pytest.mark.parametrize("kind", relops._FILTERING)
+def test_a_floating_point_key_keeps_the_frame(kind):
+    """A key whose sort order is not its `==` (NaN, -0.0) is verified in the
+    frame as before."""
+    lk = ColumnVal(jnp.asarray([0.5, 7.0, 1.5, 2.5]), None)
+    rk = ColumnVal(jnp.asarray([7.0, 1.5, 1.5]), None)
+    live_l, live_r = jnp.ones((4,), jnp.bool_), jnp.ones((3,), jnp.bool_)
+    (cols, live, required), said = _filter_events(lambda: relops.equi_join(
+        kind, [lk], live_l, [rk], live_r, [lk], [rk], None, 16))
+    assert said == ["frame"] and int(required) == 3
+    hit = np.asarray(cols[-1].data if kind.startswith("mark") else live)
+    want = np.asarray([False, True, True, False])
+    np.testing.assert_array_equal(hit, want if kind in ("semi", "mark", "mark_in") else ~want)
 
 
 def test_unnest_takes_the_helper(monkeypatch):
@@ -297,6 +465,99 @@ def test_what_the_sort_path_traces_to(nr, nl, C, bounds, expansion):
     # gathers and scatters nothing
     assert _count(jaxpr, "scatter") == (expansion == "merged")
     assert _count(jaxpr, "scan") == 2 * (bounds == "scan") + (expansion == "scan")
+
+
+def _traced_filter(kind, nr, nl, C, op):
+    """The jaxpr of a filtering join over shapes alone — one narrowed key and
+    one compared column a side, `a <op> b` the residual, handed over as
+    `exec/compiler.py` does — its dispatch events, and whether it reported
+    a need."""
+    from trino_tpu.ops.expr import eval_predicate
+
+    ir = None if op is None else _ir(op, 1, 3)
+    needs = []
+
+    def call(lkey, a, ll, rkey, b, rl):
+        col = lambda d: ColumnVal(d, None, None, BIGINT)  # noqa: E731
+        lc, rc = [col(lkey), col(a)], [col(rkey), col(b)]
+        residual = None if ir is None else (lambda cols, n: eval_predicate(ir, cols, n))
+        cols, live, required = relops.equi_join(
+            kind, lc, ll, rc, rl, lc[:1], rc[:1], residual, C,
+            None if ir is None else (op, lc[1], rc[1]))
+        needs.append(required is not None)
+        return [c.data for c in cols], live
+
+    s = lambda n, dt: jax.ShapeDtypeStruct((n,), dt)  # noqa: E731
+    events = kernels.begin_capture()
+    try:
+        jaxpr = jax.make_jaxpr(call)(
+            s(nl, jnp.int32), s(nl, jnp.int32), s(nl, jnp.bool_),
+            s(nr, jnp.int32), s(nr, jnp.int32), s(nr, jnp.bool_))
+    finally:
+        kernels.end_capture()
+    return jaxpr.jaxpr, events, needs[0]
+
+
+def _lanes(jaxpr, out: set, name=None) -> set:
+    """The leading sizes of every operand and result in `jaxpr` and under it
+    (of primitive `name` alone, where given: its results)."""
+    for e in jaxpr.eqns:
+        if name is None or e.primitive.name == name:
+            for v in (e.outvars if name else [*e.invars, *e.outvars]):
+                shape = getattr(v.aval, "shape", ())
+                if shape:
+                    out.add(shape[0])
+        for v in e.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else (v,)):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    _lanes(inner, out, name)
+    return out
+
+
+# q21's Join#8 and Join#6 (all of lineitem, and its late lines in Compact#15's 2^26-lane
+# frame, against 2,097,152 probes; frames of 16,777,216 lanes until PR 48), q22's Join#5,
+# q20's Join#13, SF10 and SF1 q18's Join#8
+@pytest.mark.parametrize("kind,nr,nl,C,op,form", [
+    ("semi", 60_000_466, 2_097_152, 16_777_216, "ne", "minmax"),
+    ("anti", 67_108_864, 2_097_152, 16_777_216, "ne", "minmax"),
+    ("anti", 15_000_000, 524_288, 4_194_304, None, "rank"),
+    ("semi", 65_536, 8_000_000, 262_144, None, "rank"),
+    ("semi", 4_096, 15_000_000, 4_096, None, "rank"),
+    ("semi", 2_048, 1_500_000, 2_048, None, "rank"),
+    ("null_anti", 16_384, 4_194_304, 4_096, None, "rank"),
+    ("mark", 60_000_466, 2_097_152, 16_777_216, "lt", "minmax"),
+])
+def test_a_filtering_join_traces_to_no_frame(kind, nr, nl, C, op, form):
+    """No operand of `C` lanes, no scatter, no `cond`, no binary search, no
+    sort of the build side alone: ONE sort of build ++ probe lanes and ONE
+    home, and gathers of `nl` lanes only (the run's min and max: two)."""
+    jaxpr, events, needed = _traced_filter(kind, nr, nl, C, op)
+    assert not needed
+    said = kind + ("+residual" if op else "")
+    assert [e for e in events if e[0] == "join"] == [
+        ("join", "sort", f"{said} build {nr} probe {nl} -> C {C}")]
+    assert [e[:2] for e in events if e[0] in ("join_rank", "join_filter")] == [
+        ("join_rank", "merged"), ("join_filter", form)]
+    lanes = _lanes(jaxpr, set())
+    assert C not in lanes or C in (nr, nl), lanes
+    assert lanes <= {1, nr, nl, nr + nl, nr + nl - 1}, lanes
+    assert _count(jaxpr, "sort") == _count(jaxpr, "sort", nr + nl) == 2
+    for absent in ("scatter", "scatter-add", "scatter-max", "cond", "scan", "while"):
+        assert _count(jaxpr, absent) == 0, absent
+    assert _count(jaxpr, "gather") == (2 if form == "minmax" else 0)
+    assert _lanes(jaxpr, set(), "gather") <= {nl}
+
+
+def test_the_frame_form_of_a_filtering_join_is_the_inner_joins_path():
+    """A residual of another shape at q21's sizes: the frame, its `C`-lane
+    operands, the scatter home, and a need to report."""
+    nr, nl, C = 60_000_466, 2_097_152, 16_777_216
+    jaxpr, events, needed = _traced_filter("semi", nr, nl, C, "eq")
+    assert needed
+    assert [e[:2] for e in events if e[0] == "join_filter"] == [("join_filter", "frame")]
+    assert C in _lanes(jaxpr, set()) and _count(jaxpr, "sort") == 3
+    assert _count(jaxpr, "scatter") == _count(jaxpr, "scatter-max") == 1  # the rows' starts; home
 
 
 def test_the_parent_traced_to_seven_sorts(monkeypatch):

@@ -21,6 +21,7 @@ added, at sizes a test run can hold on the CPU:
 
 import dataclasses
 import os
+import re
 import sys
 
 import pytest
@@ -241,6 +242,14 @@ KINDS = {  # the filtering, marking and cross joins of each plan at SF0.01
 }
 
 
+FILTER_FORMS = {  # `join_filter` events of each program (ops/kernels.py), sorted
+    "q16": ["rank"],
+    "q20": ["rank", "rank"],
+    "q21": ["minmax", "minmax"],
+    "q22": ["rank"],
+}
+
+
 @pytest.mark.parametrize("name", STATEMENTS)
 def test_planner_span_holds_the_subqueries_and_the_join_kinds(deployment, name):
     entry, _templates, _data, _config = deployment
@@ -273,9 +282,27 @@ def test_planner_span_holds_the_subqueries_and_the_join_kinds(deployment, name):
         if said != "cross":  # a cross join broadcasts one row: no equi_join
             assert f"sort join ({said} build " in text, (said, text)
     assert text.count("sort join (") == sum(k != "cross" for k in kinds.values())
-    # a sized filtering join's expansion frame is among the run's frames
+    # how each filtering join found its answer (PR 48): off the merged rank
+    # (`rank`), off its key run's min and max (`minmax`: q21's `ne`), never
+    # through a frame here
+    # ... but where SF0.01's tiers leave a join so few probes that `rank_form`
+    # searches (q20's Join#2 at its first, loose tiers: 128 probes, 32,768
+    # build lanes): their frame is as small as they are, and stays
+    taken = re.findall(r"(\w+) join_rank \([^)]*\); (\w+) join_filter \(", text)
+    assert len(taken) == len(FILTER_FORMS[name]), text
+    assert sorted(form for rank, form in taken if rank == "merged") == \
+        FILTER_FORMS[name][:sum(rank == "merged" for rank, _ in taken)], text
+    assert all(form == "frame" for rank, form in taken if rank == "scan"), text
+    for said in kinds.values():
+        if said.split("+")[0] in FILTERING:
+            assert f" join_filter ({said} " in text, (said, text)
+    # a join that builds an expansion frame is among the run's frames; one that
+    # built none has no need to report and is not
     frames = next(s for s in spans if s.name == "device_wait").attributes["frames"]
-    assert {nid for nid, said in kinds.items() if said != "cross"} <= set(frames)
+    framed = {nid for nid, said in kinds.items() if said.split("+")[0] not in FILTERING + ("cross",)}
+    assert framed <= set(frames)
+    unframed = sum(form != "frame" for _rank, form in taken)
+    assert len(set(kinds) - set(frames)) == unframed + sum(k == "cross" for k in kinds.values())
 
 
 def test_a_statement_without_a_join_says_nothing(deployment):
@@ -296,6 +323,11 @@ def test_explain_analyze_names_kind_and_residual(deployment):
     assert any("sort join (semi+residual build " in line for line in kernel), text
     assert any("sort join (anti+residual build " in line for line in kernel), text
     assert any("sort join (inner build " in line for line in kernel), text
+    # and how each of the two answered: `ne` asked of the run's min and max
+    assert any("minmax join_filter (semi+residual " in line and "ne of the run" in line
+               for line in kernel), text
+    assert any("minmax join_filter (anti+residual " in line for line in kernel), text
+    assert not any(" join_filter (inner" in line for line in kernel), text
 
 
 # ------------------------------------ (d) the plans at SF10's recorded statistics

@@ -32,10 +32,11 @@ from ..data.types import Type
 from ..ops.expr import ColumnVal, column_val, eval_expr, eval_predicate, param_context
 from ..ops.kernels import JOIN_ROWS
 from ..ops.relops import (
-    AggSpec, SortSpec, broadcast_single_row, compact_rows, equi_join,
-    group_aggregate, limit_mask, sort_rows, top_n, unnest_expand,
+    MINMAX_KINDS, AggSpec, SortSpec, broadcast_single_row, compact_rows,
+    equi_join, group_aggregate, limit_mask, sort_rows, top_n, unnest_expand,
 )
 from .capcache import TIGHTENED, load_caps, store_caps
+from ..plan.ir import Call, field_refs
 from ..plan.nodes import (
     Aggregate, Compact, Concat, Distinct, EnforceSingleRow, Exchange, Filter,
     Join, Limit, MatchRecognize, PlanNode, Project, RemoteSource, Sort,
@@ -1522,18 +1523,29 @@ def _trace_plan(
             lkeys = [eval_expr(k, left.cols, left.capacity) for k in node.left_keys]
             rkeys = [eval_expr(k, right.cols, right.capacity) for k in node.right_keys]
             lkeys, rkeys = _align_join_keys(lkeys, rkeys)
-            residual = None
+            residual = compare = None
             if node.residual is not None:
                 res_ir = node.residual
 
                 def residual(gathered, cap, _ir=res_ir):
                     return eval_predicate(_ir, gathered, cap)
 
+                sides = (_one_comparison(res_ir, len(left.cols))
+                         if node.kind in MINMAX_KINDS else None)
+                if sides is not None:
+                    # a filtering join asks such a residual of its key run's
+                    # smallest and largest build value (equi_join, "minmax")
+                    op, probe_ir, build_ir = sides
+                    compare = (
+                        op, eval_expr(probe_ir, left.cols, left.capacity),
+                        eval_expr(build_ir, [*left.cols, *right.cols], right.capacity))
+
             cols, live, req = equi_join(
                 node.kind, left.cols, left.live, right.cols, right.live,
-                lkeys, rkeys, residual, C,
+                lkeys, rkeys, residual, C, compare,
             )
-            report(nid, req)
+            if req is not None:  # a join that built no frame has no need
+                report(nid, req)
             return _Stage(cols, live)
 
         if isinstance(node, Unnest):
@@ -1694,6 +1706,26 @@ def _concat_columns(parts: list[ColumnVal], t) -> ColumnVal:
             ]
         )
     return ColumnVal(data, valid, out_dict, t)
+
+
+_MIRRORED = {"ne": "ne", "lt": "gt", "le": "ge", "gt": "lt", "ge": "le"}
+
+
+def _one_comparison(ir, n_left: int):
+    """-> (op, probe-side IR, build-side IR), read `probe op build`, where a
+    join's residual is ONE comparison between an expression over the left
+    page's fields (`< n_left`) and one over the right page's; else None."""
+    if not (isinstance(ir, Call) and ir.op in _MIRRORED and len(ir.args) == 2):
+        return None
+    x, y = ir.args
+    fx, fy = field_refs(x), field_refs(y)
+    if not fx or not fy:
+        return None
+    if max(fx) < n_left <= min(fy):
+        return ir.op, x, y
+    if max(fy) < n_left <= min(fx):
+        return _MIRRORED[ir.op], y, x
+    return None
 
 
 def _align_join_keys(lkeys: list[ColumnVal], rkeys: list[ColumnVal]):

@@ -43,6 +43,18 @@ ops and their impls:
     search (few probes against many keys: relops.rank_form); the detail is
     `60000466 ++ 4096 lanes -> C 16384` (build lanes, probe lanes, the
     expansion frame).
+  join_filter (one more event for a join that filters or marks its left
+    page — semi, anti, null_anti, mark, mark_in; relops.filter_form): how it
+    found its one bit a probe row — "rank" = no residual, off ONE sort of
+    build ++ probe lanes by the key's own words (the run of equal keys holds
+    a build row or not); "minmax" = a residual that is one comparison of a
+    probe-side with a build-side integer, asked of the run's smallest and
+    largest build value, which ride the same sort; "frame" = the inner
+    join's expansion frame (any other residual, a floating-point key, few
+    probes by a binary search).  rank and minmax build no frame and report no
+    need (`required` lacks the node).  The detail is `semi+residual 60000466
+    ++ 2097152 lanes, 1 key words, ne of the run's min and max`, or for a
+    frame `... lanes -> C 16777216`.
   compact (relops.compact_rows): "carry" = the columns rode the
     compaction's sort, "gather" = they were fetched through its
     permutation; the detail is `60000466 -> 33554432 lanes, 5 words`.
@@ -74,10 +86,12 @@ _POLICY = _DEFAULT
 _DISPATCH = _metrics.GLOBAL.counter(
     "trino_tpu_kernel_dispatch_total",
     "Data-plane kernel selections at plan-trace time, by relational op "
-    "(group_by | join | join_rank | fused_pipeline | segment_reduce | top_n "
-    "| compact) and implementation (pallas = Pallas TPU kernel; group_by and "
-    "join: sort; join_rank: merged = one sort of build ++ probe hashes, "
-    "scan = a binary search; segment_reduce and top_n count their Pallas "
+    "(group_by | join | join_rank | join_filter | fused_pipeline | "
+    "segment_reduce | top_n | compact) and implementation (pallas = Pallas "
+    "TPU kernel; group_by and join: sort; join_rank: merged = one sort of "
+    "build ++ probe hashes, scan = a binary search; join_filter: rank | "
+    "minmax = a filtering join answered off its rank with no expansion "
+    "frame, frame = through one; segment_reduce and top_n count their Pallas "
     "selections only; compact: carry = the columns rode the compaction's "
     "sort, gather = they were fetched through its permutation)",
     ("op", "impl"),

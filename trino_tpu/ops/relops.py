@@ -13,7 +13,9 @@ with whole-page device kernels:
 - equi-join: the reference's PagesHash + JoinProbe (operator/join/) becomes
   a sort of the build side's hashes, the probe's bounds over it (a running
   count over one merged sort, or a binary search for few probes: rank_form)
-  and a prefix-sum expansion.
+  and a prefix-sum expansion.  A join that only filters or marks its left
+  page (semi, anti, NOT IN, mark) reads its answer off the probe rows' rank
+  in one sort by the key's own words and expands nothing (filter_form).
   Output capacity is static; the kernel reports the true match count so the
   host can retry at a bigger tier (exec/executor.py), mirroring how the
   reference's planner-fed stats size hash tables.
@@ -38,7 +40,7 @@ from .expr import ColumnVal
 __all__ = [
     "group_aggregate", "equi_join", "broadcast_single_row", "sort_rows",
     "compact_rows", "top_n", "limit_mask", "unnest_expand", "AggSpec",
-    "SortSpec",
+    "SortSpec", "MINMAX_KINDS",
 ]
 
 
@@ -1263,6 +1265,165 @@ def _in_null_facts(left_keys, right_keys, left_live, right_live, nl, nr):
     return build_any, build_has_null, probe_ok
 
 
+# the kinds that filter or mark the left page: their answer is one bit a
+# probe row, so they need no frame where the rank itself is exact
+_FILTERING = ("semi", "anti", "null_anti", "mark", "mark_in")
+# those of them a one-comparison residual leaves two-valued (IN's three-valued
+# forms keep the frame under a residual)
+MINMAX_KINDS = ("semi", "anti", "mark")
+
+# "some build row of my run satisfies `a <op> b`", asked of the run's smallest
+# and largest b
+_RUN_COMPARE = {
+    "ne": lambda a, bmin, bmax: (bmin != a) | (bmax != a),
+    "lt": lambda a, bmin, bmax: a < bmax,
+    "le": lambda a, bmin, bmax: a <= bmax,
+    "gt": lambda a, bmin, bmax: a > bmin,
+    "ge": lambda a, bmin, bmax: a >= bmin,
+}
+
+
+def _plain_integer(v: ColumnVal) -> bool:
+    return (v.dict is None and v.data2 is None
+            and jnp.issubdtype(v.data.dtype, jnp.integer))
+
+
+def _all_valid(live: jnp.ndarray, vals) -> jnp.ndarray:
+    for v in vals:
+        if v.valid is not None:
+            live = live & v.valid
+    return live
+
+
+def _exact_key_words(left_keys, right_keys) -> Optional[list]:
+    """The key columns as (probe word, build word) pairs whose `==`, word for
+    word, IS the SQL key's — the words the frame's verification compares:
+    each column's `data` in the two sides' common dtype, and the high limb
+    where a side has one (a single-lane side sign-extends).  None where a
+    column's sort order is not its equality (floating point: NaN, -0.0)."""
+    words = []
+    for lk, rk in zip(left_keys, right_keys):
+        limbed = lk.data2 is not None or rk.data2 is not None
+        dt = jnp.int64 if limbed else jnp.promote_types(lk.data.dtype, rk.data.dtype)
+        if dt == jnp.bool_:
+            dt = jnp.int8
+        if not jnp.issubdtype(dt, jnp.integer):
+            return None
+        lo_l, lo_r = lk.data.astype(dt), rk.data.astype(dt)
+        words.append((lo_l, lo_r))
+        if limbed:
+            words.append((lo_l >> 63 if lk.data2 is None else lk.data2.astype(dt),
+                          lo_r >> 63 if rk.data2 is None else rk.data2.astype(dt)))
+    return words
+
+
+def filter_form(kind: str, rank: str, key_words, residual, compare) -> Optional[str]:
+    """How a join that filters or marks its left page finds its answer (None
+    for the kinds that output an expansion): "rank" — no residual, a probe
+    row matches iff its run of equal keys holds a build row; "minmax" — the
+    residual is ONE comparison `a <op> b` of a probe-side with a build-side
+    integer, asked of the run's smallest and largest b (semi, anti, mark:
+    IN's three-valued forms keep the frame); "frame" — the inner join's
+    expansion, for any other residual, a key whose order is not its equality,
+    and the few probes that `rank_form` sends to a binary search (their
+    frame is as small as they are).  Kind, the residual's shape and the
+    traced shapes decide: no switch."""
+    if kind not in _FILTERING:
+        return None
+    if rank == "scan" or key_words is None:
+        return "frame"
+    if residual is None:
+        return "rank"
+    if (compare is not None and kind in MINMAX_KINDS and compare[0] in _RUN_COMPARE
+            and _plain_integer(compare[1]) and _plain_integer(compare[2])):
+        return "minmax"
+    return "frame"
+
+
+def _hit_by_rank(key_words, probe_ok, build_ok, compare):
+    """-> for each probe row, whether a live build row has its key (and, with
+    `compare` = (op, a, b), whether one of them satisfies `a <op> b`), from
+    ONE sort of build ++ probe lanes by (the key's own words[, b], lane):
+    exact with nothing left to verify, and no lane of an expansion.
+
+    A lane that is dead or has a NULL key (a build lane whose b is NULL too:
+    it can satisfy nothing) carries the lane number's top bit, so within a
+    run of equal keys the order is live build lanes (by b, where b rides),
+    live probe lanes, then the dead of either side.  A probe's run holds a
+    build row iff the last live build lane at or before it lies at or after
+    the run's first lane — two running maxima of positions.  With b riding
+    (probe and dead lanes at its dtype's largest value, so they sort behind),
+    the run's smallest b stands at the run's first lane and its largest at
+    that last build lane: two gathers of probe-many lanes after a sort home
+    on the lane number, as `_merged_bounds` goes home.  Without b the answer
+    is one bit and rides home in the lane number's lowest."""
+    nl, nr = probe_ok.shape[0], build_ok.shape[0]
+    operands = [jnp.concatenate([b, p]) for p, b in key_words]
+    if compare is not None:
+        op, a, b = compare
+        probe_ok, build_ok = _all_valid(probe_ok, [a]), _all_valid(build_ok, [b])
+        top = jnp.iinfo(b.data.dtype).max
+        operands.append(jnp.concatenate(
+            [jnp.where(build_ok, b.data, top), jnp.full((nl,), top, b.data.dtype)]))
+    dead = jnp.uint32(1 << 31)
+    lane = jnp.arange(nr + nl, dtype=jnp.uint32)
+    lane = jnp.where(jnp.concatenate([build_ok, probe_ok]), lane, lane | dead)
+    *sorted_words, lane_s = jax.lax.sort(
+        operands + [lane], num_keys=len(operands) + 1, is_stable=False)
+    first = jnp.zeros((nr + nl - 1,), jnp.bool_)
+    for w in sorted_words[:len(key_words)]:
+        first = first | (w[1:] != w[:-1])
+    first = jnp.concatenate([jnp.ones((1,), jnp.bool_), first])
+    pos = jnp.arange(nr + nl, dtype=jnp.int32)
+    start = jax.lax.cummax(jnp.where(first, pos, 0))
+    last_b = jax.lax.cummax(jnp.where(lane_s < nr, pos, -1))
+    came = lane_s & ~dead  # the lane it came from: build lanes first
+    is_p = came >= nr
+    probe = came - jnp.uint32(nr)  # wraps above every probe for a build lane
+    away = jnp.uint32(0xFFFFFFFF)
+    if compare is None:
+        held = (last_b >= start).astype(jnp.uint32)
+        home = jax.lax.sort(jnp.where(is_p, (probe << 1) | held, away), is_stable=False)
+        return probe_ok & ((home[:nl] & 1) == 1)
+    _, start, last_b = jax.lax.sort(
+        [jnp.where(is_p, probe, away), start, last_b], num_keys=1, is_stable=False)
+    start, last_b = start[:nl], last_b[:nl]
+    b_s = sorted_words[-1]
+    bmin, bmax = jnp.take(b_s, start), jnp.take(b_s, jnp.maximum(last_b, 0))
+    dt = jnp.promote_types(a.data.dtype, b_s.dtype)
+    some = _RUN_COMPARE[op](a.data.astype(dt), bmin.astype(dt), bmax.astype(dt))
+    return probe_ok & (last_b >= start) & some
+
+
+def _filtered(kind, left_cols, left_live, left_keys, right_keys, right_live, hit):
+    """A filtering kind's output from `hit`, one bit a probe row."""
+    nl, nr = left_live.shape[0], right_live.shape[0]
+    if kind in ("mark", "mark_in"):
+        from ..data.types import BOOLEAN
+
+        if kind == "mark":
+            mark = ColumnVal(hit, None, None, BOOLEAN)
+        else:
+            build_any, build_has_null, probe_ok = _in_null_facts(
+                left_keys, right_keys, left_live, right_live, nl, nr
+            )
+            # TRUE on match; else FALSE when definitively absent (non-null
+            # probe, no build NULLs, or empty build); else NULL (unknown)
+            definite = hit | ~build_any | (probe_ok & ~build_has_null)
+            mark = ColumnVal(hit, definite, None, BOOLEAN)
+        return list(left_cols) + [mark], left_live
+    if kind == "semi":
+        return list(left_cols), left_live & hit
+    if kind == "anti":
+        return list(left_cols), left_live & ~hit
+    # null_anti: SQL three-valued NOT IN
+    build_any, build_has_null, probe_ok = _in_null_facts(
+        left_keys, right_keys, left_live, right_live, nl, nr
+    )
+    keep = jnp.where(build_any, ~hit & probe_ok & ~build_has_null, True)
+    return list(left_cols), left_live & keep
+
+
 def equi_join(
     kind: str,
     left_cols: Sequence[ColumnVal],
@@ -1273,6 +1434,7 @@ def equi_join(
     right_keys: Sequence[ColumnVal],
     residual: Optional[Callable[[list[ColumnVal], int], jnp.ndarray]],
     out_capacity: int,
+    compare: Optional[tuple[str, ColumnVal, ColumnVal]] = None,
 ):
     """Sort equi-join.  kind: inner | left | full | semi | anti | null_anti |
     mark | mark_in.
@@ -1292,7 +1454,20 @@ def equi_join(
       semiJoinOutput symbol).  mark is two-valued (EXISTS); mark_in is
       SQL three-valued: NULL when the probe key is NULL or the build side
       holds a NULL key and there is no match (an empty build is FALSE).
-    `required` is the true expansion size for the host's retry loop.
+    `required` is the true expansion size for the host's retry loop — or
+    None where the join built no expansion and so has no need to report.
+
+    The filtering and marking kinds build no frame of `out_capacity` lanes
+    where their answer can be read off the probe rows' rank among the build
+    rows (`filter_form`, `_hit_by_rank`): with no residual ("rank": all five
+    kinds), and — semi, anti and mark — with a residual that is ONE
+    comparison ne | lt | le | gt | ge between a probe-side and a build-side
+    integer ("minmax"), which the caller that knows the residual's IR hands
+    over as `compare` = (op, a over the left page, b over the right page),
+    meaning `a <op> b`, beside `residual`.  Any other residual, null_anti or
+    mark_in with one, a floating-point key, and the few probes that
+    `rank_form` sends to a binary search take the frame, as inner, left and
+    full do.
     """
     nl = left_live.shape[0]
     nr = right_live.shape[0]
@@ -1304,6 +1479,19 @@ def equi_join(
     said = kind + ("+residual" if residual is not None else "")
     record_dispatch("join", "sort", f"{said} build {nr} probe {nl} -> C {C}")
     record_dispatch("join_rank", rank, f"{nr} ++ {nl} lanes -> C {C}")
+    key_words = _exact_key_words(left_keys, right_keys) if kind in _FILTERING else None
+    form = filter_form(kind, rank, key_words, residual, compare)
+    if form is not None:
+        how = f" -> C {C}" if form == "frame" else f", {len(key_words)} key words"
+        if form == "minmax":
+            how += f", {compare[0]} of the run's min and max"
+        record_dispatch("join_filter", form, f"{said} {nr} ++ {nl} lanes{how}")
+    if form in ("rank", "minmax"):
+        hit = _hit_by_rank(
+            key_words, _all_valid(left_live, left_keys), _all_valid(right_live, right_keys),
+            compare if form == "minmax" else None)
+        return *_filtered(kind, left_cols, left_live, left_keys, right_keys,
+                          right_live, hit), None
     bh = _combined_hash(right_keys, right_live, nr, _SENT_BUILD)
     ph = _combined_hash(left_keys, left_live, nl, _SENT_PROBE)
     iota_r = jnp.arange(nr, dtype=jnp.int32)
@@ -1377,37 +1565,10 @@ def equi_join(
 
     required = total
 
-    if kind in ("mark", "mark_in"):
-        from ..data.types import BOOLEAN
-
+    if kind in _FILTERING:
         hit = jnp.zeros((nl,), jnp.bool_).at[pidx_c].max(match, mode="drop")
-        if kind == "mark":
-            mark = ColumnVal(hit, None, None, BOOLEAN)
-        else:
-            build_any, build_has_null, probe_ok = _in_null_facts(
-                left_keys, right_keys, left_live, right_live, nl, nr
-            )
-            # TRUE on match; else FALSE when definitively absent (non-null
-            # probe, no build NULLs, or empty build); else NULL (unknown)
-            definite = hit | ~build_any | (probe_ok & ~build_has_null)
-            mark = ColumnVal(hit, definite, None, BOOLEAN)
-        return list(left_cols) + [mark], left_live, required
-
-    if kind in ("semi", "anti", "null_anti"):
-        hit = jnp.zeros((nl,), jnp.bool_).at[pidx_c].max(match, mode="drop")
-        if kind == "semi":
-            new_live = left_live & hit
-        elif kind == "anti":
-            new_live = left_live & ~hit
-        else:  # null_anti: SQL three-valued NOT IN
-            build_any, build_has_null, probe_ok = _in_null_facts(
-                left_keys, right_keys, left_live, right_live, nl, nr
-            )
-            keep = jnp.where(
-                build_any, ~hit & probe_ok & ~build_has_null, True
-            )
-            new_live = left_live & keep
-        return list(left_cols), new_live, required
+        return *_filtered(kind, left_cols, left_live, left_keys, right_keys,
+                          right_live, hit), required
 
     if kind == "inner":
         return gathered, match, required
